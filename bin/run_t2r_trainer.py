@@ -66,4 +66,9 @@ def main(argv=None):
 
 
 if __name__ == '__main__':
+  from tensor2robot_tpu import runtime
+
+  # Process-wide configuration belongs to the process entry, not to
+  # main(argv), which tests call in-process.
+  runtime.enable_compile_cache()
   main()

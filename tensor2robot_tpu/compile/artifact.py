@@ -197,13 +197,6 @@ def resolve_cache_winner(entry) -> Tuple[Optional[Any], str]:
   return winner, 'ok'
 
 
-def _layout_text(compiled, attr: str) -> Optional[str]:
-  try:
-    return str(getattr(compiled, attr))
-  except Exception:  # noqa: BLE001 — layouts are provenance, not contract
-    return None
-
-
 class ArtifactStore:
   """Atomic on-disk store of serialized executables next to the cache.
 
@@ -327,8 +320,13 @@ class ArtifactStore:
           'lowered_sha': lowered_sha,
           'fingerprint': fingerprint or '',
           'hlo_text': hlo_text,
-          'in_layouts': _layout_text(compiled, 'input_layouts'),
-          'out_layouts': _layout_text(compiled, 'output_layouts'),
+          'in_layouts': str(compiled.input_formats),
+          'out_layouts': str(compiled.output_formats),
+          # The devices the program was compiled for, in assignment
+          # order: load() binds the executable to exactly these.
+          'device_ids': [
+              d.id for d in
+              compiled._executable._unloaded_executable.device_list],
           'serialized': serialized,
           'in_tree': in_tree,
           'out_tree': out_tree,
@@ -374,11 +372,20 @@ class ArtifactStore:
       # The key embeds device/jax already; these field checks catch a
       # tampered or hash-collided payload — stale, recompile.
       return None, payload, 'stale'
+    # deserialize_and_load binds to EVERY backend device unless told
+    # otherwise; a program compiled for one device then rejects its
+    # arguments on a host with more. A payload naming a device this
+    # process lacks (or none: written before the field existed) is stale.
+    by_id = {d.id: d for d in jax.devices()}
+    device_ids = payload.get('device_ids')
+    if not device_ids or any(i not in by_id for i in device_ids):
+      return None, payload, 'stale'
     try:
       from jax.experimental import serialize_executable
 
       executable = serialize_executable.deserialize_and_load(
-          payload['serialized'], payload['in_tree'], payload['out_tree'])
+          payload['serialized'], payload['in_tree'], payload['out_tree'],
+          execution_devices=[by_id[i] for i in device_ids])
       try:
         os.utime(path)  # LRU touch: a loaded artifact outlives dead ones
       except OSError:

@@ -62,6 +62,7 @@ def measure(cache_path: str, model_dir: str, batch_size: int = 8,
   import optax
   from jax.sharding import NamedSharding, PartitionSpec as P
 
+  from tensor2robot_tpu import runtime
   from tensor2robot_tpu.data.input_generators import (
       DefaultRandomInputGenerator,
   )
@@ -86,7 +87,7 @@ def measure(cache_path: str, model_dir: str, batch_size: int = 8,
     )
 
     model = Grasping44E2EOpenCloseTerminateGripperStatusHeightToBottom(
-        device_type='cpu')
+        device_type='tpu' if runtime.on_tpu() else 'cpu')
     height, width = 512, 640  # the flagship camera frame
     workload = 'coldstart_qtopt44_b{}'.format(batch_size)
   elif model_name == 'sim':
@@ -198,4 +199,9 @@ def main(argv=None) -> int:
 if __name__ == '__main__':
   import sys
 
+  from tensor2robot_tpu import runtime
+
+  # Process-wide configuration belongs to the process entry, not to
+  # main(argv), which tests call in-process.
+  runtime.enable_compile_cache()
   sys.exit(main())

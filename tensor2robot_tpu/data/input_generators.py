@@ -242,8 +242,8 @@ class DefaultRecordInputGenerator(AbstractInputGenerator):
   multithreaded record read + proto parse + JPEG decode outside the GIL,
   the analog of the reference's C++ tf.data pipeline
   (utils/tfdata.py:527-575). ``use_native=False`` (or T2R_NATIVE_LOADER=0)
-  forces the pure-Python pipeline; 'auto' falls back silently when specs
-  are unsupported or the toolchain can't build the library.
+  forces the pure-Python pipeline; 'auto' takes it only when the specs
+  are unsupported — a library that fails to build raises.
   """
 
   def __init__(self, file_patterns: Optional[str] = None,
@@ -378,37 +378,36 @@ class DefaultRecordInputGenerator(AbstractInputGenerator):
             'loader (sequences without sequence_max_len, PNG images, '
             'duplicate or unnamed feature names).')
       return None
-    try:
-      # Through _dataset_files() so subclass overrides (e.g. Fractional's
-      # file_fraction truncation) apply to the native path too. One file
-      # list per dataset key: the native loader zips multi-dataset plans
-      # itself (record_loader.cc file groups).
-      files_by_key = {}
-      for key, patterns in self._dataset_files().items():
-        _, files = parse_file_patterns(patterns)
-        files = files[shard_index::num_shards]
-        if not files:
-          return None
-        files_by_key[key] = files
-      if set(plan.dataset_keys) != set(files_by_key):
-        # Specs reference dataset keys with no configured files (the
-        # Python path raises the clear error), OR the dataset_map names
-        # datasets no spec reads — the Python pipeline still ZIPS those
-        # (epoch ends at the shortest dataset), so the native path must
-        # not silently change epoch length/pairing by ignoring them.
+    # Through _dataset_files() so subclass overrides (e.g. Fractional's
+    # file_fraction truncation) apply to the native path too. One file
+    # list per dataset key: the native loader zips multi-dataset plans
+    # itself (record_loader.cc file groups).
+    files_by_key = {}
+    for key, patterns in self._dataset_files().items():
+      _, files = parse_file_patterns(patterns)
+      files = files[shard_index::num_shards]
+      if not files:
         return None
-      stream_files = (files_by_key[''] if plan.dataset_keys == ['']
-                      else files_by_key)
-      stream = native_loader.NativeBatchedStream(
-          plan, stream_files, batch_size=self._batch_size,
-          shuffle=(mode == ModeKeys.TRAIN),
-          shuffle_buffer=self._shuffle_buffer_size,
-          num_epochs=num_epochs, seed=seed,
-          num_threads=self._num_native_threads)
-    except RuntimeError:
-      if self._use_native is True:
-        raise
-      return None  # toolchain missing etc. — silent fallback
+      files_by_key[key] = files
+    if set(plan.dataset_keys) != set(files_by_key):
+      # Specs reference dataset keys with no configured files (the
+      # Python path raises the clear error), OR the dataset_map names
+      # datasets no spec reads — the Python pipeline still ZIPS those
+      # (epoch ends at the shortest dataset), so the native path must
+      # not silently change epoch length/pairing by ignoring them.
+      return None
+    stream_files = (files_by_key[''] if plan.dataset_keys == ['']
+                    else files_by_key)
+    # A library that does not build, or a stream the loader rejects, is
+    # an error under 'auto' too: the Python parser is several times
+    # slower, and a run that silently took it reports a host rate that
+    # is not the system's.
+    stream = native_loader.NativeBatchedStream(
+        plan, stream_files, batch_size=self._batch_size,
+        shuffle=(mode == ModeKeys.TRAIN),
+        shuffle_buffer=self._shuffle_buffer_size,
+        num_epochs=num_epochs, seed=seed,
+        num_threads=self._num_native_threads)
     return iter(stream)
 
   def _create_iterator(self, mode, num_epochs, shard_index, num_shards, seed):

@@ -5,7 +5,8 @@ data-loader runtime: TFRecord framing, tf.Example wire parsing, libjpeg
 decode and batch assembly on a worker thread pool, with batches landing in a
 ring of preallocated buffers. This module:
 
-  * builds the shared library on first use (g++, cached by mtime);
+  * builds the shared library on first use (g++, cached by a hash of
+    the source and the flags);
   * decides, from a feature/label spec pair, whether the fast path supports
     the dataset (``plan_for_specs``). Since round 6 the fast path covers
     sequences (given ``sequence_max_len``), varlen pad/clip, optional
@@ -29,7 +30,10 @@ sized to host cores via the ``threads`` knob.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -68,26 +72,42 @@ PACKED_BUCKET = 2048
 ESCAPE_BUCKET = 256
 
 
+_COMPILE_CMD = ('g++', '-O2', '-fPIC', '-shared', '-std=c++17', '-msse4.2')
+_LINK_LIBS = ('-ljpeg', '-lpthread')
+
+
 def _so_path() -> str:
-  return os.path.join(_NATIVE_DIR, '_record_loader.so')
+  """The library's path, NAMED by a hash of the source and the flags.
+
+  A file time says nothing once a tree has been copied or checked out,
+  so a stale library is one whose name no longer matches.
+  """
+  digest = hashlib.sha1(' '.join(_COMPILE_CMD + _LINK_LIBS).encode())
+  with open(_SOURCE, 'rb') as f:
+    digest.update(f.read())
+  return os.path.join(
+      _NATIVE_DIR, '_record_loader.{}.so'.format(digest.hexdigest()[:16]))
 
 
 def build_native(force: bool = False) -> str:
-  """Compiles record_loader.cc into a shared library (cached by mtime)."""
+  """Compiles record_loader.cc into a shared library (cached by content
+  hash; libraries built from other sources or flags are removed)."""
   so = _so_path()
   with _BUILD_LOCK:
-    if (not force and os.path.exists(so)
-        and os.path.getmtime(so) >= os.path.getmtime(_SOURCE)):
+    if not force and os.path.exists(so):
       return so
     tmp = so + '.build.{}'.format(os.getpid())
-    cmd = ['g++', '-O2', '-fPIC', '-shared', '-std=c++17', '-msse4.2',
-           '-o', tmp, _SOURCE, '-ljpeg', '-lpthread']
+    cmd = list(_COMPILE_CMD) + ['-o', tmp, _SOURCE] + list(_LINK_LIBS)
     try:
       subprocess.run(cmd, check=True, capture_output=True, text=True)
-    except subprocess.CalledProcessError as e:
-      raise RuntimeError(
-          'native loader build failed:\n{}'.format(e.stderr)) from e
+    except (OSError, subprocess.CalledProcessError) as e:
+      raise RuntimeError('native loader build failed ({}):\n{}'.format(
+          ' '.join(cmd), getattr(e, 'stderr', None) or e)) from e
     os.replace(tmp, so)  # atomic: concurrent builders race benignly
+    for stale in glob.glob(os.path.join(_NATIVE_DIR, '_record_loader*.so')):
+      if stale != so:
+        with contextlib.suppress(FileNotFoundError):  # a racing builder
+          os.unlink(stale)
   return so
 
 

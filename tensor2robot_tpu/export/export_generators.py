@@ -38,12 +38,7 @@ import jax
 import numpy as np
 import orbax.checkpoint as ocp
 
-try:
-  # jax >= 0.4.30 ships the stable module; plain `jax.export` attribute
-  # access is deprecation-gated on 0.4.x and raises AttributeError.
-  from jax import export as jax_export
-except ImportError:  # pragma: no cover - older jax without jax.export
-  jax_export = None
+from jax import export as jax_export
 
 from tensor2robot_tpu.modes import ModeKeys
 from tensor2robot_tpu.specs import assets as assets_lib
@@ -239,8 +234,6 @@ class AbstractExportGenerator:
                                       np.asarray(v).dtype)
               for k, v in features.items()}
 
-    if jax_export is None:
-      return None
     try:
       (batch_dim,) = jax_export.symbolic_shape('b')
       exported = jax_export.export(jax.jit(serve))(

@@ -45,6 +45,7 @@ flash_lib = importlib.import_module(
 ring_lib = importlib.import_module(
     'tensor2robot_tpu.parallel.ring_attention')
 
+from tensor2robot_tpu import runtime
 from tensor2robot_tpu.parallel.sharding import constrain as _constrain
 
 _FLASH_MIN_LENGTH = 2048
@@ -74,8 +75,7 @@ def resolve_attention_mode(mode: str, seq_length: int) -> str:
   """
   if mode != 'auto':
     return mode
-  on_tpu = jax.default_backend() == 'tpu'
-  return 'flash' if (on_tpu and seq_length >= _FLASH_MIN_LENGTH
+  return 'flash' if (runtime.on_tpu() and seq_length >= _FLASH_MIN_LENGTH
                      and seq_length % 128 == 0) else 'xla'
 
 

@@ -34,7 +34,8 @@ from tensor2robot_tpu.observability import registry as registry_lib
 
 __all__ = [
     'COMPILE_COUNTER', 'COMPILE_MS_HISTOGRAM', 'TRACE_MS_HISTOGRAM',
-    'CACHE_MISS_COUNTER', 'HOST_RSS_GAUGE', 'HOST_PEAK_RSS_GAUGE',
+    'CACHE_MISS_COUNTER', 'CACHE_HIT_COUNTER', 'HOST_RSS_GAUGE',
+    'HOST_PEAK_RSS_GAUGE',
     'DEVICE_BYTES_GAUGE', 'DEVICE_PEAK_BYTES_GAUGE',
     'install_jax_listeners', 'uninstall_jax_listeners', 'sample_memory',
     'host_identity',
@@ -44,17 +45,19 @@ COMPILE_COUNTER = 'jax/compiles'
 COMPILE_MS_HISTOGRAM = 'jax/compile_ms'
 TRACE_MS_HISTOGRAM = 'jax/trace_ms'
 CACHE_MISS_COUNTER = 'jax/compilation_cache_misses'
+CACHE_HIT_COUNTER = 'jax/compilation_cache_hits'
 
 HOST_RSS_GAUGE = 'memory/host_rss_bytes'
 HOST_PEAK_RSS_GAUGE = 'memory/host_peak_rss_bytes'
 DEVICE_BYTES_GAUGE = 'memory/device_bytes_in_use'
 DEVICE_PEAK_BYTES_GAUGE = 'memory/device_peak_bytes'
 
-# jax._src.dispatch event names (stable across 0.4.x; unknown events are
+# jax._src.dispatch event names (unknown events are
 # simply never matched, so a rename degrades to "no signal", not a crash).
 _BACKEND_COMPILE_EVENT = '/jax/core/compile/backend_compile_duration'
 _JAXPR_TRACE_EVENT = '/jax/core/compile/jaxpr_trace_duration'
 _CACHE_MISS_EVENT = '/jax/compilation_cache/cache_misses'
+_CACHE_HIT_EVENT = '/jax/compilation_cache/cache_hits'
 
 _installed = False
 _enabled = False
@@ -82,6 +85,8 @@ def _on_event(event: str, **kwargs) -> None:
     return
   if event == _CACHE_MISS_EVENT:
     registry_lib.get_registry().counter(CACHE_MISS_COUNTER).inc()
+  elif event == _CACHE_HIT_EVENT:
+    registry_lib.get_registry().counter(CACHE_HIT_COUNTER).inc()
 
 
 def install_jax_listeners() -> bool:

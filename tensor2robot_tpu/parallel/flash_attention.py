@@ -44,6 +44,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tensor2robot_tpu import runtime
+
 NEG_INF = -1e30
 
 
@@ -296,7 +298,7 @@ def flash_attention_carry(q, k, v, o, m, l, q_offset, k_offset,
   forward-only (no VJP) — the differentiable ring path is the jnp one.
   """
   if interpret is None:
-    interpret = jax.default_backend() == 'cpu'
+    interpret = not runtime.on_tpu()
   bh, l_q, d = q.shape
   l_k = k.shape[1]
   block_q = min(block_q, l_q)
@@ -624,7 +626,7 @@ def flash_attention(q, k, v,
   if scale is None:
     scale = 1.0 / float(np.sqrt(q.shape[-1]))
   if interpret is None:
-    interpret = jax.default_backend() == 'cpu'
+    interpret = not runtime.on_tpu()
   b, l_q, h, d = q.shape
   l_k = k.shape[1]
   if jnp.dtype(q.dtype).itemsize >= 4:

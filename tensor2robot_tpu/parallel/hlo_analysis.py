@@ -224,11 +224,21 @@ def _split_instruction(line: str):
           rest[start + 1:end], rest[end + 1:])
 
 
+_OPERAND_NAME_RE = re.compile(r'%([\w.-]+)')
+
+
 def _parse_computations(hlo_text: str):
-  """{computation_name: [instruction tuples]}, plus the ENTRY name."""
+  """{computation_name: [instruction tuples]}, plus the ENTRY name.
+
+  The installed XLA prints operands by NAME only (``dot(%a.1, %b.1)``),
+  so each instruction's operand string is rewritten to the operands'
+  output shapes from the computation's own definitions — HLO text is in
+  def-before-use order — which is what the shape arithmetic below reads.
+  """
   computations: Dict[str, list] = {}
   entry_name = None
   current = None
+  shapes: Dict[str, str] = {}
   for line in hlo_text.splitlines():
     stripped = line.strip()
     if current is None:
@@ -237,6 +247,7 @@ def _parse_computations(hlo_text: str):
         if header:
           current = header.group('name')
           computations[current] = []
+          shapes = {}
           if header.group('entry'):
             entry_name = current
       continue
@@ -245,7 +256,14 @@ def _parse_computations(hlo_text: str):
       continue
     instr = _split_instruction(line)
     if instr:
-      computations[current].append(instr)
+      name, opcode, out_str, operand_str, attrs = instr
+      shapes[name] = out_str
+      if not _SHAPE_RE.search(operand_str):
+        operand_str = ', '.join(
+            shapes.get(operand, '')
+            for operand in _OPERAND_NAME_RE.findall(operand_str))
+      computations[current].append(
+          (name, opcode, out_str, operand_str, attrs))
   return computations, entry_name
 
 

@@ -26,6 +26,8 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
+from tensor2robot_tpu.reliability.logutil import log_warning
+
 DATA_AXIS = 'data'
 FSDP_AXIS = 'fsdp'
 MODEL_AXIS = 'model'
@@ -73,7 +75,12 @@ def create_mesh(axis_sizes: Optional[Dict[str, int]] = None,
     device_array = mesh_utils.create_device_mesh(
         shape, devices=devices,
         allow_split_physical_axes=allow_split_physical_axes)
-  except (ValueError, AssertionError):
+  except (ValueError, AssertionError) as e:
+    # Never silent: id order can put an inner axis across slow links.
+    log_warning(
+        'create_device_mesh could not map axes %s onto the topology (%s); '
+        'using jax.devices() id order: %s', dict(zip(names, shape)), e,
+        [d.id for d in devices])
     device_array = np.asarray(devices).reshape(shape)
   return Mesh(device_array, tuple(names))
 

@@ -134,6 +134,10 @@ def multihost_dryrun(workdir: str, num_processes: int, process_id: int,
         'host 0 must compile + persist the shared executable', artifact)
   multihost_utils.sync_global_devices('artifact_persisted')
   if process_id != 0:
+    # Eager PRNG seeding compiles two tiny programs of its own on first
+    # use; they are process start-up, identical with or without a store,
+    # and kept out of the window that counts the STEP's compiles.
+    jax.random.PRNGKey(0)
     compiles_before = float(registry.scalars().get('jax/compiles', 0.0))
     artifact = trainer.bind_train_step(bind_features, bind_labels)
     compiles_delta = float(

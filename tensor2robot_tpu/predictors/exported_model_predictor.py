@@ -102,7 +102,7 @@ class ExportedModelPredictor(AbstractPredictor):
         # whose serialization fell back to None can never serve model-less.
         fn_path = os.path.join(version_dir,
                                export_generators.PREDICT_FN_FILENAME)
-        from jax import export as jax_export  # stable module, jax>=0.4.30
+        from jax import export as jax_export
 
         with open(fn_path, 'rb') as f:
           exported_fn = jax_export.deserialize(f.read())

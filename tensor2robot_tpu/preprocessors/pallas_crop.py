@@ -46,6 +46,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tensor2robot_tpu import runtime
+
 
 def supported(image_shape: Tuple[int, ...]) -> bool:
   """True if the fused kernel handles [B, H, W, C] efficiently.
@@ -97,7 +99,7 @@ def fused_crop_convert(images: jax.Array, offsets: jax.Array,
                      'H % 8 == 0); use crop_images instead.'
                      .format(images.shape))
   if interpret is None:
-    interpret = jax.default_backend() == 'cpu'
+    interpret = not runtime.on_tpu()
 
   offsets = jnp.asarray(offsets, jnp.int32)
   offsets = jnp.clip(offsets, 0,

@@ -1,4 +1,4 @@
-"""Exception taxonomy for the fault-tolerance layer.
+"""Exception classes of the fault-tolerance layer.
 
 One module with no intra-package imports so retry/, fault_injection/,
 quarantine/ and the wired-up layers (trainer/, data/, predictors/) can all

@@ -50,7 +50,7 @@ from tensor2robot_tpu.utils.image import numpy_to_image_string
 # close-terminal positives and negatives arrive in comparable numbers.
 # (The round-5 first cut used THRESHOLD=0.25/H_MAX=1.2: ~13% positives on
 # a conjunction rule, and the full-scale critic regressed the dataset
-# mean instead of the rule — measured, see docs/round5_notes.md.)
+# mean instead of the rule — measured in round 5.)
 THRESHOLD = 0.5
 DESCENT_SCALE = 0.35
 H_MAX = 1.6

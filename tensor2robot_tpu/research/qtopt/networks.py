@@ -34,53 +34,6 @@ import jax.numpy as jnp
 
 from tensor2robot_tpu.layers.pooling import max_pool
 
-@jax.custom_jvp
-def _schedule_barrier(x):
-  """``optimization_barrier`` that stays differentiable on jax 0.4.x.
-
-  The barrier is the identity — it only pins XLA scheduling — but older
-  jax ships no AD rule for it, and eval-mode activations still get
-  differentiated (e.g. actor gradients through a frozen Q-network, the
-  stem-rewrite parity tests). Tangents pass straight through; the
-  primal keeps the barrier, so the fusion guard holds wherever it runs.
-  """
-  return jax.lax.optimization_barrier(x)
-
-
-@_schedule_barrier.defjvp
-def _schedule_barrier_jvp(primals, tangents):
-  (x,), (dx,) = primals, tangents
-  return _schedule_barrier(x), dx
-
-
-def _register_barrier_batch_rule() -> None:
-  """``optimization_barrier`` also ships no vmap rule on jax 0.4.x.
-
-  The barrier is elementwise identity, so batching it is the barrier on
-  the batched operands with the batch dims passed straight through.
-  Needed by the serving megabatch program (ISSUE 8):
-  ``make_batched_select_action`` vmaps the CEM selector — and the Q
-  tower under it — over the request batch. Registered at import, next
-  to the AD rule above, with the same degrade-to-no-op posture when the
-  internals move.
-  """
-  try:
-    from jax._src.lax import lax as _lax_internal
-    from jax.interpreters import batching as _batching
-    prim = _lax_internal.optimization_barrier_p
-  except (ImportError, AttributeError):  # newer jax: rule ships built-in
-    return
-  if prim in _batching.primitive_batchers:
-    return
-
-  def _rule(args, dims):
-    return prim.bind(*args), list(dims)
-
-  _batching.primitive_batchers[prim] = _rule
-
-
-_register_barrier_batch_rule()
-
 
 NUM_LAYERS = 19
 BATCH_SIZE = 64
@@ -235,7 +188,7 @@ class _PrePoolStatsBatchNorm(nn.Module):
     else:
       mean, var = ra_mean.value, ra_var.value
       # Same eval-mode fusion pathology guard as Grasping44Network._bn.
-      pooled = _schedule_barrier(pooled)
+      pooled = jax.lax.optimization_barrier(pooled)
     # Same arithmetic flax's BatchNorm applies: operands cast to the
     # module dtype first, normalize computed in that dtype.
     x = jnp.asarray(pooled, self.dtype)
@@ -298,7 +251,7 @@ class Grasping44Network(nn.Module):
       # native conv emitter to a loop fusion — measured 98 ms -> 33 ms
       # for the full eval forward at batch 256 with this barrier. The
       # barrier is the identity; numerics are untouched.
-      net = _schedule_barrier(net)
+      net = jax.lax.optimization_barrier(net)
     return nn.BatchNorm(
         use_running_average=not train, momentum=self.batch_norm_decay,
         epsilon=self.batch_norm_epsilon, use_scale=scale,

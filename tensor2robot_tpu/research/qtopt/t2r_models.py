@@ -242,7 +242,7 @@ class LegacyGraspingModelWrapper(CriticModel):
     — for workloads that are not reproducing the paper's 2018 training
     recipe, such as the off-policy convergence benchmark, where adaptive
     steps learn action-conditional rules ~an order of magnitude faster
-    (measured, docs/round5_notes.md).
+    (measured in round 5).
     """
     self.hparams = optimizer_builder.default_hparams(
         learning_rate=learning_rate,

@@ -341,9 +341,8 @@ def pairwise_ranking_accuracy(q_fn, pairs) -> float:
   wrong for critics normalized with batch statistics: batch-stat BN
   removes any feature that is constant within a forward batch, and each
   arm of a ranking pair holds a constant action column — exactly the
-  signal being measured (the round-5 debugging find,
-  docs/round5_notes.md; regression-tested in tests/test_offpolicy.py
-  TestRankingAccuracyBatchStats).
+  signal being measured (the round-5 debugging find; regression-tested
+  in tests/test_offpolicy.py TestRankingAccuracyBatchStats).
   """
   combined, arm_rows = concat_ranking_pairs(pairs)
   if not arm_rows:

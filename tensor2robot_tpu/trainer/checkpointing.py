@@ -135,7 +135,12 @@ class CheckpointManager:
     """Restores into the structure/shardings of ``state_template``.
 
     ``state_template`` may be a concrete pytree or one of
-    ``jax.ShapeDtypeStruct`` leaves (from ``jax.eval_shape``).
+    ``jax.ShapeDtypeStruct`` leaves (from ``jax.eval_shape``). None —
+    the form foreign readers use (predictors, warm starts) — returns the
+    saved tree whole on this process's first local device: a reader's
+    devices are not the writer's, and Orbax's own default (the shardings
+    that SAVED it) fails wherever they differ, e.g. a four-chip run's
+    checkpoint served from one chip.
     """
     if step is None:
       step = self.latest_step()
@@ -147,8 +152,11 @@ class CheckpointManager:
 
     def _restore():
       fault_injection.maybe_fail(fault_injection.SITE_CKPT_RESTORE)
+      template = state_template
+      if template is None:
+        template = self._saved_tree_on_local_device(int(step))
       return self._manager.restore(
-          int(step), args=ocp.args.StandardRestore(state_template))
+          int(step), args=ocp.args.StandardRestore(template))
 
     try:
       with span('ckpt.restore'):
@@ -194,12 +202,34 @@ class CheckpointManager:
 
   def _restore_step_direct(self, step: int, state_template):
     """Reads one step's 'default' item without the (poisoned) manager."""
+    if state_template is None:
+      state_template = self._saved_tree_on_local_device(step)
     item_dir = os.path.join(self.directory, str(step), 'default')
     checkpointer = ocp.StandardCheckpointer()
     try:
       return checkpointer.restore(item_dir, target=state_template)
     finally:
       checkpointer.close()
+
+  def _saved_tree_on_local_device(self, step: int):
+    """The tree saved at ``step`` as ShapeDtypeStructs placed on the
+    first local device — the template of a template-less restore."""
+    item_dir = os.path.join(self.directory, str(step), 'default')
+    checkpointer = ocp.StandardCheckpointer()
+    try:
+      tree = checkpointer.metadata(item_dir).item_metadata.tree
+    except (FileNotFoundError, AttributeError) as e:
+      # A gutted or vanished step has no tree to describe. ValueError is
+      # what restore() classifies against the on-disk damage, exactly as
+      # it does for Orbax's own complaint about such a step.
+      raise ValueError(
+          'step {} holds no readable tree metadata'.format(step)) from e
+    finally:
+      checkpointer.close()
+    sharding = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
+    return jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=sharding), tree)
 
   def _on_disk_steps(self):
     if not os.path.isdir(self.directory):

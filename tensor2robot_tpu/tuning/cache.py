@@ -34,12 +34,15 @@ CACHE_SCHEMA = 't2r.tuning.v1'
 
 
 def default_cache_path() -> str:
-  """$T2R_TUNING_CACHE, else ~/.cache/t2r/tuning_cache.json."""
+  """$T2R_TUNING_CACHE, else ``<compile-cache root>/t2r/tuning_cache.json``
+  (``runtime.cache_root``: beside JAX's persistent cache, so one
+  directory placed from outside carries every compiled program)."""
   env = os.environ.get(CACHE_PATH_ENV)
   if env:
     return env
-  return os.path.join(os.path.expanduser('~'), '.cache', 't2r',
-                      'tuning_cache.json')
+  from tensor2robot_tpu import runtime
+
+  return os.path.join(runtime.cache_root(), 't2r', 'tuning_cache.json')
 
 
 def _leaf_signature(leaf) -> str:

@@ -38,6 +38,7 @@ import json
 import os
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from tensor2robot_tpu import runtime
 from tensor2robot_tpu.tuning import autotuner
 from tensor2robot_tpu.tuning import cache as cache_lib
 
@@ -114,7 +115,7 @@ def _build_pallas_wgrad(shape: Optional[Tuple[int, ...]] = None,
 
   from tensor2robot_tpu.layers import pallas_wgrad
 
-  on_cpu = jax.default_backend() == 'cpu'
+  on_cpu = not runtime.on_tpu()
   if shape is None:
     shape = (2, 8, 8, 8) if on_cpu else (512, 79, 79, 64)
   if dtype is None:

@@ -152,9 +152,8 @@ def candidate_configs(backend: Optional[str] = None,
   model-override candidates for harnesses that cannot rebuild the model.
   """
   if backend is None:
-    import jax
-    backend = jax.default_backend()
-  backend = (backend or 'cpu').lower()
-  if backend == 'tpu':
+    from tensor2robot_tpu import runtime
+    backend = 'tpu' if runtime.on_tpu() else 'cpu'
+  if backend.lower() == 'tpu':
     return _tpu_candidates(include_layouts)
   return _cpu_candidates(include_layouts)

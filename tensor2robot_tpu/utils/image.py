@@ -35,3 +35,22 @@ def image_string_to_numpy(image_bytes: bytes) -> np.ndarray:
   """Decodes encoded image bytes back to a numpy array."""
   with io.BytesIO(image_bytes) as buf:
     return np.asarray(Image.open(buf))
+
+
+def camera_like_frame(rng: np.random.RandomState, height: int,
+                      width: int) -> np.ndarray:
+  """A synthetic uint8 [H, W, 3] frame with camera-like statistics:
+  gradient background, solid blocks, mild sensor noise. Uniform noise —
+  the obvious alternative — is the JPEG worst case (several times the
+  bytes and decode time of a real frame) and would misstate every
+  host-side figure taken on it."""
+  x = np.linspace(0, 1, width)
+  y = np.linspace(0, 1, height)
+  frame = (np.outer(y, x)[..., None] *
+           rng.randint(100, 255, 3)).astype(np.float32)
+  for _ in range(12):
+    r = rng.randint(0, max(1, height - 80))
+    c = rng.randint(0, max(1, width - 100))
+    frame[r:r + 80, c:c + 100] = rng.randint(0, 255, 3)
+  frame += rng.randn(height, width, 1) * 6
+  return np.clip(frame, 0, 255).astype(np.uint8)

@@ -255,11 +255,6 @@ class TestReferenceConfigParity:
     from tensor2robot_tpu.trainer import latest_checkpoint_step
     assert latest_checkpoint_step(model_dir) == 2
 
-  @pytest.mark.xfail(
-      strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): XLA hlo-verifier '
-      'INTERNAL error on a reshape in the MAML inner loop under this '
-      'jax/jaxlib CPU build — not a repo regression')
   def test_pose_env_maml_config_trains(self, tmp_path):
     model_dir = str(tmp_path / 'run')
     results = self._run_trainer(

@@ -53,7 +53,6 @@ def _load_elastic_gate():
 
 def _subprocess_env():
   env = dict(os.environ)
-  env.pop('PYTHONPATH', None)
   env['JAX_PLATFORMS'] = 'cpu'
   env.pop('XLA_FLAGS', None)
   return env

@@ -117,16 +117,14 @@ class TestPerHostEmission:
 
 
 class TestTwoProcessFederation:
-  """The subprocess harness the xfailed jax.distributed dryrun cannot
-  provide on this container (its CPU backend lacks multi-process
-  computations): two REAL concurrent processes, each writing its own
+  """The federation contract is files, not collectives, so it is proven
+  without jax.distributed (tests/test_multihost.py covers that):
+  two REAL concurrent processes, each writing its own
   per-host stream under one shared model_dir through the same
   TelemetryLogger path a real trainer process uses."""
 
   def test_round_trip(self, tmp_path):
     model_dir = str(tmp_path)
-    env = dict(os.environ)
-    env.pop('PYTHONPATH', None)
     procs = [
         subprocess.Popen(
             [sys.executable, '-m',
@@ -135,7 +133,7 @@ class TestTwoProcessFederation:
              '--process_index', str(pid), '--process_count', '2',
              '--step_times', times,
              '--sleep_per_window_secs', '0.05'],
-            cwd=REPO_ROOT, env=env)
+            cwd=REPO_ROOT)
         for pid, times in ((0, '0.010,0.010,0.010'),
                            ('1', '0.020,0.020,0.020'))]
     for proc in procs:

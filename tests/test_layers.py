@@ -393,51 +393,6 @@ class TestFastMaxPool:
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
 
-class TestPallasMaxPool:
-  """Interpret-mode parity for the Pallas pool kernel (layers/pallas_pooling).
-
-  The kernel is a measured-and-documented negative result on v5e (see
-  its module docstring) but its numerics are pinned here so it stays a
-  working artifact: forward/argmax/backward must match nn.max_pool for
-  every supported geometry (ties aside — absent in random f32 data).
-  """
-
-  CASES = [
-      ((2, 35, 35, 8), (3, 3), 'SAME'),     # high-pad row + 2-col tail
-      ((1, 27, 27, 8), (2, 2), 'SAME'),     # 1-col tail
-      ((2, 24, 24, 8), (2, 2), 'VALID'),
-      ((1, 29, 31, 8), (3, 3), 'VALID'),    # non-divisible: tail cropped
-      ((1, 30, 30, 8), (3, 3), 'SAME'),     # exact division
-  ]
-
-  @pytest.mark.parametrize('shape,window,padding', CASES)
-  def test_value_and_grad_match_reference(self, shape, window, padding):
-    import flax.linen as nn
-    from tensor2robot_tpu.layers import pallas_pooling
-
-    assert pallas_pooling.supported(shape, window, padding)
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(*shape).astype(np.float32))
-    want = nn.max_pool(x, window, strides=window, padding=padding)
-    got = pallas_pooling.max_pool_pallas(x, window, padding, True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-    dy = jnp.asarray(rng.randn(*want.shape).astype(np.float32))
-    _, vjp_ref = jax.vjp(
-        lambda x: nn.max_pool(x, window, strides=window, padding=padding), x)
-    (dx_ref,) = vjp_ref(dy)
-    _, vjp_new = jax.vjp(
-        lambda x: pallas_pooling.max_pool_pallas(x, window, padding, True), x)
-    (dx_new,) = vjp_new(dy)
-    np.testing.assert_array_equal(np.asarray(dx_new), np.asarray(dx_ref))
-
-  def test_low_padding_geometry_rejected(self):
-    from tensor2robot_tpu.layers import pallas_pooling
-    # 79 -> 27 with window 3 SAME needs low padding 1: outside the
-    # kernel's geometry, must be rejected by the gate.
-    assert not pallas_pooling.supported((2, 79, 79, 8), (3, 3), 'SAME')
-
-
 class TestPallasWgrad:
   """Interpret-mode parity for the Pallas 5x5 wgrad record kernel
   (layers/pallas_wgrad.py — the measured evidence that XLA's conv

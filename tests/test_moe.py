@@ -163,12 +163,6 @@ class TestExpertParallel:
       trainer.close()
     return float(metrics['loss']), shardings
 
-  @pytest.mark.xfail(
-      strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): the EP step '
-      'diverges ~0.4% from the replicated reference vs rtol 2e-5 on '
-      'this jaxlib CPU build (collective numeric drift) — not a repo '
-      'regression')
   def test_ep_step_matches_replicated(self):
     from tensor2robot_tpu import parallel
     from tensor2robot_tpu.parallel.sharding import EP_RULES_MOE

@@ -364,9 +364,10 @@ class TestOffPolicyLearning:
 
   @pytest.mark.xfail(
       strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): XLA hlo-verifier '
-      'INTERNAL error on a reshape in the lagged-target refresh under '
-      'this jax/jaxlib CPU build — not a repo regression')
+      reason='under jax 0.9.0 on CPU the depth-2 family Q reaches 0.56 '
+      'against the 0.6 bar in the 240-step budget (the earlier '
+      'hlo-verifier error is gone); a learning threshold, not yet '
+      'investigated — found in PR 21 when the module became importable')
   def test_learns_analytic_ordering_with_lagged_target(self, tmp_path):
     records = _collect_replay(tmp_path)
     acc, per_family, fam2_q, refreshes = self._train(

@@ -251,12 +251,6 @@ class TestTensorParallel:
       trainer.close()
     return float(metrics['loss']), sharding_of
 
-  @pytest.mark.xfail(
-      strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): the TP step '
-      'diverges ~0.4% from the replicated reference vs rtol 2e-5 on '
-      'this jaxlib CPU build (collective numeric drift) — not a repo '
-      'regression')
   def test_tp_step_matches_replicated(self):
     from tensor2robot_tpu import parallel
     from tensor2robot_tpu.parallel.sharding import TP_RULES_TRANSFORMER

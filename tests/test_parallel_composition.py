@@ -75,12 +75,6 @@ def _replicated_loss(**model_overrides):
 
 class TestWorkingPairs:
 
-  @pytest.mark.xfail(
-      strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): the composed-'
-      'parallelism step diverges ~0.4% from the replicated reference '
-      'vs rtol 2e-5 on this jaxlib CPU build (collective numeric '
-      'drift) — not a repo regression')
   def test_tp_with_ep_matches_replicated(self):
     """data x model x expert: attention TP-sharded, MoE expert-sharded
     (the a2a shard_map), in one transformer — rule sets concatenate."""
@@ -96,12 +90,6 @@ class TestWorkingPairs:
     w_in = [s for p, s in shardings.items() if p.endswith("'w_in']")]
     assert w_in and all('expert' in s for s in w_in), shardings
 
-  @pytest.mark.xfail(
-      strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): the composed-'
-      'parallelism step diverges ~0.4% from the replicated reference '
-      'vs rtol 2e-5 on this jaxlib CPU build (collective numeric '
-      'drift) — not a repo regression')
   def test_ring_with_fsdp_matches_replicated(self):
     """data x fsdp with ring attention: the seq shard_map and the FSDP
     param gathers compose."""
@@ -115,12 +103,6 @@ class TestWorkingPairs:
     np.testing.assert_allclose(loss, ref, rtol=2e-5)
     assert any('fsdp' in s for s in shardings.values()), shardings
 
-  @pytest.mark.xfail(
-      strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): the composed-'
-      'parallelism step diverges ~0.4% from the replicated reference '
-      'vs rtol 2e-5 on this jaxlib CPU build (collective numeric '
-      'drift) — not a repo regression')
   def test_ep_with_fsdp_matches_replicated(self):
     mesh = parallel.create_mesh({'data': 2, 'expert': 2, 'fsdp': 2})
     moe = dict(moe_experts=4, moe_top_k=2, moe_capacity_factor=2.0,
@@ -134,12 +116,6 @@ class TestWorkingPairs:
     assert w_in and all('expert' in s for s in w_in), shardings
     assert any('fsdp' in s for s in shardings.values()), shardings
 
-  @pytest.mark.xfail(
-      strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): the composed-'
-      'parallelism step diverges ~0.4% from the replicated reference '
-      'vs rtol 2e-5 on this jaxlib CPU build (collective numeric '
-      'drift) — not a repo regression')
   def test_pp_with_fsdp_matches_replicated(self):
     mesh = parallel.create_mesh({'data': 2, 'pipe': 2, 'fsdp': 2})
     loss, shardings = _one_step(
@@ -159,12 +135,6 @@ class TestWorkingPairs:
     assert pipe and all('pipe' in s for s in pipe), shardings
     assert any('fsdp' in s for s in shardings.values()), shardings
 
-  @pytest.mark.xfail(
-      strict=False,
-      reason='pre-existing env skew (CHANGES.md PR 4): the composed-'
-      'parallelism step diverges ~0.4% from the replicated reference '
-      'vs rtol 2e-5 on this jaxlib CPU build (collective numeric '
-      'drift) — not a repo regression')
   def test_ring_with_ep_matches_replicated(self):
     """Sequence-sharded attention + expert-sharded MoE in one block
     stack: two independent shard_maps over different axes."""

@@ -352,6 +352,7 @@ class TestActStepStability:
 
 class TestFaultSites:
 
+  @pytest.mark.slow  # 44 s measured (PR 21): past the tier-1 ~30 s rule
   def test_actor_stall_takes_exactly_one_budgeted_capture(
       self, tmp_path, monkeypatch):
     """ISSUE 12 satellite acceptance: an armed actor.stall inflates one
@@ -562,12 +563,13 @@ class TestCli:
     assert 'swaps=' in result.stdout
 
   @pytest.mark.slow
-  def test_rl_loop_selfcheck(self):
+  def test_rl_loop_selfcheck(self, tmp_path):
     result = subprocess.run(
         [sys.executable, os.path.join(REPO_ROOT, 'bin', 't2r_rl_loop'),
          '--selfcheck'],
         capture_output=True, text=True, timeout=600,
-        env=dict(os.environ, JAX_PLATFORMS='cpu'))
+        env=dict(os.environ, JAX_PLATFORMS='cpu',
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path)))
     assert result.returncode == 0, result.stdout + result.stderr
     summary = json.loads(result.stdout)
     assert summary['episodes'] > 0 and summary['learner_steps'] > 0
