@@ -48,7 +48,7 @@ def prefetch_iterator(iterator: Iterator, depth: int,
   import queue
   import threading
 
-  from tensor2robot_tpu.observability import get_registry
+  from tensor2robot_tpu.observability import get_registry, span
   from tensor2robot_tpu.observability.pipeline_xray import StageMeter
 
   q: 'queue.Queue' = queue.Queue(maxsize=depth)
@@ -71,7 +71,9 @@ def prefetch_iterator(iterator: Iterator, depth: int,
   # real cost is downstream backpressure (queue-full waits), which must
   # NOT be attributed to this stage; the stage's health signals are the
   # flow count and the prefetch-depth gauge, and it never competes in
-  # the capacity argmin (native pack cost is pipeline/batch/pack_ms).
+  # the capacity argmin (native pack cost is the span data.pack). The
+  # wait itself is the span data.handoff_wait: near the whole of this
+  # thread's time when input is hidden behind the device.
   batch_meter = StageMeter('batch')
 
   def _put(item) -> bool:
@@ -106,10 +108,13 @@ def prefetch_iterator(iterator: Iterator, depth: int,
 
   def _producer():
     try:
-      for item in iterator:
+      for index, item in enumerate(iterator):
         prefetched.inc()
         batch_meter.add(examples=_batch_examples(item))
-        if not _put(item):
+        # The queue is full: the consumer is slower (healthy back-pressure).
+        with span('data.handoff_wait', batch=index):
+          handed = _put(item)
+        if not handed:
           return
     except BaseException as e:  # surfaced on the consumer side
       error.append(e)

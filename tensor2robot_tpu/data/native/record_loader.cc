@@ -1021,7 +1021,11 @@ struct Loader {
   std::atomic<long long> st_records_read{0};   // records framed off disk
   std::atomic<long long> st_bytes_read{0};     // incl. TFRecord framing
   std::atomic<long long> st_reader_busy_us{0}; // read + shuffle time
-  std::atomic<long long> st_reader_wait_us{0}; // blocked on slots/space
+  // The reader's two waits, kept apart because they blame different
+  // stages: no free ring slot (pack or the consumer holds the ring) and
+  // work queue full (decode is behind). reader_wait_us is their sum.
+  std::atomic<long long> st_reader_wait_slot_us{0};
+  std::atomic<long long> st_reader_wait_space_us{0};
   std::atomic<long long> st_rows_parsed{0};    // batch rows completed
   std::atomic<long long> st_parse_bytes{0};    // record bytes parsed
   std::atomic<long long> st_worker_busy_us{0}; // parse/decode, pool total
@@ -1045,11 +1049,15 @@ struct Loader {
       std::lock_guard<std::mutex> lk(mu);
       completed = completed_batches;
     }
-    const long long vals[12] = {
+    const long long wait_slot =
+        st_reader_wait_slot_us.load(std::memory_order_relaxed);
+    const long long wait_space =
+        st_reader_wait_space_us.load(std::memory_order_relaxed);
+    const long long vals[14] = {
         st_records_read.load(std::memory_order_relaxed),
         st_bytes_read.load(std::memory_order_relaxed),
         st_reader_busy_us.load(std::memory_order_relaxed),
-        st_reader_wait_us.load(std::memory_order_relaxed),
+        wait_slot + wait_space,
         st_rows_parsed.load(std::memory_order_relaxed),
         st_parse_bytes.load(std::memory_order_relaxed),
         st_worker_busy_us.load(std::memory_order_relaxed),
@@ -1058,8 +1066,10 @@ struct Loader {
         completed,
         min_busy,
         max_busy,
+        wait_slot,
+        wait_space,
     };
-    int m = n < 12 ? n : 12;
+    int m = n < 14 ? n : 14;
     for (int i = 0; i < m; i++) out[i] = vals[i];
     return m;
   }
@@ -1106,7 +1116,8 @@ struct Loader {
           if (s.state == kFree) return true;
         return false;
       });
-      st_reader_wait_us.fetch_add(now_us() - t0, std::memory_order_relaxed);
+      st_reader_wait_slot_us.fetch_add(now_us() - t0,
+                                       std::memory_order_relaxed);
       if (stop) return false;
       for (size_t i = 0; i < slots.size(); i++) {
         if (slots[i].state == kFree) {
@@ -1126,7 +1137,8 @@ struct Loader {
       cv_space.wait(lk, [&] {
         return stop || work.size() < (size_t)(4 * cfg.threads + 64);
       });
-      st_reader_wait_us.fetch_add(now_us() - t0, std::memory_order_relaxed);
+      st_reader_wait_space_us.fetch_add(now_us() - t0,
+                                        std::memory_order_relaxed);
       if (stop) return false;
       work.push_back(WorkItem{std::move(recs), *cur_slot, *cur_row});
     }
@@ -1916,9 +1928,11 @@ int t2r_loader_next(void* h) { return ((Loader*)h)->next_slot(); }
 // Pipeline X-ray stats: fills up to n slots of `out` with the cumulative
 // counters [records_read, bytes_read, reader_busy_us, reader_wait_us,
 // rows_parsed, parse_bytes, worker_busy_us, worker_idle_us, n_workers,
-// completed_batches, min_worker_busy_us, max_worker_busy_us]; returns the
-// count written. Never launches the worker threads (lazy-launch boundary
-// preserved): before the first next() every value is 0.
+// completed_batches, min_worker_busy_us, max_worker_busy_us,
+// reader_wait_slot_us, reader_wait_space_us]; returns the count written
+// (reader_wait_us is the sum of the last two). Never launches the worker
+// threads (lazy-launch boundary preserved): before the first next() every
+// value is 0.
 long long t2r_loader_stats(void* h, long long* out, int n) {
   return ((Loader*)h)->stats_snapshot(out, n);
 }

@@ -145,7 +145,8 @@ _STAT_NAMES = ('records_read', 'bytes_read', 'reader_busy_us',
                'reader_wait_us', 'rows_parsed', 'parse_bytes',
                'worker_busy_us', 'worker_idle_us', 'n_workers',
                'completed_batches', 'min_worker_busy_us',
-               'max_worker_busy_us')
+               'max_worker_busy_us', 'reader_wait_slot_us',
+               'reader_wait_space_us')
 
 
 class _Field:
@@ -528,7 +529,7 @@ class NativeBatchedStream:
     return {name: int(buf[i]) for i, name in enumerate(_STAT_NAMES[:n])}
 
   def _publish_stats(self) -> None:
-    from tensor2robot_tpu.observability import get_registry
+    from tensor2robot_tpu.observability import event, get_registry
     from tensor2robot_tpu.observability.pipeline_xray import (
         DECODE_IDLE_COUNTER,
         DECODE_WORKERS_GAUGE,
@@ -557,6 +558,17 @@ class NativeBatchedStream:
     if idle > 0:
       idle_counter.inc(idle / 1e6)
     workers_gauge.set(float(stats.get('n_workers', 0)))
+    # The same deltas on the span ring's clock, batch by batch: what the
+    # reader thread and the decode pool did while this batch was made.
+    event('data.loader_stats',
+          reader_busy_s=delta.get('reader_busy_us', 0) / 1e6,
+          reader_wait_slot_s=delta.get('reader_wait_slot_us', 0) / 1e6,
+          reader_wait_space_s=delta.get('reader_wait_space_us', 0) / 1e6,
+          worker_busy_s=delta.get('worker_busy_us', 0) / 1e6,
+          worker_idle_s=idle / 1e6,
+          workers=stats.get('n_workers', 0),
+          records=delta.get('records_read', 0),
+          bytes=delta.get('bytes_read', 0))
 
   # -- buffer views ----------------------------------------------------------
 
@@ -775,15 +787,13 @@ class NativeBatchedStream:
     return qt[first:first + 1].copy()
 
   def __iter__(self):
-    import time
+    from tensor2robot_tpu.observability import span
 
-    from tensor2robot_tpu.observability import get_registry
-    from tensor2robot_tpu.observability.spans import SPAN_BUCKETS_MS
-
-    pack_ms = get_registry().histogram('pipeline/batch/pack_ms',
-                                       bounds=SPAN_BUCKETS_MS)
+    batch_index = 0
     while True:
-      slot = self._lib.t2r_loader_next(self._handle)
+      # The C++ loader has no whole batch ready: read or decode is behind.
+      with span('data.ring_wait', batch=batch_index):
+        slot = self._lib.t2r_loader_next(self._handle)
       if slot == -1:
         self._publish_stats()
         self._release_held()
@@ -793,12 +803,14 @@ class NativeBatchedStream:
         raise RuntimeError('native loader: ' +
                            (err or b'?').decode('utf-8', 'replace'))
       try:
-        t_pack = time.perf_counter()
-        batch = self._pack(slot)
-        # Busy-only histogram: the pack rows are already counted by the
-        # decode stage, so a batch-stage examples counter here would
-        # double-count them in the X-ray capacity table.
-        pack_ms.record((time.perf_counter() - t_pack) * 1e3)
+        # Slices, the owned copy, spec validation. The span's histogram is
+        # busy-only: the pack rows are already counted by the decode stage,
+        # so a batch-stage examples counter here would double-count them
+        # in the X-ray capacity table.
+        with span('data.pack', batch=batch_index) as sp:
+          batch = self._pack(slot)
+          sp.note(bytes=sum(int(getattr(leaf, 'nbytes', 0))
+                            for side in batch for leaf in side.values()))
         self._publish_stats()
       finally:
         if self._copy:
@@ -808,6 +820,7 @@ class NativeBatchedStream:
           # consumer can use the views for one full step.
           self._release_held()
           self._held_slot = slot
+      batch_index += 1
       yield batch
 
   def _release_held(self):

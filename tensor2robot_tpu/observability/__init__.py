@@ -7,10 +7,12 @@ The measurement substrate every perf/reliability PR builds on (ISSUE 3):
     ``scalars()`` for the TensorBoard writer, structured ``snapshot()``
     (+ ``snapshot_delta``) for jsonl export. ``get_registry()`` is the
     default instance the built-in layers report to.
-  * ``span`` (`spans.py`) — context-manager/decorator timing regions
-    into ``span/<name>`` histograms and, when a profiler trace window is
-    open (``set_trace_active``), into ``jax.profiler.TraceAnnotation``
-    rows that line up with `utils/xplane.py` captures.
+  * ``span`` / ``event`` (`spans.py`) — context-manager/decorator timing
+    regions into ``span/<name>`` histograms AND into one bounded,
+    always-on in-memory ring of records (id, parent, thread, start, end,
+    attributes) on one clock for every thread; ``span_records()`` reads
+    it. Captures tie it to the device trace by a clock marker
+    (`autoprofiler.py`), not by host-tracer annotations.
   * ``GoodputTracker`` (`goodput.py`) — every trainer-loop second
     charged to productive / data / checkpoint / retry; fractions sum to
     1.0 by construction.
@@ -144,9 +146,9 @@ from tensor2robot_tpu.observability.registry import (
     snapshot_delta,
 )
 from tensor2robot_tpu.observability.spans import (
-    set_trace_active,
+    event,
+    records as span_records,
     span,
-    trace_active,
 )
 from tensor2robot_tpu.observability.telemetry_file import (
     HEARTBEAT_FILENAME,
@@ -196,6 +198,7 @@ __all__ = [
     'classify_bound',
     'device_peaks',
     'discover_hosts',
+    'event',
     'exponential_buckets',
     'fleet_summary',
     'get_registry',
@@ -208,11 +211,10 @@ __all__ = [
     'read_telemetry',
     'sample_memory',
     'set_registry',
-    'set_trace_active',
     'snapshot_delta',
     'span',
+    'span_records',
     'split_collective_wait',
-    'trace_active',
     'uninstall_jax_listeners',
     'write_report',
 ]

@@ -15,6 +15,18 @@ Static windows stay supported (the ``profile_steps`` trainer arg maps to
 deliberate pre-planned capture and an incident response are different
 budgets.
 
+Every capture of a device runs with the profiler's host and Python
+tracers OFF: on a TPU host they record one event per chunk of every
+host-to-device copy (4.5 million for three 63 MB batches, a 148 MB trace
+and a loop 50 times slower: PERF.md, Findings PR 24), so a capture with
+them on IS the slowdown. (Only on the CPU backend, where XLA's thunks are
+themselves host-tracer events and nothing else would be captured, the
+host tracer stays on; the Python tracer never is.) What the host did
+during a capture comes from the program's own span ring (`spans.py`),
+tied to the trace's clock by a marker: a tiny program run and waited for
+right after ``start_trace``, whose end the host notes on the ring's clock
+and the device records on the trace's.
+
 All timing here is ``time.perf_counter`` (rate limiting is a duration,
 and tests/test_no_wallclock.py enforces the monotonic discipline). All
 jax imports are deferred and failures disable the profiler for the rest
@@ -24,12 +36,14 @@ profiling is evidence collection, never a liveness risk.
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 from tensor2robot_tpu.observability import forensics
 from tensor2robot_tpu.observability import registry as registry_lib
-from tensor2robot_tpu.observability.spans import set_trace_active, span
+from tensor2robot_tpu.observability import spans
+from tensor2robot_tpu.observability.spans import span
 
 __all__ = ['AutoProfiler', 'CAPTURE_COUNTER']
 
@@ -44,6 +58,15 @@ def _log(msg: str, *args) -> None:
     from absl import logging as _absl_logging  # deferred: absl optional
     _logv = _absl_logging.info
   _logv(msg, *args)
+
+
+def _clock_marker(x):
+  return x + 1
+
+
+# The jitted program carries this name in the trace's ``XLA Modules`` line,
+# which is how ``forensics`` finds its end there.
+_clock_marker.__name__ = forensics.CLOCK_MARKER
 
 
 class AutoProfiler:
@@ -86,6 +109,12 @@ class AutoProfiler:
     self._captures_taken = 0
     self._last_capture_end: Optional[float] = None
     self.last_report_path: Optional[str] = None
+    # The clock marker: (jitted program, operand), compiled before the
+    # first trace starts; and, per capture, the ring-clock times of the
+    # capture's start and of the marker's end.
+    self._marker = None
+    self._start_ns = 0
+    self.marker_done_ns: Optional[int] = None
 
   @property
   def registry(self) -> registry_lib.TelemetryRegistry:
@@ -171,7 +200,6 @@ class AutoProfiler:
       jax.profiler.stop_trace()
     except Exception as e:  # noqa: BLE001 — already unwinding
       _log('Profiler stop on failure path failed: %s', e)
-    set_trace_active(False)
 
   # -- internals -------------------------------------------------------------
 
@@ -179,14 +207,35 @@ class AutoProfiler:
              stop_step: int) -> None:
     try:
       import jax
+      import jax.numpy as jnp
 
+      if self._marker is None:
+        marker = jax.jit(_clock_marker)
+        operand = jnp.zeros((), jnp.float32)
+        jax.block_until_ready(marker(operand))  # compiled before any trace
+        self._marker = (marker, operand)
+      options = jax.profiler.ProfileOptions()
+      options.python_tracer_level = 0
+      # The CPU backend's ops ARE host-tracer events (module docstring).
+      if jax.default_backend() != 'cpu':
+        options.host_tracer_level = 0
+      self._start_ns = time.perf_counter_ns()
       # start_trace appends plugins/profile/<run> itself — pass the
       # logdir root so TensorBoard's profile plugin finds the trace.
-      jax.profiler.start_trace(self.model_dir)
+      jax.profiler.start_trace(self.model_dir, profiler_options=options)
     except Exception as e:  # noqa: BLE001 — profiling is best-effort
       _log('Profiler unavailable (%s); disabling capture for this run.', e)
       self._broken = True
       return
+    # The marker queues behind the steps in flight, so waiting for it
+    # also drains the host's lead: a capture starts from a synced device.
+    self.marker_done_ns = None
+    try:
+      marker, operand = self._marker
+      jax.block_until_ready(marker(operand))
+      self.marker_done_ns = time.perf_counter_ns()
+    except Exception as e:  # noqa: BLE001 — the capture is still worth having
+      _log('Clock marker failed (%s); idle gaps will not be named.', e)
     self._active = True
     self._reason = reason
     self._trigger = trigger
@@ -212,15 +261,11 @@ class AutoProfiler:
         _log('Forensics context callback at window open failed: %s', e)
     self.registry.counter_family(CAPTURE_COUNTER, ('trigger',)) \
         .series(reason).inc()
-    # Spans now also emit TraceAnnotations, so the host-side seams
-    # (data.next, ckpt.save) show up as rows in this capture.
-    set_trace_active(True)
     _log('Profiler window [%d, %d) opened (%s).', step, self._stop_step,
          reason)
 
   def _stop(self, step: int) -> Optional[str]:
     self._active = False
-    set_trace_active(False)
     try:
       import jax
 
@@ -263,6 +308,15 @@ class AutoProfiler:
         counters_delta = {}
     xplane_path = forensics.find_latest_xplane(
         self.model_dir, newer_than=self._start_walltime)
+    # The ring's records of the captured interval (a span still open now,
+    # like the loop iteration this runs in, is not in the ring yet). The
+    # capture's own thread is the one that dispatches the steps.
+    host_trace = {
+        'records': [r for r in spans.records()
+                    if r.end_ns >= self._start_ns],
+        'marker_done_ns': self.marker_done_ns,
+        'thread': threading.current_thread().name,
+    } if self.marker_done_ns is not None else None
     report = forensics.build_report(
         step=step,
         reason=self._reason or 'static',
@@ -277,7 +331,8 @@ class AutoProfiler:
         registry=self.registry,
         tuned_config=context.get('tuned_config'),
         pipeline=self._start_pipeline,
-        host=context.get('host'))
+        host=context.get('host'),
+        host_trace=host_trace)
     path = forensics.write_report(self.model_dir, step, report)
     self.last_report_path = path
     _log('Forensics report: %s (top op: %s)', path,

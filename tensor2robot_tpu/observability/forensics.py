@@ -8,8 +8,10 @@ nothing. This module turns each capture into the report a human (or
     attribution machinery, now automated), with a host-executor fallback
     for captures without a TPU plane (CPU runs name their XLA thunks on
     ``tf_...`` executor thread lines);
-  * device occupancy + host-vs-device overlap from event offsets (the
-    idle-gap complement of goodput's host-side view);
+  * device occupancy, and every device idle gap of the capture named for
+    the program span (`spans.py` ring) the capturing thread had open in
+    its middle — the ring's clock and the trace's are tied by the clock
+    marker the ``AutoProfiler`` runs as the trace starts;
   * collective counts/bytes from the compiled step's HLO
     (`parallel/hlo_analysis.py`), when the trainer can provide it;
   * the goodput split of the surrounding run with a ranked attribution
@@ -34,9 +36,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from tensor2robot_tpu.observability import registry as registry_lib
 
-__all__ = ['FORENSICS_DIRNAME', 'REPORT_SCHEMA', 'build_report',
-           'write_report', 'read_reports', 'find_latest_xplane',
-           'attribute_goodput', 'split_collective_wait']
+__all__ = ['FORENSICS_DIRNAME', 'REPORT_SCHEMA', 'CLOCK_MARKER',
+           'NO_HOST_EVENT', 'build_report', 'write_report', 'read_reports',
+           'find_latest_xplane', 'attribute_goodput', 'name_idle_gaps',
+           'split_collective_wait']
 
 FORENSICS_DIRNAME = 'forensics'
 REPORT_SCHEMA = 't2r.forensics.v1'
@@ -44,6 +47,16 @@ DEFAULT_TOP_K = 15
 
 # Fractions below this are noise, not a diagnosis.
 _ATTRIBUTION_FLOOR = 0.05
+
+# The tiny program ``AutoProfiler`` runs and waits for right after
+# ``start_trace``: the host notes ``perf_counter_ns`` at its end, the
+# trace's ``XLA Modules`` line holds the same end on the trace's clock.
+CLOCK_MARKER = 't2r_clock_marker'
+NO_HOST_EVENT = 'no_host_event'
+# Gaps between back-to-back device ops (launch latency) are not stalls.
+_SHORT_GAP_NS = 2000
+# A report stays a readable file: the newest records of a long capture.
+_MAX_HOST_SPANS = 4096
 
 
 def find_latest_xplane(model_dir: str,
@@ -69,7 +82,7 @@ def find_latest_xplane(model_dir: str,
 
 
 def _device_top_ops(xplane_path: str, n_steps: int, top_k: int):
-  """(top_ops, occupancy, overlap, warnings, families) from one capture.
+  """(top_ops, occupancy, warnings, families) from one capture.
 
   Prefers the TPU ``XLA Ops`` line (serial device stream). A capture
   with several TPU planes (multi-chip) is narrowed to the first plane —
@@ -83,7 +96,6 @@ def _device_top_ops(xplane_path: str, n_steps: int, top_k: int):
   warnings: List[str] = []
   top_ops: List[Dict[str, object]] = []
   occupancy = None
-  overlap = None
   source = None
   try:
     families = xplane.op_families(xplane_path, n_steps=n_steps)
@@ -125,30 +137,88 @@ def _device_top_ops(xplane_path: str, n_steps: int, top_k: int):
                 'fraction': (ms / total_ms) if total_ms else 0.0,
                 'source': source}
                for name, ms in families[:top_k]]
-  # Occupancy of the analyzed serial line + host-vs-device overlap.
+  # Occupancy of the analyzed serial line.
   device_lines = [s for s in stats
                   if (s['line'] == 'XLA Ops' and 'TPU' in str(s['plane']))
                   or (source == 'host_executor'
                       and str(s['line']).startswith('tf_'))]
   if device_lines:
-    busiest = max(device_lines, key=lambda s: s['busy_ms'])
-    occupancy = dict(busiest)
-    host_lines = [s for s in stats if s['line'] == 'python']
-    if host_lines:
-      host = max(host_lines, key=lambda s: s['busy_ms'])
-      extent = max(busiest['extent_ms'], 1e-9)
-      overlap = {
-          'device_busy_ms': busiest['busy_ms'],
-          'device_extent_ms': busiest['extent_ms'],
-          # Device idle inside its own active window == time the host
-          # failed to keep it fed (dispatch gaps, data waits).
-          'device_idle_fraction': 1.0 - min(
-              busiest['busy_ms'] / extent, 1.0),
-          'host_line_events': host['events'],
-      }
+    occupancy = dict(max(device_lines, key=lambda s: s['busy_ms']))
   if not top_ops:
     warnings.append('capture held no attributable op events')
-  return top_ops, occupancy, overlap, warnings, families
+  return top_ops, occupancy, warnings, families
+
+
+def name_idle_gaps(busy: List[Tuple[float, float]], host_spans
+                   ) -> Dict[str, float]:
+  """{span name: seconds} over the idle gaps of one serial device stream.
+
+  ``busy``: sorted, disjoint (start_ns, end_ns) intervals in which an op
+  ran, on the SAME clock as ``host_spans`` (ring records of one thread).
+  Each gap of 2 us or more between two intervals is named for the
+  innermost span open at its middle — of those that contain the moment,
+  the one that started last — or ``no_host_event`` if none was. The one
+  gap-naming rule of the program (the benchmark keeps its own copy).
+  """
+  spans = sorted((r for r in host_spans if r.end_ns > r.start_ns),
+                 key=lambda r: (r.start_ns, r.id))
+  out: Dict[str, float] = {}
+  for (_, gap_start), (gap_end, _) in zip(busy[:-1], busy[1:]):
+    if gap_end - gap_start < _SHORT_GAP_NS:
+      continue
+    middle = (gap_start + gap_end) / 2
+    label = NO_HOST_EVENT
+    for record in spans:
+      if record.start_ns > middle:
+        break
+      if record.end_ns >= middle:
+        label = record.name
+    out[label] = out.get(label, 0.0) + (gap_end - gap_start) / 1e9
+  return out
+
+
+def _host_device_overlap(xplane_path: str, host_trace: Dict[str, object]):
+  """The capture's idle gaps by what the capturing thread was doing, or
+  None where the two clocks cannot be tied: no device plane (CPU), or no
+  marker execution in it."""
+  from tensor2robot_tpu.utils import xplane
+
+  lines = xplane.timed_events(xplane_path)
+  marker_ends = [start + duration
+                 for name, start, duration in lines.get('XLA Modules', [])
+                 if CLOCK_MARKER in name]
+  if not marker_ends or not lines.get('XLA Ops'):
+    return None
+  marker_end = min(marker_ends)
+  # trace clock + offset = the ring's clock.
+  offset_ns = float(host_trace['marker_done_ns']) - marker_end
+  busy: List[List[float]] = []
+  for _, start, duration in sorted(lines['XLA Ops'], key=lambda e: e[1]):
+    if start <= marker_end:  # the marker is neither work nor the start
+      continue
+    start, end = start + offset_ns, start + duration + offset_ns
+    if busy and start <= busy[-1][1]:
+      busy[-1][1] = max(busy[-1][1], end)
+    else:
+      busy.append([start, end])
+  if not busy:
+    return None
+  thread = host_trace.get('thread')
+  gaps = name_idle_gaps(
+      busy, [r for r in host_trace['records'] if r.thread == thread])
+  busy_ms = sum(end - start for start, end in busy) / 1e6
+  extent_ms = (busy[-1][1] - busy[0][0]) / 1e6
+  return {
+      'device_busy_ms': busy_ms,
+      'device_extent_ms': extent_ms,
+      # Device idle inside its own active window == time the host
+      # failed to keep it fed (dispatch gaps, data waits).
+      'device_idle_fraction': 1.0 - min(busy_ms / max(extent_ms, 1e-9), 1.0),
+      'idle_gaps_ms': {name: seconds * 1e3 for name, seconds in sorted(
+          gaps.items(), key=lambda kv: -kv[1])},
+      'clock_offset_ns': offset_ns,
+      'host_thread': thread,
+  }
 
 
 _COLLECTIVE_TOKENS = ('all-reduce', 'all-gather', 'all-to-all',
@@ -285,7 +355,8 @@ def build_report(step: int,
                  registry: Optional[registry_lib.TelemetryRegistry] = None,
                  tuned_config: Optional[str] = None,
                  pipeline: Optional[Dict[str, object]] = None,
-                 host: Optional[Dict[str, object]] = None
+                 host: Optional[Dict[str, object]] = None,
+                 host_trace: Optional[Dict[str, object]] = None
                  ) -> Dict[str, object]:
   """Assembles the forensics report dict. Never raises: torn captures,
   missing HLO, or reader bugs each degrade to a ``warnings`` entry.
@@ -298,7 +369,12 @@ def build_report(step: int,
   data-path incident's report names the stage, not just the symptom.
   ``host``: this process's fleet identity (``signals.host_identity()``)
   — with the ``collective_wait`` split below, a straggler capture names
-  WHICH host gated WHICH collective, not just that a step got slow."""
+  WHICH host gated WHICH collective, not just that a step got slow.
+  ``host_trace``: ``{'records', 'marker_done_ns', 'thread'}`` from the
+  ``AutoProfiler`` — the span ring's records of the captured interval
+  (carried as ``host_spans``), the ring-clock time at which the clock
+  marker ended, and the capturing thread, whose spans name the device's
+  idle gaps (``host_device_overlap``; absent without a device plane)."""
   registry = registry or registry_lib.get_registry()
   warnings: List[str] = []
   report: Dict[str, object] = {
@@ -312,6 +388,7 @@ def build_report(step: int,
       'top_ops': [],
       'device_occupancy': None,
       'host_device_overlap': None,
+      'host_spans': [],
       'collectives': {},
       'collective_bytes_total': 0,
       'collective_wait': None,
@@ -334,15 +411,21 @@ def build_report(step: int,
     warnings.append('no xplane capture found for this window')
   else:
     try:
-      top_ops, occupancy, overlap, op_warnings, families = \
+      top_ops, occupancy, op_warnings, families = \
           _device_top_ops(xplane_path, max(n_steps, 1), DEFAULT_TOP_K)
       report['top_ops'] = top_ops
       report['device_occupancy'] = occupancy
-      report['host_device_overlap'] = overlap
       warnings.extend(op_warnings)
+      if host_trace is not None:
+        report['host_device_overlap'] = _host_device_overlap(
+            xplane_path, host_trace)
     except Exception as e:  # noqa: BLE001 — torn/truncated capture
       warnings.append('xplane analysis failed ({}: {}); raw capture kept '
                       'at {}'.format(type(e).__name__, e, xplane_path))
+  if host_trace is not None:
+    report['host_spans'] = [
+        record._asdict()
+        for record in host_trace['records'][-_MAX_HOST_SPANS:]]
   hlo_collectives = None
   hlo_text = None
   if hlo_text_fn is not None:

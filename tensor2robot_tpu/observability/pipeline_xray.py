@@ -16,7 +16,7 @@ into (docs/observability.md "Pipeline X-ray"):
                    Python ExampleParser fallback.
   * ``batch``    — batch assembly/handoff: the generators' prefetch
                    producers (data/input_generators.py); the native
-                   stream's pack cost is the ``pipeline/batch/pack_ms``
+                   stream's pack cost is the ``data.pack`` span's
                    histogram (busy-only — its rows are already counted
                    by the decode stage).
   * ``transfer`` — the host->device hop: ``data/device_feed.py``

@@ -127,7 +127,7 @@ class CheckpointManager:
     # The span holds only the SYNCHRONOUS portion; with async
     # checkpointing the background commit is invisible here (the trainer
     # sees it at wait_until_finished).
-    with span('ckpt.save'):
+    with span('ckpt.save', step=int(step)):
       return retry(_save, self._retry_policy,
                    site=fault_injection.SITE_CKPT_SAVE)
 

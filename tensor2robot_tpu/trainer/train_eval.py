@@ -19,9 +19,11 @@ and the model_fn skeleton it drives (/root/reference/models/abstract_model.py
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import sys
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -40,6 +42,7 @@ from tensor2robot_tpu.observability import (
     TelemetryLogger,
     Watchdog,
     WatchdogConfig,
+    event,
     get_registry,
     span,
 )
@@ -85,6 +88,76 @@ def _json_scalar(value):
     return None
   value = float(np.mean(value))
   return value if np.isfinite(value) else None
+
+
+class _StepWatcher:
+  """Records WHEN steps finish on the device, with no profiler.
+
+  The training thread hands over a leaf of each step's ``metrics`` output
+  (never the state: that is donated to the next step) as it dispatches;
+  this daemon thread waits for one step at a time and appends the event
+  ``train.step_done`` (``step``, ``steps_covered``) to the span ring, on
+  the clock of every other span. It waits for the OLDEST pending step —
+  in a device-bound loop the host leads by several steps and each is seen
+  as it ends — unless that one is already done while newer ones wait: then
+  this thread has fallen behind a fast loop, and it skips to the first
+  step still running (or the newest), so it samples, one wake-up at a
+  time. ``steps_covered`` says how many steps an event stands for.
+
+  With ``train.step``'s end (step n dispatched) and ``train.step_done`` of
+  step n-1 a reader knows the interval between completions, how far the
+  host leads, and the time the device had nothing to run,
+  ``max(0, dispatched(n) - done(n-1))``.
+  """
+
+  def __init__(self):
+    self._cond = threading.Condition()
+    self._pending: collections.deque = collections.deque()
+    self._stopped = False
+    self._thread = threading.Thread(target=self._run, daemon=True,
+                                    name='t2r-step-watch')
+    self._thread.start()
+
+  def submit(self, step: int, metrics) -> None:
+    leaves = jax.tree_util.tree_leaves(metrics)
+    if not leaves:
+      return
+    with self._cond:
+      self._pending.append((step, leaves[0]))
+      self._cond.notify()
+
+  def stop(self) -> None:
+    """Ends the thread; called on every exit path of ``train``. A step
+    still running is waited out (the caller is about to sync anyway)."""
+    with self._cond:
+      self._stopped = True
+      self._pending.clear()
+      self._cond.notify()
+    self._thread.join(timeout=60.0)
+    if self._thread.is_alive():
+      _log('Step watcher still waiting for the device after 60 s; left '
+           'behind as a daemon thread.')
+
+  def _run(self) -> None:
+    last_step = None
+    while True:
+      with self._cond:
+        while not self._pending and not self._stopped:
+          self._cond.wait()
+        if self._stopped:
+          return
+        step, leaf = self._pending.popleft()
+        while self._pending and leaf.is_ready():
+          step, leaf = self._pending.popleft()
+      try:
+        jax.block_until_ready(leaf)
+      except Exception:  # noqa: BLE001 — a failed step ends no other way
+        continue
+      # After a rollback the step counter runs backwards: such an event
+      # stands for itself alone.
+      covered = step - last_step if last_step is not None else 1
+      event('train.step_done', step=step, steps_covered=max(covered, 1))
+      last_step = step
 
 
 def provide_input_generator_with_model_information(
@@ -997,10 +1070,16 @@ class Trainer:
                   total - data_s - ckpt_s - retry_s)
 
     with graceful_shutdown() as shutdown:
+      step_watcher = _StepWatcher()
       try:
         while step_i < max_train_steps:
           iter_start = time.perf_counter()
           data_s = ckpt_s = retry_s = 0.0
+          # One pass of the loop under one name, so that every second of
+          # the training thread has one (its self time is the loop's own
+          # overhead); closed in the finally below on every exit path.
+          iteration = span('train.iteration', step=step_i + 1)
+          iteration.__enter__()
           # try/finally, not explicit commit calls: an iteration that
           # exits via continue, preemption, OR an exception (NaN raise,
           # corruption budget, retry exhaustion — often the longest,
@@ -1022,7 +1101,7 @@ class Trainer:
                     'roofline', step=step_i,
                     **roofline_lib.telemetry_payload(roofline_record))
               telemetry.flush()
-            with span('data.put_batch') as sp:
+            with span('data.put_batch', step=step_i + 1) as sp:
               if pipelined is not None:
                 # Blocks only while the buffer is EMPTY — the producer
                 # thread owns decode + transfer; transfer telemetry and
@@ -1038,13 +1117,15 @@ class Trainer:
             force_nan = np.asarray(
                 fault_injection.fires(fault_injection.SITE_STEP_NAN))
             # NOTE: the step span measures dispatch, not device compute —
-            # jax returns before the XLA program finishes. Device time
-            # comes from the profiler trace (utils/xplane.py); host-side
-            # blocking (donated-buffer backpressure) does land here.
-            with span('train.step'):
+            # jax returns before the XLA program finishes; host-side
+            # blocking (donated-buffer backpressure) does land here. When
+            # the step FINISHED on the device is the train.step_done event
+            # of the same ``step``, recorded by the watcher thread.
+            with span('train.step', step=step_i + 1):
               state, metrics = step_fn(state, device_batch['features'],
                                        device_batch['labels'], base_rng,
                                        force_nan)
+            step_watcher.submit(step_i + 1, metrics)
             # The 'step.slow' injection site: a host-side stall the
             # watchdog must detect as a step-time regression — charged
             # to productive time exactly like a real slowdown would be.
@@ -1091,131 +1172,136 @@ class Trainer:
                 steps_since_log = 0
                 t_last = time.perf_counter()
                 if pipelined is None:
-                  with span('data.next') as sp:
+                  with span('data.next', step=step_i + 1) as sp:
                     batch = next(iterator)
                   retry_s += sp.elapsed
                 continue
             if (step_i % self.log_every_n_steps == 0
                 or step_i == max_train_steps):
-              metrics = jax.device_get(dict(metrics))
-              dt = time.perf_counter() - t_last
-              examples_per_sec = batch_size * steps_since_log / max(dt, 1e-9)
-              step_time_s = dt / max(steps_since_log, 1)
-              self._throughput = (examples_per_sec, step_time_s)
-              _log('step %d: loss=%s (%.1f examples/sec)', step_i,
-                   metrics.get('loss'), examples_per_sec)
-              # Performance-forensics sampling, BEFORE the exports so
-              # the same window's watermarks/anomaly counters land in
-              # this very TensorBoard write and telemetry record.
-              signals_lib.sample_memory(registry)
-              self._sample_recompiles(registry)
-              # Live MFU ledger: gauges land BEFORE the watchdog pass so
-              # mfu_regression sees this very window's utilization, and
-              # before the exports so TensorBoard + telemetry carry it.
-              self._publish_perf(registry, step_time_s)
-              pipeline_record = None
-              if self._xray is not None:
-                # X-ray before watchdog: a data-path incident should
-                # claim the capture under its pipeline kind (with the
-                # stage attribution in the trigger), not as the generic
-                # step_time_regression the same stall also causes.
-                pipeline_record, pipeline_anomalies = self._xray.observe(
-                    step_i, examples=batch_size * steps_since_log,
-                    window_seconds=dt,
-                    goodput_seconds=tracker.seconds())
-                for anomaly in pipeline_anomalies:
-                  _log('Pipeline X-ray anomaly: %s', anomaly.message)
-                  if telemetry is not None:
-                    telemetry.log('anomaly', step=step_i,
-                                  anomaly=anomaly.kind,
-                                  message=anomaly.message,
-                                  detail=anomaly.detail)
-                  self._auto_profiler.request_capture(
-                      anomaly.kind, step_i, anomaly.detail)
-              fleet_record = None
-              if self.fleet_observer is not None:
-                # Fleet before watchdog: a straggler IS a step-time
-                # regression locally, but the fleet kind carries the
-                # host attribution — it should claim the capture.
-                fleet_record, fleet_anomalies = \
-                    self.fleet_observer.observe(
-                        step_i, step_time_s=step_time_s,
-                        examples_per_sec=examples_per_sec,
-                        productive_fraction=tracker.fractions().get(
-                            'productive'))
-                for anomaly in fleet_anomalies:
-                  _log('Fleet anomaly: %s', anomaly.message)
-                  if telemetry is not None:
-                    telemetry.log('anomaly', step=step_i,
-                                  anomaly=anomaly.kind,
-                                  message=anomaly.message,
-                                  detail=anomaly.detail)
-                  self._auto_profiler.request_capture(
-                      anomaly.kind, step_i, anomaly.detail)
-              if self._watchdog is not None:
-                for anomaly in self._watchdog.observe(
-                    step_i, step_time_s, tracker.seconds()):
-                  _log('Watchdog anomaly: %s', anomaly.message)
-                  if telemetry is not None:
-                    telemetry.log('anomaly', step=step_i,
-                                  anomaly=anomaly.kind,
-                                  message=anomaly.message,
-                                  detail=anomaly.detail)
-                  self._auto_profiler.request_capture(
-                      anomaly.kind, step_i, anomaly.detail)
-              writer = self.train_metrics_writer
-              if writer is not None:
-                scalars = {k: float(np.mean(v)) for k, v in metrics.items()
-                           if np.ndim(v) == 0}
-                scalars['global_step/sec'] = 1.0 / max(
-                    dt / max(steps_since_log, 1), 1e-9)
-                scalars['examples/sec'] = examples_per_sec
-                # The unified telemetry pipeline: every registry counter/
-                # gauge/histogram-summary (quarantine, retries, rollbacks,
-                # span and inference latencies) plus the goodput split —
-                # tolerated damage and lost wall-clock are never invisible.
-                scalars.update(registry.scalars())
-                scalars.update(tracker.scalars())
-                writer.write_scalars(step_i, scalars)
-                writer.flush()
-              if telemetry is not None:
-                snapshot = registry.snapshot()
-                # Gauges ride along so offline tooling (doctor) can
-                # compute across SAMPLES — "prefetch queue empty in 81%
-                # of samples" needs the series, not the last value.
-                telemetry.log('train', step=step_i,
-                              loss=_json_scalar(metrics.get('loss')),
-                              examples_per_sec=examples_per_sec,
-                              step_time_s=step_time_s,
-                              goodput=tracker.fractions(),
-                              goodput_seconds=tracker.seconds(),
-                              counters=snapshot['counters'],
-                              gauges=snapshot['gauges'])
-                if pipeline_record is not None:
-                  # The t2r.pipeline.v1 attribution record: gating stage
-                  # + headroom vs. the device rate, per log window.
-                  telemetry.log('pipeline', step=step_i, **pipeline_record)
-                if fleet_record is not None:
-                  # The t2r.fleet.v1 federation record: per-host table,
-                  # skew, gating host, fleet-min goodput, per window.
-                  telemetry.log('fleet', step=step_i, **fleet_record)
-                # Window stats ride the heartbeat so a peer's
-                # FleetObserver can read the whole fleet's health from
-                # N tiny atomic files instead of N telemetry re-parses.
-                telemetry.heartbeat(
-                    step_i, step_time_s=step_time_s,
-                    examples_per_sec=examples_per_sec,
-                    productive_fraction=tracker.fractions().get(
-                        'productive'))
-                telemetry.flush()
+              # The log window under one name: the fetch of the metrics
+              # (a device sync) through the exports to telemetry.flush().
+              with span('train.log_window', step=step_i):
+                metrics = jax.device_get(dict(metrics))
+                dt = time.perf_counter() - t_last
+                examples_per_sec = batch_size * steps_since_log / max(dt, 1e-9)
+                step_time_s = dt / max(steps_since_log, 1)
+                self._throughput = (examples_per_sec, step_time_s)
+                _log('step %d: loss=%s (%.1f examples/sec)', step_i,
+                     metrics.get('loss'), examples_per_sec)
+                # Performance-forensics sampling, BEFORE the exports so
+                # the same window's watermarks/anomaly counters land in
+                # this very TensorBoard write and telemetry record.
+                signals_lib.sample_memory(registry)
+                self._sample_recompiles(registry)
+                # Live MFU ledger: gauges land BEFORE the watchdog pass so
+                # mfu_regression sees this very window's utilization, and
+                # before the exports so TensorBoard + telemetry carry it.
+                self._publish_perf(registry, step_time_s)
+                pipeline_record = None
+                if self._xray is not None:
+                  # X-ray before watchdog: a data-path incident should
+                  # claim the capture under its pipeline kind (with the
+                  # stage attribution in the trigger), not as the generic
+                  # step_time_regression the same stall also causes.
+                  pipeline_record, pipeline_anomalies = self._xray.observe(
+                      step_i, examples=batch_size * steps_since_log,
+                      window_seconds=dt,
+                      goodput_seconds=tracker.seconds())
+                  for anomaly in pipeline_anomalies:
+                    _log('Pipeline X-ray anomaly: %s', anomaly.message)
+                    if telemetry is not None:
+                      telemetry.log('anomaly', step=step_i,
+                                    anomaly=anomaly.kind,
+                                    message=anomaly.message,
+                                    detail=anomaly.detail)
+                    self._auto_profiler.request_capture(
+                        anomaly.kind, step_i, anomaly.detail)
+                fleet_record = None
+                if self.fleet_observer is not None:
+                  # Fleet before watchdog: a straggler IS a step-time
+                  # regression locally, but the fleet kind carries the
+                  # host attribution — it should claim the capture.
+                  fleet_record, fleet_anomalies = \
+                      self.fleet_observer.observe(
+                          step_i, step_time_s=step_time_s,
+                          examples_per_sec=examples_per_sec,
+                          productive_fraction=tracker.fractions().get(
+                              'productive'))
+                  for anomaly in fleet_anomalies:
+                    _log('Fleet anomaly: %s', anomaly.message)
+                    if telemetry is not None:
+                      telemetry.log('anomaly', step=step_i,
+                                    anomaly=anomaly.kind,
+                                    message=anomaly.message,
+                                    detail=anomaly.detail)
+                    self._auto_profiler.request_capture(
+                        anomaly.kind, step_i, anomaly.detail)
+                if self._watchdog is not None:
+                  for anomaly in self._watchdog.observe(
+                      step_i, step_time_s, tracker.seconds()):
+                    _log('Watchdog anomaly: %s', anomaly.message)
+                    if telemetry is not None:
+                      telemetry.log('anomaly', step=step_i,
+                                    anomaly=anomaly.kind,
+                                    message=anomaly.message,
+                                    detail=anomaly.detail)
+                    self._auto_profiler.request_capture(
+                        anomaly.kind, step_i, anomaly.detail)
+                writer = self.train_metrics_writer
+                if writer is not None:
+                  scalars = {k: float(np.mean(v)) for k, v in metrics.items()
+                             if np.ndim(v) == 0}
+                  scalars['global_step/sec'] = 1.0 / max(
+                      dt / max(steps_since_log, 1), 1e-9)
+                  scalars['examples/sec'] = examples_per_sec
+                  # The unified telemetry pipeline: every registry counter/
+                  # gauge/histogram-summary (quarantine, retries, rollbacks,
+                  # span and inference latencies) plus the goodput split —
+                  # tolerated damage and lost wall-clock are never invisible.
+                  scalars.update(registry.scalars())
+                  scalars.update(tracker.scalars())
+                  writer.write_scalars(step_i, scalars)
+                  writer.flush()
+                if telemetry is not None:
+                  snapshot = registry.snapshot()
+                  # Gauges ride along so offline tooling (doctor) can
+                  # compute across SAMPLES — "prefetch queue empty in 81%
+                  # of samples" needs the series, not the last value.
+                  telemetry.log('train', step=step_i,
+                                loss=_json_scalar(metrics.get('loss')),
+                                examples_per_sec=examples_per_sec,
+                                step_time_s=step_time_s,
+                                goodput=tracker.fractions(),
+                                goodput_seconds=tracker.seconds(),
+                                counters=snapshot['counters'],
+                                gauges=snapshot['gauges'])
+                  if pipeline_record is not None:
+                    # The t2r.pipeline.v1 attribution record: gating stage
+                    # + headroom vs. the device rate, per log window.
+                    telemetry.log('pipeline', step=step_i, **pipeline_record)
+                  if fleet_record is not None:
+                    # The t2r.fleet.v1 federation record: per-host table,
+                    # skew, gating host, fleet-min goodput, per window.
+                    telemetry.log('fleet', step=step_i, **fleet_record)
+                  # Window stats ride the heartbeat so a peer's
+                  # FleetObserver can read the whole fleet's health from
+                  # N tiny atomic files instead of N telemetry re-parses.
+                  telemetry.heartbeat(
+                      step_i, step_time_s=step_time_s,
+                      examples_per_sec=examples_per_sec,
+                      productive_fraction=tracker.fractions().get(
+                          'productive'))
+                  telemetry.flush()
               t_last = time.perf_counter()
               steps_since_log = 0
             if step_i % self.save_checkpoints_steps == 0:
               ckpt_t0 = time.perf_counter()
               self.save_checkpoint(state)
               ckpt_s += time.perf_counter() - ckpt_t0
-            for hook in hooks:
-              hook.after_step(self, state, step_i, metrics)
+            if hooks:
+              with span('train.hooks', step=step_i):
+                for hook in hooks:
+                  hook.after_step(self, state, step_i, metrics)
             preempt_signum = None
             if shutdown.requested:
               preempt_signum = int(shutdown.signum)
@@ -1246,13 +1332,15 @@ class Trainer:
                     process_index=self.host_identity.get('process_index'))
               raise TrainingPreempted(preempt_signum, step_i)
             if step_i < max_train_steps and pipelined is None:
-              with span('data.next') as sp:
+              with span('data.next', step=step_i + 1) as sp:
                 batch = next(iterator)
               data_s += sp.elapsed
           finally:
+            iteration.__exit__(None, None, None)
             commit_goodput(iter_start, data_s, ckpt_s, retry_s)
         completed = True
       finally:
+        step_watcher.stop()
         if pipelined is not None:
           # Stop the producer on EVERY exit path — a live thread parked
           # inside the native loader's next() would otherwise race the

@@ -13,7 +13,8 @@ Wire schema subset (tensorflow/tsl profiler xplane.proto):
     XSpace  { repeated XPlane planes = 1; }
     XPlane  { string name = 2; repeated XLine lines = 3;
               map<int64, XEventMetadata> event_metadata = 4; }
-    XLine   { string name = 2; repeated XEvent events = 4; }
+    XLine   { string name = 2; int64 timestamp_ns = 3;
+              repeated XEvent events = 4; }
     XEvent  { int64 metadata_id = 1; int64 offset_ps = 2;
               int64 duration_ps = 3; }
     XEventMetadata { string name = 2; }
@@ -61,13 +62,16 @@ def _parse_event(buf, start, end) -> Tuple[int, int, int]:
 
 def _parse_line(buf, start, end):
   name = ''
+  timestamp_ns = 0
   events: List[Tuple[int, int, int]] = []
   for field, wire, value in _iter_fields(buf, start, end):
     if field == 2 and wire == _WIRE_BYTES:
       name = bytes(buf[value[0]:value[1]]).decode('utf-8', 'replace')
+    elif field == 3 and wire == _WIRE_VARINT:
+      timestamp_ns = value
     elif field == 4 and wire == _WIRE_BYTES:
       events.append(_parse_event(buf, *value))
-  return name, events
+  return name, events, timestamp_ns
 
 
 def _parse_metadata_entry(buf, start, end) -> Tuple[int, str]:
@@ -98,9 +102,9 @@ def _parse_plane(buf, start, end):
   return name, lines, metadata
 
 
-def parse_xspace(path: str):
-  """[(plane_name, [(line_name, [(metadata_id, duration_ps,
-  offset_ps)])], meta)]."""
+def _parse_planes(path: str):
+  """As ``parse_xspace``, each line with its ``timestamp_ns`` as a third
+  entry (event offsets count from it)."""
   with open(path, 'rb') as f:
     buf = f.read()
   planes = []
@@ -108,6 +112,35 @@ def parse_xspace(path: str):
     if field == 1 and wire == _WIRE_BYTES:
       planes.append(_parse_plane(buf, *value))
   return planes
+
+
+def parse_xspace(path: str):
+  """[(plane_name, [(line_name, [(metadata_id, duration_ps,
+  offset_ps)])], meta)]."""
+  return [(name, [line[:2] for line in lines], metadata)
+          for name, lines, metadata in _parse_planes(path)]
+
+
+def timed_events(path: str, plane_substr: str = 'TPU'
+                 ) -> Dict[str, List[Tuple[str, float, float]]]:
+  """{line name: [(event name, start_ns, duration_ns)]} of the FIRST plane
+  (by name) matching ``plane_substr``, every line on the capture's one
+  clock (line timestamp + event offset). {} when no plane matches.
+
+  One plane on purpose: a chip's ``XLA Ops`` (serial device stream) and
+  ``XLA Modules`` (one event per program execution) lines share a clock,
+  which is what lining host records up against device idle gaps needs.
+  """
+  planes = sorted((p for p in _parse_planes(path) if plane_substr in p[0]),
+                  key=lambda p: p[0])
+  if not planes:
+    return {}
+  _, lines, metadata = planes[0]
+  return {
+      line_name: [(metadata.get(metadata_id, str(metadata_id)),
+                   timestamp_ns + offset_ps / 1e3, duration_ps / 1e3)
+                  for metadata_id, duration_ps, offset_ps in events]
+      for line_name, events, timestamp_ns in lines}
 
 
 def op_totals(path: str,

@@ -1,0 +1,143 @@
+"""The input producer in the span ring: the native stream's ``data.ring_wait``
+and ``data.pack``, the ``data.loader_stats`` event with the reader's split
+wait, and the prefetch hand-over's ``data.handoff_wait``."""
+
+import time
+
+import numpy as np
+import pytest
+
+from tensor2robot_tpu import observability as obs
+from tensor2robot_tpu.data import native_loader, tfrecord
+from tensor2robot_tpu.data.input_generators import prefetch_iterator
+from tensor2robot_tpu.data.wire import build_example
+from tensor2robot_tpu.observability import spans
+from tensor2robot_tpu.specs.struct import SpecStruct
+from tensor2robot_tpu.specs.tensor_spec import TensorSpec
+
+PRODUCER_SPANS = ('data.ring_wait', 'data.pack', 'data.handoff_wait')
+
+
+@pytest.fixture(autouse=True)
+def fresh_registry():
+  previous = obs.set_registry(obs.TelemetryRegistry())
+  yield obs.get_registry()
+  obs.set_registry(previous)
+
+
+@pytest.fixture
+def mark():
+  spans.event('test.mark')
+  return max(r.id for r in spans.records())
+
+
+def _stream(tmp_path, records=64, batch_size=4, width=4096, **kwargs):
+  features = SpecStruct(vec=TensorSpec((width,), np.float32, name='vec'))
+  labels = SpecStruct(target=TensorSpec((1,), np.float32, name='target'))
+  rng = np.random.RandomState(0)
+  path = str(tmp_path / 'data.tfrecord')
+  tfrecord.write_records(path, [build_example({
+      'vec': rng.rand(width).astype(np.float32),
+      'target': np.asarray([i * 0.5], np.float32),
+  }) for i in range(records)])
+  plan = native_loader.plan_for_specs(features, labels)
+  kwargs.setdefault('num_epochs', 1)
+  return native_loader.NativeBatchedStream(
+      plan, [path], batch_size=batch_size, num_threads=2, **kwargs)
+
+
+def test_ring_wait_and_pack_bracket_every_batch(tmp_path, mark):
+  stream = _stream(tmp_path, records=12)
+  batches = list(stream)
+  stream.close()
+  records = spans.records(since_id=mark)
+  waits = [r for r in records if r.name == 'data.ring_wait']
+  packs = [r for r in records if r.name == 'data.pack']
+  # One wait per batch and one more that finds the end of the data.
+  assert [r.attrs['batch'] for r in waits] == [0, 1, 2, 3]
+  assert [r.attrs['batch'] for r in packs] == [0, 1, 2]
+  for pack, (features, labels) in zip(packs, batches):
+    assert pack.attrs['bytes'] == (features['vec'].nbytes +
+                                   labels['target'].nbytes)
+  for wait, pack in zip(waits, packs):
+    assert wait.end_ns <= pack.start_ns
+  # The span's histogram took the place of pipeline/batch/pack_ms.
+  scalars = obs.get_registry().scalars()
+  assert scalars['span/data.pack/count'] == 3.0
+  assert scalars['span/data.ring_wait/count'] == 4.0
+  assert not [tag for tag in scalars if 'pack_ms' in tag]
+
+
+def test_split_reader_waits_sum_to_reader_wait(tmp_path):
+  # A ring of two slots and a slow consumer: the reader waits for a slot.
+  stream = _stream(tmp_path, records=64)
+  for _ in stream:
+    time.sleep(0.005)
+  stats = stream.stats()
+  stream.close()
+  assert native_loader._STAT_NAMES[-2:] == ('reader_wait_slot_us',
+                                            'reader_wait_space_us')
+  assert stats['reader_wait_us'] == (stats['reader_wait_slot_us'] +
+                                     stats['reader_wait_space_us'])
+  assert stats['reader_wait_slot_us'] > 0
+  assert stats['reader_wait_space_us'] >= 0
+
+
+def test_loader_stats_events_carry_the_deltas_of_the_counters(tmp_path, mark):
+  stream = _stream(tmp_path, records=32)
+  batches = 0
+  for _ in stream:
+    batches += 1
+  stats = stream.stats()
+  stream.close()
+  events = [r for r in spans.records(since_id=mark)
+            if r.name == 'data.loader_stats']
+  # One per batch, and the one that publishes what was left at the end.
+  assert len(events) == batches + 1
+  assert set(events[0].attrs) == {
+      'reader_busy_s', 'reader_wait_slot_s', 'reader_wait_space_s',
+      'worker_busy_s', 'worker_idle_s', 'workers', 'records', 'bytes'}
+  total = lambda key: sum(e.attrs[key] for e in events)
+  assert total('records') == stats['records_read'] == 32
+  assert total('bytes') == stats['bytes_read']
+  assert all(e.attrs['workers'] == 2 for e in events[1:])
+  for key, counter in (('reader_busy_s', 'reader_busy_us'),
+                       ('reader_wait_slot_s', 'reader_wait_slot_us'),
+                       ('reader_wait_space_s', 'reader_wait_space_us'),
+                       ('worker_busy_s', 'worker_busy_us'),
+                       ('worker_idle_s', 'worker_idle_us')):
+    # The stats read after the last event may have moved on a little.
+    assert total(key) <= stats[counter] / 1e6 + 1e-9
+    assert total(key) >= 0
+
+
+def test_the_three_producer_spans_partition_the_producer_threads_time(
+    tmp_path, mark):
+  stream = _stream(tmp_path, records=512, batch_size=8, num_epochs=None)
+  iterator = prefetch_iterator(iter(stream), depth=2, label='test')
+  try:
+    for _ in range(30):
+      next(iterator)
+      time.sleep(0.03)  # a slower consumer: the hand-over waits
+  finally:
+    iterator.close()
+  records = [r for r in spans.records(since_id=mark)
+             if r.name in PRODUCER_SPANS]
+  assert {r.thread for r in records} == {'t2r-prefetch'}
+  assert {r.name for r in records} == set(PRODUCER_SPANS)
+  handoffs = [r for r in records if r.name == 'data.handoff_wait']
+  assert [r.attrs['batch'] for r in handoffs] == list(range(len(handoffs)))
+  # From the first wait for the loader to the last hand-over, the three
+  # spans cover the thread's time: what lies between them (stats, queue
+  # bookkeeping, the generator's own frames) is under 3%.
+  start = min(r.start_ns for r in records)
+  end = max(r.end_ns for r in handoffs)
+  covered = sum(min(r.end_ns, end) - r.start_ns for r in records
+                if r.start_ns < end)
+  assert 0.97 <= covered / (end - start) <= 1.0
+  # No two of them are open at once on that thread.
+  ordered = sorted(records, key=lambda r: r.start_ns)
+  for a, b in zip(ordered[:-1], ordered[1:]):
+    assert a.end_ns <= b.start_ns
+  time.sleep(0.3)
+  stream.close()

@@ -1,7 +1,7 @@
 """Telemetry-layer coverage (ISSUE 3 acceptance tests).
 
 Registry thread-safety under concurrent writers, histogram percentile
-math against numpy, span→TraceAnnotation gating, goodput fractions over
+math against numpy, span histograms, goodput fractions over
 a real (CPU) training run landing in BOTH TensorBoard events and
 telemetry.jsonl, predictor latency histograms, and the t2r_telemetry
 CLI smoke test.
@@ -178,39 +178,6 @@ class TestSpans:
     assert work(1) == 2
     assert work(2) == 3
     assert fresh_registry.scalars()['span/unit.decorated/count'] == 2.0
-
-  def test_trace_annotation_only_when_trace_active(self, fresh_registry,
-                                                   monkeypatch):
-    entered = []
-
-    class FakeAnnotation:
-
-      def __init__(self, name):
-        self.name = name
-
-      def __enter__(self):
-        entered.append(self.name)
-        return self
-
-      def __exit__(self, *exc):
-        return False
-
-    monkeypatch.setattr(jax.profiler, 'TraceAnnotation', FakeAnnotation)
-    assert not obs.trace_active()
-    with obs.span('quiet'):
-      pass
-    assert entered == []  # no trace window: pure-host timing only
-    obs.set_trace_active(True)
-    try:
-      with obs.span('loud'):
-        pass
-    finally:
-      obs.set_trace_active(False)
-    assert entered == ['loud']
-    # Both spans still landed in histograms regardless of the trace.
-    scalars = fresh_registry.scalars()
-    assert scalars['span/quiet/count'] == 1.0
-    assert scalars['span/loud/count'] == 1.0
 
 
 # -- goodput ------------------------------------------------------------------

@@ -300,7 +300,7 @@ class TestNativeLoaderStats:
     assert scalars['pipeline/decode/examples'] == 12.0
     assert scalars['pipeline/read/bytes'] == stats['bytes_read']
     assert scalars[xray_lib.DECODE_WORKERS_GAUGE] == 2.0
-    assert scalars['pipeline/batch/pack_ms/count'] == 3.0
+    assert scalars['span/data.pack/count'] == 3.0
 
 
 class TestPythonPipelineStages:
