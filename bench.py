@@ -175,7 +175,7 @@ def _bench_host_pipeline(model, batch_size: int, record_path: str,
   for threads in thread_counts:
     stream = native_loader.NativeBatchedStream(
         plan, [record_path], batch_size=batch_size, shuffle=True, seed=0,
-        num_threads=threads, copy=False, validate=False)
+        num_threads=threads, validate=False)
     it = iter(stream)
     next(it)  # warm: open files, spin up workers
     seen, t0 = 0, time.time()
@@ -225,7 +225,7 @@ def _bench_host_sequence_records(tmp_dir: str, num_records: int = 512,
                                       sequence_max_len=steps)
   stream = native_loader.NativeBatchedStream(
       plan, [path], batch_size=batch_size, shuffle=True, seed=0,
-      num_threads=1, copy=False, validate=False)
+      num_threads=1, validate=False)
   it = iter(stream)
   next(it)  # warm
   seen, t0 = 0, time.time()
@@ -404,7 +404,7 @@ def _bench_e2e_from_disk(model_factory, mesh, batch_size: int,
                                       image_mode='coef_packed')
   stream = native_loader.NativeBatchedStream(
       plan, [record_path], batch_size=batch_size, shuffle=True, seed=0,
-      copy=True, validate=False)
+      validate=False)
   native_it = iter(stream)
 
   def _to_batch(parsed):
@@ -543,7 +543,7 @@ def _bench_replay(model_factory, mesh, batch_size: int, record_path: str,
                                       image_mode='coef_packed')
   stream = native_loader.NativeBatchedStream(
       plan, [record_path], batch_size=batch_size, shuffle=True, seed=0,
-      copy=True, validate=False)
+      validate=False)
   blobs = []
   wire_bytes = 0
   try:
@@ -876,7 +876,7 @@ def _bench_host_varlen(tmp_dir: str, num_records: int = 512,
   plan = native_loader.plan_for_specs(features, SpecStruct())
   stream = native_loader.NativeBatchedStream(
       plan, {'': [main_path], 'aux': [aux_path]}, batch_size=batch_size,
-      shuffle=True, seed=0, num_threads=1, copy=False, validate=False)
+      shuffle=True, seed=0, num_threads=1, validate=False)
   it = iter(stream)
   next(it)  # warm
   seen, t0 = 0, time.time()
@@ -1088,11 +1088,11 @@ def _bench_qtopt_convergence(mesh, on_tpu: bool, batch_size: int = 64,
                         num_examples=2 * batch_size, seed=1)
     stream = native_loader.NativeBatchedStream(
         plan, [train_path], batch_size=batch_size, shuffle=True, seed=0,
-        copy=True, validate=False)
+        validate=False)
     train_it = iter(stream)
     held_stream = native_loader.NativeBatchedStream(
         plan, [held_path], batch_size=batch_size, shuffle=False,
-        num_epochs=1, copy=True, validate=False)
+        num_epochs=1, validate=False)
     held = [(f, l) for f, l in held_stream]
     held_stream.close()
 
@@ -1260,7 +1260,7 @@ def _bench_qtopt_offpolicy(mesh, on_tpu: bool, batch_size: int = 32,
 
     stream = native_loader.NativeBatchedStream(
         plan, records, batch_size=batch_size, shuffle=True, seed=0,
-        copy=True, validate=False)
+        validate=False)
     train_it = iter(stream)
 
     trainer = Trainer(model, os.path.join(tmp, 'run'), mesh=mesh,

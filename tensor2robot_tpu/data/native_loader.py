@@ -36,6 +36,7 @@ import glob
 import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -423,13 +424,22 @@ def plan_for_specs(feature_spec, label_spec,
                           dataset_keys=dataset_keys)
 
 
+def _refcounts(arrays: List[np.ndarray]) -> List[int]:
+  return [sys.getrefcount(a) for a in arrays]
+
+
+# What _refcounts reads for an array that its list alone references.
+_UNREFERENCED = _refcounts([np.empty(0, np.uint8)])[0]
+
+
 class NativeBatchedStream:
   """Iterator of (features, labels) batches from the native loader.
 
-  Matches BatchedExampleStream's contract (data/pipeline.py:129). With
-  ``copy=False`` the yielded arrays are zero-copy views into the loader's
-  ring buffers, valid until ``ring - 1`` further batches have been drawn;
-  the default ``copy=True`` hands out owned arrays.
+  Matches BatchedExampleStream's contract (data/pipeline.py:129). A batch
+  is the consumer's for as long as it holds it, or anything made over it
+  (a slice, a device array aliasing host memory): the arrays are views of
+  buffers this stream owns, and ``_pack`` writes a buffer again only when
+  nothing but the stream references it any more.
   """
 
   def __init__(self, plan: NativeLoaderPlan,
@@ -442,7 +452,6 @@ class NativeBatchedStream:
                num_threads: Optional[int] = None,
                ring: int = 3,
                verify_crc: bool = False,
-               copy: bool = True,
                validate: bool = True,
                bucket_sparse: bool = True):
     """``filenames``: a sequence of record paths, or — for a plan whose
@@ -452,7 +461,6 @@ class NativeBatchedStream:
     shortest), exactly like BatchedExampleStream's dataset_map path."""
     self._plan = plan
     self._batch_size = int(batch_size)
-    self._copy = copy
     self._validate = validate
     # Multi-process SPMD callers MUST pass bucket_sparse=False: each host
     # buckets from its OWN batch's max entry count, and divergent per-host
@@ -505,7 +513,8 @@ class NativeBatchedStream:
       raise RuntimeError('native loader: ' + msg)
     self._ring = self._lib.t2r_loader_ring_size(self._handle)
     self._views = self._build_views()
-    self._held_slot = -1
+    # Per buffer of the layout: the owning arrays batches are copied into.
+    self._pools: List[List[np.ndarray]] = [[] for _ in self._views[0]]
     self._closed = False
     # Pipeline X-ray publishing (observability/pipeline_xray.py): the C++
     # loader's cumulative stats become pipeline/{read,decode}/* counter
@@ -665,39 +674,24 @@ class NativeBatchedStream:
     # Sparse coef streams: slice the capacity-sized delta/value buffers to
     # the batch's bucketed max entry count BEFORE they leave the loader —
     # the whole point of the format is that the host->device transfer pays
-    # for actual entries, not capacity padding. The slice-copy makes these
-    # arrays owned regardless of the ``copy`` setting.
+    # for actual entries, not capacity padding.
     buckets: Dict[str, int] = {}
     esc_buckets: Dict[str, int] = {}
     for buf, (f, sub) in enumerate(layout):
       if sub == 'n':
-        if f.kind == _KIND_IMAGE_COEF_PACKED:
-          # Packed wire: f.count is the BYTE capacity of the nibble
-          # stream; its own (finer) bucket granularity.
-          if not self._bucket_sparse:
-            buckets[f.key] = int(f.count)
-            continue
-          max_n = int(self._views[slot][buf].max())
-          buckets[f.key] = max(
-              PACKED_BUCKET,
-              -(-max_n // PACKED_BUCKET) * PACKED_BUCKET)
-          buckets[f.key] = min(buckets[f.key], int(f.count))
-          continue
-        if not self._bucket_sparse:
-          buckets[f.key] = int(f.count)  # full capacity: host-invariant
-          continue
-        max_n = int(self._views[slot][buf].max())
-        buckets[f.key] = max(
-            SPARSE_BUCKET,
-            -(-max_n // SPARSE_BUCKET) * SPARSE_BUCKET)
+        # Packed wire: f.count is the BYTE capacity of the nibble stream;
+        # its own (finer) bucket granularity.
+        grain = (PACKED_BUCKET if f.kind == _KIND_IMAGE_COEF_PACKED
+                 else SPARSE_BUCKET)
+        capacity, into = int(f.count), buckets
       elif sub == 'ne':
-        if not self._bucket_sparse:
-          esc_buckets[f.key] = int(f.count) // 4
-          continue
+        grain, capacity, into = ESCAPE_BUCKET, int(f.count) // 4, esc_buckets
+      else:
+        continue
+      into[f.key] = capacity  # bucket_sparse off: host-invariant
+      if self._bucket_sparse:
         max_n = int(self._views[slot][buf].max())
-        esc_buckets[f.key] = min(
-            max(ESCAPE_BUCKET, -(-max_n // ESCAPE_BUCKET) * ESCAPE_BUCKET),
-            int(f.count) // 4)
+        into[f.key] = min(max(grain, -(-max_n // grain) * grain), capacity)
     # Sequence fields: slice the capacity-padded step dim to the batch's
     # max actual length — the Python parser's pad-to-longest-in-batch
     # semantics (parser.py parse_batch).
@@ -716,6 +710,7 @@ class NativeBatchedStream:
       if sub == 'p' and not self._views[slot][buf].all():
         dropped.add(f.key)
     by_key: Dict[str, np.ndarray] = {}
+    reused = allocated = 0
     for buf, (f, sub) in enumerate(layout):
       arr = self._views[slot][buf]
       if sub in ('len', 'p') or f.key in dropped:
@@ -723,12 +718,10 @@ class NativeBatchedStream:
       if sub in ('n', 'ne') and f.kind == _KIND_IMAGE_COEF_PACKED:
         continue  # host-side bucketing inputs only; the device unpack
                   # needs no counts (padding bytes are no-ops)
-      if sub in ('sd', 'sv'):
+      if sub in ('sd', 'sv', 'pw'):
         # .copy(), NOT ascontiguousarray: when the bucket equals the full
         # capacity the slice is already contiguous and ascontiguousarray
         # would return a live VIEW into the recycled ring buffer.
-        arr = arr[:, :buckets[f.key]].copy()
-      elif sub == 'pw':
         arr = arr[:, :buckets[f.key]].copy()
       elif sub == 'se':
         arr = arr[:, :esc_buckets[f.key]].copy()
@@ -736,8 +729,22 @@ class NativeBatchedStream:
         arr = self._hoisted_quant_table(f, arr)
       elif f.seq_cap > 0 and sub == '':
         arr = arr[:, :seq_max[f.key]].copy()
-      elif self._copy:
-        arr = arr.copy()
+      else:
+        # The same shape in every batch: into a buffer nobody references
+        # any more (every array made over a view holds its owner, numpy
+        # collapses ``.base`` chains), else into a new one. A kept batch is
+        # never written again; its consumer just costs fresh memory.
+        pool = self._pools[buf]
+        counts = _refcounts(pool)
+        if _UNREFERENCED in counts:
+          owner = pool[counts.index(_UNREFERENCED)]
+          reused += 1
+        else:
+          owner = np.empty_like(arr)
+          pool.append(owner)
+          allocated += 1
+        np.copyto(owner, arr)
+        arr = owner.view()  # never the owner itself: its count is the test
       key = f.key if not sub else f.key + '/' + sub
       if sub == '' and f.spec.dtype == bfloat16:
         arr = arr.astype(bfloat16)
@@ -759,7 +766,7 @@ class NativeBatchedStream:
         if len(self._plan.label_spec):
           labels = specs_lib.validate_and_pack(
               self._plan.label_spec, labels, ignore_batch=True)
-    return features, labels
+    return (features, labels), reused, allocated
 
   def _hoisted_quant_table(self, f: _Field, qt: np.ndarray) -> np.ndarray:
     """Batch-uniform quant table, hoisted to ONE [1, 3, 64] wire array.
@@ -796,37 +803,27 @@ class NativeBatchedStream:
         slot = self._lib.t2r_loader_next(self._handle)
       if slot == -1:
         self._publish_stats()
-        self._release_held()
         return
       if slot < 0:
         err = self._lib.t2r_loader_last_error(self._handle)
         raise RuntimeError('native loader: ' +
                            (err or b'?').decode('utf-8', 'replace'))
       try:
-        # Slices, the owned copy, spec validation. The span's histogram is
-        # busy-only: the pack rows are already counted by the decode stage,
-        # so a batch-stage examples counter here would double-count them
-        # in the X-ray capacity table.
+        # Slices, the copy out of the slot, spec validation. The span's
+        # histogram is busy-only: the pack rows are already counted by the
+        # decode stage, so a batch-stage examples counter here would
+        # double-count them in the X-ray capacity table.
         with span('data.pack', batch=batch_index) as sp:
-          batch = self._pack(slot)
+          batch, reused, allocated = self._pack(slot)
           sp.note(bytes=sum(int(getattr(leaf, 'nbytes', 0))
-                            for side in batch for leaf in side.values()))
+                            for side in batch for leaf in side.values()),
+                  reused=reused, allocated=allocated)
         self._publish_stats()
       finally:
-        if self._copy:
-          self._lib.t2r_loader_release(self._handle, slot)
-        else:
-          # Zero-copy: hold this slot until the NEXT batch is drawn so the
-          # consumer can use the views for one full step.
-          self._release_held()
-          self._held_slot = slot
+        self._lib.t2r_loader_release(self._handle, slot)
       batch_index += 1
       yield batch
-
-  def _release_held(self):
-    if self._held_slot >= 0:
-      self._lib.t2r_loader_release(self._handle, self._held_slot)
-      self._held_slot = -1
+      del batch  # or its buffers would read as referenced in the next _pack
 
   def close(self):
     if not self._closed and self._handle:

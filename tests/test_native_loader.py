@@ -5,6 +5,10 @@ the native path must produce byte-identical batches on the same records
 (both decode through libjpeg-turbo, so even JPEG pixels match exactly).
 """
 
+import gc
+import hashlib
+import time
+
 import numpy as np
 import pytest
 
@@ -15,6 +19,7 @@ from tensor2robot_tpu.data.parser import ExampleParser, build_example_for_specs
 from tensor2robot_tpu.data.wire import build_example
 from tensor2robot_tpu.data import native_loader
 from tensor2robot_tpu.modes import ModeKeys
+from tensor2robot_tpu.observability import spans
 from tensor2robot_tpu.specs.struct import SpecStruct
 from tensor2robot_tpu.specs.tensor_spec import TensorSpec, bfloat16
 from tensor2robot_tpu.utils.image import numpy_to_image_string
@@ -180,13 +185,14 @@ class TestNativeStream:
     features, labels = _specs()
     plan = native_loader.plan_for_specs(features, labels)
     stream = native_loader.NativeBatchedStream(
-        plan, [path], batch_size=2, num_epochs=1, copy=False)
+        plan, [path], batch_size=2, num_epochs=1)
     try:
       it = iter(stream)
       feats, _ = next(it)
       first = np.asarray(feats['scalar']).copy()
       np.testing.assert_array_equal(first.ravel(), [0.0, 1.0])
-      next(it)  # previous views may now be recycled; copy was taken above
+      next(it)  # the ring slot is recycled; the batch in hand is not
+      np.testing.assert_array_equal(feats['scalar'], first)
     finally:
       stream.close()
 
@@ -579,6 +585,172 @@ class TestSequenceRecords:
     assert batch_labels['reward'].shape[:2] == batch_features['obs'].shape[:2]
 
 
+def _ownership_stream(kind, tmp_path):
+  """A looping shuffled stream, and a key of it whose array has one shape
+  in every batch (the sequence stream's other fields are cut to the longest
+  episode of the batch)."""
+  path = str(tmp_path / (kind + '.tfrecord'))
+  if kind == 'dense':
+    _write_records(path, 24)
+    plan = native_loader.plan_for_specs(*_specs())
+    key = 'image'
+  else:
+    _write_sequence_records(path, 24)
+    features, labels = _sequence_specs()
+    plan = native_loader.plan_for_specs(
+        specs_lib.add_sequence_length_specs(features), labels,
+        sequence_max_len=8)
+    key = 'is_demo'
+  stream = native_loader.NativeBatchedStream(
+      plan, [path], batch_size=4, shuffle=True, seed=3, shuffle_buffer=24,
+      num_threads=2)
+  return stream, key
+
+
+def _digest(batch):
+  digest = hashlib.sha1()
+  for side in batch:
+    for key in sorted(side):
+      digest.update(key.encode())
+      digest.update(np.ascontiguousarray(side[key]).tobytes())
+  return digest.hexdigest()
+
+
+def _address(array):
+  return array.__array_interface__['data'][0]
+
+
+def _ring_mark():
+  spans.event('test.mark')
+  return max(r.id for r in spans.records())
+
+
+def _packs_since(mark):
+  return [r.attrs for r in spans.records(since_id=mark)
+          if r.name == 'data.pack']
+
+
+@pytest.mark.parametrize('kind', ['dense', 'sequence'])
+class TestBatchOwnership:
+  """A batch is its holder's for as long as it is held, and its memory is
+  the stream's to write again as soon as it is not. A pool that recycles on
+  a fixed round fails every test here."""
+
+  @pytest.fixture
+  def mark(self):
+    return _ring_mark()
+
+  def test_kept_batch_keeps_its_bytes(self, kind, tmp_path):
+    stream, _ = _ownership_stream(kind, tmp_path)
+    try:
+      it = iter(stream)
+      kept = next(it)
+      drawn = _digest(kept)
+      others = set()
+      for _ in range(10):
+        others.add(_digest(next(it)))  # drawn and dropped
+      assert len(others) > 1  # other bytes did go by
+      assert _digest(kept) == drawn
+    finally:
+      stream.close()
+
+  def test_dropped_batch_gives_its_memory_to_the_next(self, kind, tmp_path,
+                                                      mark):
+    stream, key = _ownership_stream(kind, tmp_path)
+    try:
+      it = iter(stream)
+      batch = next(it)
+      address = _address(batch[0][key])
+      pooled = _packs_since(mark)[0]['allocated']
+      assert pooled > 0 and _packs_since(mark)[0]['reused'] == 0
+      for _ in range(5):
+        del batch
+        batch = next(it)
+        assert _address(batch[0][key]) == address
+      for pack in _packs_since(mark)[1:]:
+        assert (pack['reused'], pack['allocated']) == (pooled, 0)
+    finally:
+      stream.close()
+
+  def test_kept_batches_cost_fresh_memory_and_none_is_overwritten(
+      self, kind, tmp_path, mark):
+    stream, key = _ownership_stream(kind, tmp_path)
+    try:
+      kept = []
+      for batch in stream:
+        kept.append((batch, _digest(batch)))
+        if len(kept) == 8:
+          break
+      del batch
+      packs = _packs_since(mark)
+      assert all(pack['allocated'] > 0 for pack in packs)
+      assert all(pack['reused'] == 0 for pack in packs)
+      assert len({_address(batch[0][key]) for batch, _ in kept}) == 8
+      for batch, drawn in kept:
+        assert _digest(batch) == drawn
+    finally:
+      stream.close()
+
+  def test_device_arrays_behind_a_prefetch_queue_keep_their_bytes(
+      self, kind, tmp_path):
+    import jax
+
+    from tensor2robot_tpu.data.input_generators import prefetch_iterator
+
+    # What the seed gives, drawn one at a time and copied at the draw.
+    stream, _ = _ownership_stream(kind, tmp_path)
+    truth = [{k: np.array(v) for k, v in features.items()}
+             for (features, _), _ in zip(stream, range(12))]
+    stream.close()
+    mark = _ring_mark()
+    stream, _ = _ownership_stream(kind, tmp_path)
+    it = prefetch_iterator(iter(stream), depth=2, label='test')
+    try:
+      put = []
+      for n in range(12):
+        features, _ = next(it)
+        # A slow consumer: the producer gets as far ahead as the queue
+        # lets it (two queued, one in its hand) before batch n is put.
+        deadline = time.monotonic() + 5.0
+        while (len(_packs_since(mark)) < n + 4
+               and time.monotonic() < deadline):
+          time.sleep(0.001)
+        assert len(_packs_since(mark)) >= n + 4
+        put.append(jax.device_put(features.to_dict()))
+        del features
+      # Batch n + 5 has been packed for every n checked here.
+      for device, expected in zip(put[:9], truth):
+        assert sorted(device) == sorted(expected)
+        for k, v in expected.items():
+          np.testing.assert_array_equal(np.asarray(device[k]), v, err_msg=k)
+    finally:
+      it.close()
+      stream.close()
+
+  def test_batches_outlive_a_closed_stream(self, kind, tmp_path):
+    stream, _ = _ownership_stream(kind, tmp_path)
+    it = iter(stream)
+    kept, drawn = [], []
+    for _ in range(8):
+      kept.append(next(it))
+      drawn.append(_digest(kept[-1]))
+    it.close()
+    stream.close()
+    del it, stream
+    gc.collect()
+    assert [_digest(batch) for batch in kept] == drawn
+    # Slices of them die later still, each keeping its buffer alive.
+    views = [leaf[:1] for batch in kept for side in batch
+             for leaf in side.values()]
+    copies = [view.copy() for view in views]
+    del kept
+    gc.collect()
+    for view, copy in zip(views, copies):
+      np.testing.assert_array_equal(view, copy)
+    del views
+    gc.collect()
+
+
 class TestSoak:
 
   def test_epoch_coverage_under_parallel_decode(self, tmp_path):
@@ -610,7 +782,7 @@ class TestSoak:
         plan, [str(tmp_path / 'f{}.tfrecord'.format(i))
                for i in range(n_files)],
         batch_size=batch, shuffle=True, seed=11, shuffle_buffer=50,
-        num_epochs=epochs, num_threads=4, copy=False)
+        num_epochs=epochs, num_threads=4)
     seen = []
     try:
       for feats, _ in stream:
