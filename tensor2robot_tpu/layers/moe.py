@@ -36,6 +36,7 @@ E); consumers add ``aux_weight * aux_loss`` to their objective.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -221,3 +222,215 @@ class MoEMlp(nn.Module):
                   P(ep, None, None), P(ep, None, None)),
         out_specs=token_spec, check_rep=False)
     return fn(x, probs, expert_idx, w_in, w_out)
+
+
+# -- the dropless layer -------------------------------------------------------
+#
+# ``MoEMlp`` above routes against a fixed per-expert capacity and drops what
+# overflows. ``DroplessMoE`` below is the other contract: every (token,
+# expert) pair whose expert is held here is computed, however uneven the
+# routing, by grouping the pairs by expert and running grouped matrix
+# products over the groups (parallel/grouped_matmul.py). It is told which
+# experts it holds: the router is as wide as the whole model's, the layer
+# computes its own experts' part of the result, and pairs routed to an
+# absent expert are left out (on one chip of an expert-parallel deployment
+# the other chips add their parts; nothing here stands in for them).
+
+
+def route_top_k(router_logits: jnp.ndarray, top_k: int):
+  """(expert index [T, k] int32, weight [T, k] f32): the ``top_k`` largest
+  logits of each token and the softmax over just those, which is the softmax
+  over all experts, cut to the chosen and renormalised."""
+  values, index = jax.lax.top_k(router_logits.astype(jnp.float32), top_k)
+  return index.astype(jnp.int32), jax.nn.softmax(values, axis=-1)
+
+
+def buffer_rows(tokens: int, top_k: int, held: int, block_rows: int) -> int:
+  """Rows that hold ANY routing of ``tokens`` tokens: every token's
+  min(top_k, held) pairs, plus each expert's padding to whole tiles."""
+  rows = tokens * min(top_k, held) + held * (block_rows - 1)
+  return -(-rows // block_rows) * block_rows
+
+
+def group_pairs(expert_index: jnp.ndarray, first: int, held: int,
+                block_rows: int):
+  """Lays the pairs whose expert is in [first, first + held) out in rows.
+
+  Expert after expert, in token order within an expert, each expert's rows
+  padded to whole ``block_rows``-row tiles. Returns a dict:
+
+    row_pair   [M]    flat pair (token * k + choice) of each row; T * k for
+                      a padding row
+    pair_row   [T, k] row of each pair; M for a pair whose expert is absent
+    tile_group [M/block_rows] expert (0..held-1) of each tile
+    num_tiles  [1]    tiles in use
+    counts     [held] pairs of each held expert
+
+  No scatter: rows come from one sort of the pairs by expert, the pairs'
+  rows from a running count.
+  """
+  tokens, k = expert_index.shape
+  pairs = tokens * k
+  rows = buffer_rows(tokens, k, held, block_rows)
+  local = expert_index.reshape(pairs) - first
+  is_held = jnp.logical_and(local >= 0, local < held)
+  local = jnp.where(is_held, local, held)
+  onehot = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
+  counts = onehot.sum(0)
+  rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+  padded = -(-counts // block_rows) * block_rows
+  ends = jnp.cumsum(padded)
+  starts = ends - padded
+  starts_pad = jnp.concatenate([starts, jnp.full((1,), rows, jnp.int32)])
+  pair_row = jnp.where(is_held, starts_pad[local] + rank, rows)
+
+  # Pairs in expert order (absent experts last), token order within.
+  in_order = jnp.sort(local * pairs + jnp.arange(pairs, dtype=jnp.int32))
+  in_order = in_order % pairs
+  first_pair = jnp.cumsum(counts) - counts       # of each expert, in_order
+  row = jnp.arange(rows, dtype=jnp.int32)
+  group = jnp.minimum(jnp.searchsorted(ends, row, side='right'), held - 1)
+  offset = row - starts[group]
+  real = jnp.logical_and(row < ends[-1], offset < counts[group])
+  row_pair = jnp.where(
+      real, in_order[jnp.minimum(first_pair[group] + offset, pairs - 1)],
+      pairs)
+  return {
+      'row_pair': row_pair.astype(jnp.int32),
+      'pair_row': pair_row.reshape(tokens, k).astype(jnp.int32),
+      'tile_group': group[::block_rows].astype(jnp.int32),
+      'num_tiles': (ends[-1:] // block_rows).astype(jnp.int32),
+      'counts': counts,
+  }
+
+
+def _take_rows(table, index):
+  """table[index], zeros where index == len(table) (a padding row, or a
+  pair whose expert is absent)."""
+  return table.at[index].get(mode='fill', fill_value=0)
+
+
+@jax.custom_vjp
+def dispatch_rows(x, row_token, pair_row):
+  """[M, d]: row m holds token ``row_token[m]`` (zeros where that is T).
+
+  The backward pass is written as gathers too: a token's gradient is the
+  sum over its pairs of their rows' gradients."""
+  del pair_row
+  return _take_rows(x, row_token)
+
+
+def _dispatch_fwd(x, row_token, pair_row):
+  return _take_rows(x, row_token), pair_row
+
+
+def _dispatch_bwd(pair_row, d_rows):
+  dx = sum(_take_rows(d_rows, pair_row[:, j]).astype(jnp.float32)
+           for j in range(pair_row.shape[1]))
+  return dx.astype(d_rows.dtype), None, None
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(rows, weight, pair_row, row_token, row_weight_index):
+  """[T, d] f32: token t's sum over its pairs of weight x the pair's row
+  (a pair whose row is M, its expert absent, adds nothing)."""
+  del row_token, row_weight_index
+  return sum(weight[:, j, None] *
+             _take_rows(rows, pair_row[:, j]).astype(jnp.float32)
+             for j in range(pair_row.shape[1]))
+
+
+def _combine_fwd(rows, weight, pair_row, row_token, row_weight_index):
+  out = combine_rows(rows, weight, pair_row, row_token, row_weight_index)
+  return out, (rows, weight, pair_row, row_token, row_weight_index)
+
+
+def _combine_bwd(residuals, d_out):
+  rows, weight, pair_row, row_token, row_weight_index = residuals
+  row_weight = _take_rows(weight.reshape(-1), row_weight_index)
+  # Gathered at the rows' own width: the buffer is several times the tokens.
+  d_rows = (row_weight[:, None] * _take_rows(d_out.astype(rows.dtype),
+                                             row_token)).astype(rows.dtype)
+  d_weight = jnp.stack(
+      [jnp.sum(d_out * _take_rows(rows, pair_row[:, j]).astype(jnp.float32),
+               axis=-1) for j in range(pair_row.shape[1])], axis=1)
+  return d_rows, d_weight.astype(weight.dtype), None, None, None
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+class DroplessMoE(nn.Module):
+  """Top-k routed gated experts without a capacity: [T, d] -> [T, d] f32.
+
+  ``num_experts`` is the router's width (all the model's experts);
+  ``experts_held`` = (first index, count) says which of them live here.
+  ``__call__(u, router_logits)`` takes the logits from the caller, because
+  where the router reads is the block's business (before attention in the
+  model this was written for). Experts are gated: ``(relu(u Wg) * (u Wu))
+  Wd``, no bias. Weights are f32 parameters, products run in ``dtype``.
+
+  Returns ``(y, stats)``; ``stats`` are scalars of this call:
+  ``pairs_held`` (pairs computed here), ``load_max_over_mean`` (largest
+  expert's pairs over the mean, over the experts held) and
+  ``dropped_pairs`` (pairs of a held expert that were not computed: 0 by
+  construction, the buffer holds any routing; counted from the layout so
+  that a fault in it would show).
+  """
+
+  num_experts: int
+  experts_held: Tuple[int, int]
+  expert_dim: int
+  top_k: int
+  block_rows: int = 256
+  down_init_std: float = 0.02   # of w_down, which writes into the residual
+  dtype: jnp.dtype = jnp.float32
+
+  @nn.compact
+  def __call__(self, u: jnp.ndarray, router_logits: jnp.ndarray):
+    from tensor2robot_tpu.parallel import grouped_matmul as gmm_lib
+
+    tokens, d = u.shape
+    first, held = self.experts_held
+    if not 0 <= first <= first + held <= self.num_experts or held < 1:
+      raise ValueError('experts_held {} is no range of the {} experts.'
+                       .format(self.experts_held, self.num_experts))
+    k = min(self.top_k, self.num_experts)
+    init = nn.initializers.normal(0.02)
+    w_gate = self.param('w_gate', init, (held, d, self.expert_dim),
+                        jnp.float32)
+    w_up = self.param('w_up', init, (held, d, self.expert_dim), jnp.float32)
+    w_down = self.param('w_down', nn.initializers.normal(self.down_init_std),
+                        (held, self.expert_dim, d), jnp.float32)
+
+    with jax.named_scope('moe_route'):
+      expert_index, weight = route_top_k(router_logits, k)
+    with jax.named_scope('moe_group'):
+      layout = group_pairs(expert_index, first, held, self.block_rows)
+      row_pair, pair_row = layout['row_pair'], layout['pair_row']
+      row_token = jnp.where(row_pair < tokens * k, row_pair // k, tokens)
+      rows = dispatch_rows(u.astype(self.dtype), row_token, pair_row)
+    with jax.named_scope('moe_experts'):
+      product = functools.partial(
+          gmm_lib.grouped_matmul, tile_group=layout['tile_group'],
+          num_tiles=layout['num_tiles'], block_m=self.block_rows)
+      gate_up = product(rows, jnp.concatenate(
+          [w_gate.astype(self.dtype), w_up.astype(self.dtype)], axis=-1))
+      hidden = (nn.relu(gate_up[:, :self.expert_dim]) *
+                gate_up[:, self.expert_dim:])
+      out_rows = product(hidden, w_down.astype(self.dtype))
+    with jax.named_scope('moe_combine'):
+      y = combine_rows(out_rows, weight, pair_row, row_token, row_pair)
+
+    counts = layout['counts'].astype(jnp.float32)
+    pairs_held = counts.sum()
+    stats = {
+        'pairs_held': pairs_held,
+        'load_max_over_mean': counts.max() / jnp.maximum(counts.mean(), 1.0),
+        'dropped_pairs': pairs_held - jnp.sum(
+            row_pair < tokens * k).astype(jnp.float32),
+    }
+    return y, stats

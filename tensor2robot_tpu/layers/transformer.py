@@ -51,14 +51,24 @@ from tensor2robot_tpu.parallel.sharding import constrain as _constrain
 _FLASH_MIN_LENGTH = 2048
 
 
-def scaled_dot_attention(q, k, v, causal: bool) -> jnp.ndarray:
-  """Dense [B, L, H, D] attention in f32 accumulation (the oracle path)."""
+def scaled_dot_attention(q, k, v, causal: bool,
+                         window: Optional[int] = None) -> jnp.ndarray:
+  """Dense [B, L, H, D] attention in f32 accumulation (the oracle path).
+
+  k/v with fewer heads than q are grouped-query heads (query head n reads
+  head n // group); ``window`` keeps columns j with 0 <= i - j < window."""
   scale = 1.0 / np.sqrt(q.shape[-1])
+  group = q.shape[2] // k.shape[2]
+  if group > 1:
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
   scores = jnp.einsum('bqhd,bkhd->bhqk', q.astype(jnp.float32),
                       k.astype(jnp.float32)) * scale
   if causal:
     l_q, l_k = q.shape[1], k.shape[1]
     mask = jnp.tril(jnp.ones((l_q, l_k), bool), k=l_k - l_q)
+    if window is not None:
+      mask = jnp.logical_and(
+          mask, jnp.triu(jnp.ones((l_q, l_k), bool), k=l_k - l_q - window + 1))
     scores = jnp.where(mask, scores, -jnp.inf)
   probs = jax.nn.softmax(scores, axis=-1)
   return jnp.einsum('bhqk,bkhd->bqhd', probs, v.astype(jnp.float32)
@@ -80,16 +90,23 @@ def resolve_attention_mode(mode: str, seq_length: int) -> str:
 
 
 def run_attention(q, k, v, *, mode: str, causal: bool,
-                  mesh=None, seq_axis: str = 'data') -> jnp.ndarray:
-  """Dispatches [B, L, H, D] self-attention to the selected backend."""
+                  mesh=None, seq_axis: str = 'data',
+                  window: Optional[int] = None) -> jnp.ndarray:
+  """Dispatches [B, L, H, D] self-attention to the selected backend.
+
+  Grouped-query heads (k/v with fewer heads) and ``window`` are the dense
+  and flash backends'; the ring backend has neither."""
   mode = resolve_attention_mode(mode, q.shape[1])
   if mode == 'xla':
-    return scaled_dot_attention(q, k, v, causal)
+    return scaled_dot_attention(q, k, v, causal, window)
   if mode == 'flash':
-    return flash_lib.flash_attention(q, k, v, causal=causal)
+    return flash_lib.flash_attention(q, k, v, causal=causal, window=window)
   if mode == 'ring':
     if mesh is None:
       raise ValueError("attention_mode='ring' requires a mesh.")
+    if window is not None or k.shape[2] != q.shape[2]:
+      raise ValueError("attention_mode='ring' has no window and no "
+                       'grouped-query heads.')
     return ring_lib.ring_self_attention(q, k, v, mesh, seq_axis=seq_axis,
                                         causal=causal)
   raise ValueError('Unknown attention mode: {!r}'.format(mode))
@@ -264,6 +281,125 @@ class TransformerBlock(nn.Module):
     if self.dropout_rate:
       h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
     return x + h, aux
+
+
+class RMSNorm(nn.Module):
+  """x / sqrt(mean(x^2) + eps) * scale, in f32 whatever comes in."""
+
+  eps: float = 1e-6
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    scale = self.param('scale', nn.initializers.ones, (x.shape[-1],),
+                       jnp.float32)
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
+
+
+def rotary_positions(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+  """Rotary position embedding of [B, L, H, D] by the position in the
+  sequence, rotate-half pairing (dimension i with i + D/2), in f32."""
+  d = x.shape[-1]
+  inverse = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+  angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inverse[None]
+  cos, sin = (jnp.concatenate([f(angle), f(angle)], axis=-1)[None, :, None]
+              for f in (jnp.cos, jnp.sin))
+  x = x.astype(jnp.float32)
+  first, second = x[..., :d // 2], x[..., d // 2:]
+  return x * cos + jnp.concatenate([-second, first], axis=-1) * sin
+
+
+class GroupedQueryAttention(nn.Module):
+  """Causal self-attention with ``num_heads`` query heads over
+  ``num_kv_heads`` key/value heads (query head n reads k/v head
+  n // group), separate bias-free q/k/v/out projections, optionally a
+  sliding ``window`` and rotary positions (``rope_theta``; None: the layer
+  carries no positions at all). Backends as ``run_attention``: the Pallas
+  kernels index the shared k/v heads, nothing is repeated in HBM."""
+
+  num_heads: int
+  num_kv_heads: int
+  head_dim: int
+  window: Optional[int] = None
+  rope_theta: Optional[float] = None
+  attention_mode: str = 'auto'
+  out_init_std: float = 0.02    # of `out`, which writes into the residual
+  dtype: jnp.dtype = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    b, l, d = x.shape
+    init = nn.initializers.normal(0.02)
+
+    def project(name, heads):
+      out = nn.Dense(heads * self.head_dim, use_bias=False, dtype=self.dtype,
+                     kernel_init=init, name=name)(x)
+      return out.reshape(b, l, heads, self.head_dim)
+
+    q = project('q', self.num_heads)
+    k = project('k', self.num_kv_heads)
+    v = project('v', self.num_kv_heads)
+    if self.rope_theta is not None:
+      q, k = (rotary_positions(t, self.rope_theta).astype(self.dtype)
+              for t in (q, k))
+    with jax.named_scope('attention'):
+      out = run_attention(q, k, v, mode=self.attention_mode, causal=True,
+                          window=self.window)
+    return nn.Dense(d, use_bias=False, dtype=self.dtype,
+                    kernel_init=nn.initializers.normal(self.out_init_std),
+                    name='out')(out.reshape(b, l, -1))
+
+
+class RouterFirstMoEBlock(nn.Module):
+  """Pre-norm block whose router reads the block's INPUT, before attention:
+
+    r = x W_r (f32);  x1 = x + attn(rmsnorm(x));  out = x1 + moe(rmsnorm(x1), r)
+
+  with grouped-query attention (window and rotary positions per layer kind)
+  and the dropless expert layer told which experts it holds
+  (layers/moe.py::DroplessMoE). Returns (out, the expert layer's stats)."""
+
+  num_heads: int
+  num_kv_heads: int
+  head_dim: int
+  num_experts: int
+  experts_held: tuple
+  expert_dim: int
+  top_k: int
+  window: Optional[int] = None
+  rope_theta: Optional[float] = None
+  eps: float = 1e-6
+  attention_mode: str = 'auto'
+  moe_block_rows: int = 256
+  residual_init_std: float = 0.02  # of the two matrices that write into x
+  dtype: jnp.dtype = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray):
+    from tensor2robot_tpu.layers.moe import DroplessMoE
+
+    b, l, d = x.shape
+    router_logits = nn.Dense(
+        self.num_experts, use_bias=False, dtype=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+        kernel_init=nn.initializers.normal(0.02), name='router')(
+            x.astype(jnp.float32))
+    h = RMSNorm(self.eps, name='norm_attn')(x).astype(self.dtype)
+    x = x + GroupedQueryAttention(
+        num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+        head_dim=self.head_dim, window=self.window,
+        rope_theta=self.rope_theta, attention_mode=self.attention_mode,
+        out_init_std=self.residual_init_std, dtype=self.dtype,
+        name='attn')(h)
+    u = RMSNorm(self.eps, name='norm_moe')(x).astype(self.dtype)
+    y, stats = DroplessMoE(
+        num_experts=self.num_experts, experts_held=tuple(self.experts_held),
+        expert_dim=self.expert_dim, top_k=self.top_k,
+        block_rows=self.moe_block_rows,
+        down_init_std=self.residual_init_std, dtype=self.dtype, name='moe')(
+            u.reshape(b * l, d), router_logits.reshape(b * l, -1))
+    return x + y.reshape(b, l, d).astype(x.dtype), stats
 
 
 class TokenLearner(nn.Module):

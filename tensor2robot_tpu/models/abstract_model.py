@@ -64,8 +64,27 @@ class TrainState(flax.struct.PyTreeNode):
     return {'params': params, **(self.model_state or {})}
 
 
+def gradient_norms(grads):
+  """{'grad_norm': global norm, 'grad_group_norm/<name>': norm of the subtree under
+  each top-level key of ``grads``}, every leaf read once."""
+  squares = {
+      name: sum(jnp.sum(jnp.square(leaf.astype(jnp.float32)))
+                for leaf in jax.tree.leaves(subtree))
+      for name, subtree in grads.items()}
+  norms = {'grad_group_norm/' + name: jnp.sqrt(value)
+           for name, value in squares.items()}
+  norms['grad_norm'] = jnp.sqrt(sum(squares.values()))
+  return norms
+
+
 class AbstractT2RModel(ModelInterface):
   """Base model: spec declarations + pure network/loss/metric functions."""
+
+  # A model that sets this reports the norm of each step's gradient as
+  # scalar step metrics: ``grad_norm`` (global) and ``grad_group_norm/<name>`` for
+  # every top-level entry of the parameter tree, so that a check can tell
+  # WHICH part's gradient is off; the others compute nothing.
+  report_gradient_norm = False
 
   def __init__(self,
                preprocessor_cls: Optional[Callable[..., AbstractPreprocessor]] = None,
@@ -246,6 +265,8 @@ class AbstractT2RModel(ModelInterface):
       ema = opt_lib.create_ema(self.avg_model_params_decay)
       avg_params, ema_state = ema.update(new_params, state.ema_state)
     metrics = SpecStruct(loss=loss)
+    if self.report_gradient_norm:
+      metrics.update(gradient_norms(grads))
     if isinstance(train_outputs, (dict, SpecStruct)):
       for key in train_outputs:
         value = train_outputs[key]

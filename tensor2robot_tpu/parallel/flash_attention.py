@@ -69,9 +69,31 @@ def _dividing_block_or_raise(requested: int, l: int) -> int:
       'pad the sequence to a multiple of 8.'.format(requested, l))
 
 
+def _in_band(q_pos, k_pos, window: Optional[int]):
+  """The causal mask, and with ``window`` the band ``q_pos - k_pos < window``
+  (a row sees itself and the ``window - 1`` positions before it)."""
+  mask = q_pos >= k_pos
+  if window is not None:
+    mask = jnp.logical_and(mask, q_pos - k_pos < window)
+  return mask
+
+
+def _block_in_band(i_q, i_k, block_q: int, block_k: int,
+                   window: Optional[int]):
+  """Whether block (i_q, i_k) holds any position of the causal band: not
+  wholly above the diagonal and, with ``window``, not wholly left of the
+  band. Blocks outside are skipped on BOTH sides."""
+  needed = i_q * block_q + block_q - 1 >= i_k * block_k
+  if window is not None:
+    needed = jnp.logical_and(
+        needed, i_q * block_q - (i_k * block_k + block_k - 1) < window)
+  return needed
+
+
 def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
                   scale: float, causal: bool, block_q: int, block_k: int,
-                  q_offset, k_offset, i_q, i_k):
+                  q_offset, k_offset, i_q, i_k,
+                  window: Optional[int] = None):
   """The shared online-softmax block update both kernels run.
 
   Reads one q/k/v block from refs, scores it, and folds it into the
@@ -97,7 +119,7 @@ def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
         jnp.int32, (block_q, block_k), 0))
     k_pos = (k_offset + i_k * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1))
-    s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    s = jnp.where(_in_band(q_pos, k_pos, window), s, NEG_INF)
 
   m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)   # [bq, 1]
   l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
@@ -117,7 +139,7 @@ def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                   l_ref, *, scale: float, causal: bool, block_q: int,
-                  block_k: int):
+                  block_k: int, window: Optional[int] = None):
   """One step of the k-outer / q-inner sweep within a q TILE.
 
   The grid is (bh, n_q_outer, n_k, n_q_inner): within one q tile
@@ -151,11 +173,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     _block_update(q_ref, k_ref, v_ref, acc_ref.at[rows, :],
                   m_ref.at[rows, :], l_ref.at[rows, :], scale=scale,
                   causal=causal, block_q=block_q, block_k=block_k,
-                  q_offset=0, k_offset=0, i_q=i_q, i_k=i_k)
+                  q_offset=0, k_offset=0, i_q=i_q, i_k=i_k, window=window)
 
   if causal:
-    # Skip blocks entirely above the causal diagonal (all scores -inf).
-    @pl.when(i_q * block_q + block_q - 1 >= i_k * block_k)
+    # Skip blocks entirely above the causal diagonal (all scores -inf)
+    # and, with a window, those entirely left of the band.
+    @pl.when(_block_in_band(i_q, i_k, block_q, block_k, window))
     def _update():
       _do_update()
   else:
@@ -173,9 +196,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     lse_ref[0] = jnp.broadcast_to(row[None, :], (8, block_q))
 
 
+def _kv_head(group: int):
+  """Index of the key/value head that query head ``b`` (of the flattened
+  batch x query-head axis) reads: ``b // group`` with grouped-query heads,
+  ``b`` itself with equal head counts."""
+  return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
 def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
-                block_k: int, interpret: bool):
+                block_k: int, interpret: bool, window: Optional[int] = None):
   """[BH, L, D] flash attention via pallas_call.
+
+  k/v may hold fewer heads than q ([BH/group, L, D], grouped-query
+  attention): query head ``b`` then reads k/v head ``b // group`` through
+  the block index maps, so no k/v head is repeated in HBM.
 
   The log-sum-exp output is materialized as [BH, 8, L] — Mosaic requires
   output blocks whose second-minor dim is divisible by 8 (or equals the
@@ -185,6 +219,7 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
   """
   bh, l_q, d = q.shape
   l_k = k.shape[1]
+  kv = _kv_head(bh // k.shape[0])
   n_q = pl.cdiv(l_q, block_q)
   n_k = pl.cdiv(l_k, block_k)
   # q rows per tile: as many q blocks as fit a few MB of f32 accumulator
@@ -197,7 +232,7 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
   tile_rows = n_qi * block_q
   kernel = functools.partial(
       _flash_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k)
+      block_k=block_k, window=window)
   # Grid: per q TILE, k OUTER / q INNER (see _flash_kernel) — each k/v
   # block is fetched once per k step per tile; the tile's accumulators
   # live in VMEM scratch.
@@ -207,8 +242,8 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
       in_specs=[
           pl.BlockSpec((1, block_q, d),
                        lambda b, qo, j, qi, n=n_qi: (b, qo * n + qi, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, qo, j, qi: (b, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, qo, j, qi: (b, j, 0)),
+          pl.BlockSpec((1, block_k, d), lambda b, qo, j, qi: (kv(b), j, 0)),
+          pl.BlockSpec((1, block_k, d), lambda b, qo, j, qi: (kv(b), j, 0)),
       ],
       out_specs=[
           pl.BlockSpec((1, block_q, d),
@@ -227,6 +262,7 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
           pltpu.VMEM((tile_rows, 128), jnp.float32),
       ],
       interpret=interpret,
+      name='flash_attention_fwd',
   )(q, k, v)
   return out, lse8[:, 0, :]
 
@@ -371,7 +407,7 @@ def _bwd_default_blocks(l_q: int, l_k: int):
 
 
 def _bwd_p_ds(q, k, v, do, lse, delta, *, scale, causal, q_base, k_base,
-              block_q, block_k):
+              block_q, block_k, window=None):
   """Shared recompute for both backward kernels: (p, ds) for one block
   pair, from the saved log-sum-exp. All operands f32 2D blocks."""
   s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -381,7 +417,7 @@ def _bwd_p_ds(q, k, v, do, lse, delta, *, scale, causal, q_base, k_base,
         jnp.int32, (block_q, block_k), 0)
     k_pos = k_base + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    s = jnp.where(_in_band(q_pos, k_pos, window), s, NEG_INF)
   p = jnp.exp(s - lse)
   if causal:
     p = jnp.where(s <= NEG_INF / 2, 0.0, p)
@@ -393,14 +429,20 @@ def _bwd_p_ds(q, k, v, do, lse, delta, *, scale, causal, q_base, k_base,
 
 def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                         causal: bool, block_q: int, block_k: int):
-  """dk/dv: grid (bh, n_k, n_q) — k/v block resident (accumulators in
-  scratch), q/do/lse/delta stream through."""
+                         causal: bool, block_q: int, block_k: int,
+                         n_q: int, group: int = 1,
+                         window: Optional[int] = None):
+  """dk/dv: grid (bh of k/v, n_k, group * n_q) — k/v block resident
+  (accumulators in scratch), q/do/lse/delta stream through. With
+  grouped-query heads the last axis runs over the ``group`` query heads
+  that read this k/v head, head-major, so dk/dv are summed over the group
+  in the scratch and written once."""
   i_k = pl.program_id(1)
-  i_q = pl.program_id(2)
-  n_q = pl.num_programs(2)
+  i_t = pl.program_id(2)
+  n_t = pl.num_programs(2)
+  i_q = i_t if group == 1 else i_t % n_q
 
-  @pl.when(i_q == 0)
+  @pl.when(i_t == 0)
   def _init():
     dk_acc[...] = jnp.zeros_like(dk_acc)
     dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -416,7 +458,7 @@ def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       v_ref[0].astype(jnp.float32), do, lse, delta,
                       scale=scale, causal=causal,
                       q_base=i_q * block_q, k_base=i_k * block_k,
-                      block_q=block_q, block_k=block_k)
+                      block_q=block_q, block_k=block_k, window=window)
     dv_acc[...] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -425,14 +467,15 @@ def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         preferred_element_type=jnp.float32)
 
   if causal:
-    # Blocks fully above the diagonal contribute nothing to dk/dv.
-    @pl.when(i_q * block_q + block_q - 1 >= i_k * block_k)
+    # Blocks fully above the diagonal (or, with a window, fully left of
+    # the band) contribute nothing to dk/dv.
+    @pl.when(_block_in_band(i_q, i_k, block_q, block_k, window))
     def _():
       _update()
   else:
     _update()
 
-  @pl.when(i_q == n_q - 1)
+  @pl.when(i_t == n_t - 1)
   def _finalize():
     dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
     dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -440,7 +483,8 @@ def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dq_ref, dq_acc, *, scale: float, causal: bool,
-                        block_q: int, block_k: int):
+                        block_q: int, block_k: int,
+                        window: Optional[int] = None):
   """dq: grid (bh, n_q, n_k) — q block resident, k/v stream through."""
   i_q = pl.program_id(1)
   i_k = pl.program_id(2)
@@ -459,13 +503,13 @@ def _flash_bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _, ds = _bwd_p_ds(q, k, v_ref[0].astype(jnp.float32), do, lse, delta,
                       scale=scale, causal=causal,
                       q_base=i_q * block_q, k_base=i_k * block_k,
-                      block_q=block_q, block_k=block_k)
+                      block_q=block_q, block_k=block_k, window=window)
     dq_acc[...] += jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
   if causal:
-    @pl.when(i_q * block_q + block_q - 1 >= i_k * block_k)
+    @pl.when(_block_in_band(i_q, i_k, block_q, block_k, window))
     def _():
       _update()
   else:
@@ -477,17 +521,23 @@ def _flash_bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
-                      block_q, block_k, interpret):
-  """Full Pallas backward: dq, dk, dv over [BH, L, D] operands.
+                      block_q, block_k, interpret, window=None):
+  """Full Pallas backward: dq over [BH, L, D], dk, dv over k/v's
+  [BH/group, L, D].
 
   Two kernels (FlashAttention-2 structure): dk/dv with the k/v block
   resident and q streaming, dq with the q block resident and k/v
   streaming. P is recomputed from the forward's saved log-sum-exp; no
   [L, L] tensor exists in either pass. delta = rowsum(do * out) is one
-  fused elementwise pass XLA handles before the kernels.
+  fused elementwise pass XLA handles before the kernels. Grouped-query
+  heads: both kernels read k/v head ``b // group`` through their index
+  maps; the dk/dv kernel sweeps the group's query heads after one another
+  over its resident k/v block (see _flash_bwd_kv_kernel).
   """
   bh, l_q, d = q.shape
   l_k = k.shape[1]
+  group = bh // k.shape[0]
+  kv = _kv_head(group)
   n_q = l_q // block_q
   n_k = l_k // block_k
   do = d_out.astype(jnp.float32)
@@ -497,23 +547,31 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
   lse8 = jnp.broadcast_to(lse[:, None, :], (bh, 8, l_q))
   delta8 = jnp.broadcast_to(delta[:, None, :], (bh, 8, l_q))
 
+  # (query head of the flattened axis, q block) of step t of k/v head b.
+  if group == 1:
+    q_of = lambda b, t: (b, t)
+  else:
+    q_of = lambda b, t: (b * group + t // n_q, t % n_q)
+
   kv_kernel = functools.partial(
       _flash_bwd_kv_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k)
+      block_k=block_k, n_q=n_q, group=group, window=window)
   dk, dv = pl.pallas_call(
       kv_kernel,
-      grid=(bh, n_k, n_q),
+      grid=(bh // group, n_k, group * n_q),
       in_specs=[
-          pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-          pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-          pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
-          pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
+          pl.BlockSpec((1, block_q, d), lambda b, j, t: (*q_of(b, t), 0)),
+          pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
+          pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
+          pl.BlockSpec((1, block_q, d), lambda b, j, t: (*q_of(b, t), 0)),
+          pl.BlockSpec((1, 8, block_q),
+                       lambda b, j, t: (q_of(b, t)[0], 0, q_of(b, t)[1])),
+          pl.BlockSpec((1, 8, block_q),
+                       lambda b, j, t: (q_of(b, t)[0], 0, q_of(b, t)[1])),
       ],
       out_specs=[
-          pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+          pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
+          pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
       ],
       out_shape=[
           jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -524,18 +582,19 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
           pltpu.VMEM((block_k, d), jnp.float32),
       ],
       interpret=interpret,
+      name='flash_attention_bwd_dkv',
   )(q, k, v, d_out, lse8, delta8)
 
   q_kernel = functools.partial(
       _flash_bwd_q_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k)
+      block_k=block_k, window=window)
   dq = pl.pallas_call(
       q_kernel,
       grid=(bh, n_q, n_k),
       in_specs=[
           pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+          pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
+          pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
           pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
           pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
           pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
@@ -546,32 +605,34 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
       out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
       scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
       interpret=interpret,
+      name='flash_attention_bwd_dq',
   )(q, k, v, d_out, lse8, delta8)[0]
   return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_diff(q, k, v, causal, scale, block_q, block_k, interpret,
-                block_q_bwd, block_k_bwd):
+                block_q_bwd, block_k_bwd, window):
   """custom_vjp core over [BH, L, D] operands."""
   del block_q_bwd, block_k_bwd  # backward-only
   out, _ = _flash_bhld(q, k, v, scale=scale, causal=causal,
                        block_q=block_q, block_k=block_k,
-                       interpret=interpret)
+                       interpret=interpret, window=window)
   return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               block_q_bwd, block_k_bwd):
+               block_q_bwd, block_k_bwd, window):
   del block_q_bwd, block_k_bwd
   out, lse = _flash_bhld(q, k, v, scale=scale, causal=causal,
                          block_q=block_q, block_k=block_k,
-                         interpret=interpret)
+                         interpret=interpret, window=window)
   return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, block_q_bwd,
-               block_k_bwd, residuals, d_out):
+               block_k_bwd, window, residuals, d_out):
   """Pallas FlashAttention-2 backward (see _flash_bwd_pallas).
 
   Until round 4 this was an XLA lax.scan recompute; it is now the same
@@ -586,7 +647,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, block_q_bwd,
   bk = _dividing_block_or_raise(min(block_k_bwd or default_bk, l_k), l_k)
   dq, dk, dv = _flash_bwd_pallas(
       q, k, v, out, lse, d_out, scale=scale, causal=causal,
-      block_q=bq, block_k=bk, interpret=interpret)
+      block_q=bq, block_k=bk, interpret=interpret, window=window)
   return dq, dk, dv
 
 
@@ -600,7 +661,8 @@ def flash_attention(q, k, v,
                     block_k: int = 1024,
                     interpret: Optional[bool] = None,
                     block_q_bwd: Optional[int] = None,
-                    block_k_bwd: Optional[int] = None):
+                    block_k_bwd: Optional[int] = None,
+                    window: Optional[int] = None):
   """Exact attention over [B, L, H, D] inputs, O(L) memory, differentiable.
 
   Forward runs the Pallas kernel (k-outer/q-inner tiled sweep, see
@@ -609,6 +671,15 @@ def flash_attention(q, k, v,
   either. Blocks step down automatically to sizes dividing L.
   ``interpret=None`` auto-selects the Pallas interpreter off-TPU so
   tests run on CPU.
+
+  Grouped-query attention: k and v may hold fewer heads than q
+  ([B, L, H/group, D]); query head n reads key/value head n // group.
+  The kernels index the shared k/v blocks, nothing is repeated in HBM,
+  and dk/dv come back summed over the group. ``window`` (causal only)
+  keeps, for row i, the columns j with ``0 <= i - j < window``; blocks
+  that lie wholly outside that band are skipped in all three kernels.
+  ``window=None`` with equal head counts is the plain causal (or full)
+  attention the kernels always computed.
 
   Default block sizes come from v5e sweeps (B=1, H=8, D=128, causal,
   chained on-device timing): (1024, 1024) — grid-step count (fixed
@@ -628,7 +699,15 @@ def flash_attention(q, k, v,
   if interpret is None:
     interpret = not runtime.on_tpu()
   b, l_q, h, d = q.shape
-  l_k = k.shape[1]
+  l_k, h_kv = k.shape[1], k.shape[2]
+  if h % h_kv or v.shape[2] != h_kv:
+    raise ValueError(
+        'grouped-query attention needs the query heads ({}) to be a '
+        'multiple of the key/value heads ({}, {}).'.format(
+            h, h_kv, v.shape[2]))
+  if window is not None and (not causal or window < 1):
+    raise ValueError('window={!r} needs causal=True and window >= 1.'.format(
+        window))
   if jnp.dtype(q.dtype).itemsize >= 4:
     # f32 operands double the VMEM block footprint; the bf16-tuned
     # (1024, 1024) defaults press past the 16 MB scoped-VMEM limit at
@@ -643,12 +722,13 @@ def flash_attention(q, k, v,
   dp = -(-d // 128) * 128 if not interpret else d
 
   def _to_bhld(x):
-    x = x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+    x = x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)
     if dp != d:
       x = jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
     return x
 
   out = _flash_diff(_to_bhld(q), _to_bhld(k), _to_bhld(v), causal, scale,
-                    block_q, block_k, interpret, block_q_bwd, block_k_bwd)
+                    block_q, block_k, interpret, block_q_bwd, block_k_bwd,
+                    window)
   out = out[:, :, :d] if dp != d else out
   return out.reshape(b, h, l_q, d).transpose(0, 2, 1, 3)
