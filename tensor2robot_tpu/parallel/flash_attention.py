@@ -31,6 +31,22 @@ ms at L=4k/16k/32k vs XLA 13.1/46.4/OOM; fwd+bwd 10.8/18.1/60.4 ms vs
 XLA 11.3/uncompilable/uncompilable). This is the single-device
 long-context path; ring_attention.py handles the cross-device dimension
 with its own shard-level blockwise accumulation.
+
+Named residuals. The forward rule of the custom VJP (_flash_fwd) names
+what the backward kernels read with ``jax.ad_checkpoint.checkpoint_name``:
+q, k and v as the kernels see them ([BH, L, D], after the caller's
+positions and the layout change), ``out`` and the per-row log-sum-exp
+(FLASH_Q .. FLASH_LSE; BACKWARD_READS holds all five). A name is the
+identity unless a ``jax.checkpoint`` around the caller has a policy that
+asks for it (``save_only_these_names``): that checkpoint then keeps the
+array instead of running the forward kernel a second time in its backward
+pass. Who saves them: research/smallthinker's block checkpoint, all five.
+Every other caller (MultiHeadAttention / TransformerBlock,
+parallel/pipeline.py's policy-less checkpoint, direct calls) names no
+policy and compiles to what it compiled to without the names. The names
+must be given INSIDE the forward rule: under differentiation JAX traces
+that rule in place of the primal function, so a name in the primal
+function is never seen by a policy.
 """
 
 from __future__ import annotations
@@ -41,12 +57,24 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tensor2robot_tpu import runtime
 
 NEG_INF = -1e30
+
+# Names of the custom VJP's residuals (see the module docstring): what the
+# two backward kernels read. A checkpoint policy that saves them spares its
+# backward pass the forward kernel (out, lse) and the caller's projections,
+# positions and layout change (q, k, v).
+FLASH_Q = 'flash_q'
+FLASH_K = 'flash_k'
+FLASH_V = 'flash_v'
+FLASH_OUT = 'flash_out'
+FLASH_LSE = 'flash_lse'
+BACKWARD_READS = (FLASH_Q, FLASH_K, FLASH_V, FLASH_OUT, FLASH_LSE)
 
 
 def _dividing_block_or_raise(requested: int, l: int) -> int:
@@ -625,9 +653,15 @@ def _flash_diff(q, k, v, causal, scale, block_q, block_k, interpret,
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                block_q_bwd, block_k_bwd, window):
   del block_q_bwd, block_k_bwd
+  q, k, v = (checkpoint_name(x, name)
+             for x, name in zip((q, k, v), (FLASH_Q, FLASH_K, FLASH_V)))
   out, lse = _flash_bhld(q, k, v, scale=scale, causal=causal,
                          block_q=block_q, block_k=block_k,
                          interpret=interpret, window=window)
+  # The named ``out`` is both the result and the residual: one saved array
+  # serves the caller's next layer and the backward kernels.
+  out = checkpoint_name(out, FLASH_OUT)
+  lse = checkpoint_name(lse, FLASH_LSE)
   return out, (q, k, v, out, lse)
 
 

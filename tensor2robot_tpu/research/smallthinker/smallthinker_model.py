@@ -17,10 +17,16 @@ and token ids, logits and loss are over the held rows. Nothing stands in
 for the absent chips.
 
 Training cost is kept in bounds by three things: ``jax.checkpoint`` around
-every block (only the residual stream between blocks is kept for the
-backward pass), the flash kernels (no [L, L] scores), and a head whose
-logits and loss are computed over blocks of tokens, so that the float32
-logits of a whole batch never exist at once.
+every block, the flash kernels (no [L, L] scores), and a head whose logits
+and loss are computed over blocks of tokens, so that the float32 logits of
+a whole batch never exist at once. A block's checkpoint keeps, beside the
+residual stream between blocks, what the attention backward kernels read
+(q, k, v as the kernels see them, ``out`` and the log-sum-exp: the arrays
+parallel/flash_attention.py names, BACKWARD_READS), so the backward pass
+runs neither the flash forward kernel nor the q, k, v projections a second
+time; everything else in the block (norms, router, the expert layer) is
+computed again. A block whose attention is not the flash kernel carries no
+such names and keeps the residual stream alone.
 """
 
 from __future__ import annotations
@@ -42,6 +48,15 @@ from tensor2robot_tpu.specs.tensor_spec import TensorSpec
 
 MOE_STATS = ('moe/pairs_held', 'moe/expert_load_max_over_mean',
              'moe/dropped_pairs')
+
+# A block under ``jax.checkpoint`` that keeps, for its backward pass, the
+# residuals the flash kernels' forward rule names (at 2 x 8192 tokens, 28 and
+# 4 heads of 128, bf16: 117.4 MB each for q and out, 16.8 each for k and v,
+# 1.8 for the log-sum-exp, a layer) and computes the rest of itself again.
+CheckpointedBlock = nn.remat(
+    transformer_lib.RouterFirstMoEBlock,
+    policy=jax.checkpoint_policies.save_only_these_names(
+        *transformer_lib.flash_lib.BACKWARD_READS))
 
 
 def next_token_loss(hidden, head, tokens, block_tokens: int, dtype):
@@ -112,10 +127,9 @@ class SmallThinkerNet(nn.Module):
                       jnp.float32)
     x = jnp.take(embedding, tokens, axis=0).astype(self.dtype)
     stats = []
-    block_cls = nn.remat(transformer_lib.RouterFirstMoEBlock)
     for layer, (windowed, rotary) in enumerate(
         zip(self.window_layers, self.rope_layers)):
-      x, layer_stats = block_cls(
+      x, layer_stats = CheckpointedBlock(
           num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
           head_dim=self.head_dim, num_experts=self.num_experts,
           experts_held=tuple(self.experts_held), expert_dim=self.expert_dim,

@@ -5,7 +5,10 @@ platform to expose 8 devices (SURVEY.md §4: the JAX analog of the reference's
 TPU-without-TPU estimator tests).
 """
 
+import collections
 import os
+
+import pytest
 
 # The suite runs on eight forced CPU devices whatever the ambient
 # platform is: sharding tests need the device count, and a test process
@@ -26,3 +29,27 @@ def pytest_configure(config):
       'markers',
       'fault: FaultInjector-driven fault-tolerance tests '
       "(kept inside the tier-1 'not slow' selection; filter with -m fault)")
+
+
+def _count_calls(jaxpr, calls, tags):
+  for eqn in jaxpr.eqns:
+    calls[eqn.primitive.name] += 1
+    if eqn.primitive.name == 'pallas_call':
+      calls[eqn.params['name']] += 1
+    elif eqn.primitive.name == 'name':
+      tags[eqn.params['name']] += 1
+    else:
+      for inner in jax.core.jaxprs_in_params(eqn.params):
+        _count_calls(inner, calls, tags)
+  return calls, tags
+
+
+@pytest.fixture(scope='session')
+def jaxpr_calls():
+  """``calls, tags = jaxpr_calls(fn, *args)``: two Counters over the whole
+  jaxpr of ``fn(*args)``, nested ones included: every primitive by its name
+  and the Pallas kernels also by their ``name`` (their bodies are not
+  entered); and the ``checkpoint_name`` tags. Exact, and nothing runs."""
+  return lambda fn, *args: _count_calls(
+      jax.make_jaxpr(fn)(*args).jaxpr, collections.Counter(),
+      collections.Counter())

@@ -11,8 +11,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tensor2robot_tpu.layers import transformer as transformer_lib
 from tensor2robot_tpu.parallel.flash_attention import flash_attention
 from tensor2robot_tpu.parallel.ring_attention import reference_attention
+
+
+flash_lib = transformer_lib.flash_lib  # the module; the package exports the function
 
 
 def _qkv(b=2, l=256, h=4, d=64, dtype=np.float32, seed=0):
@@ -128,3 +132,77 @@ class TestRingWithPallas:
                                 jnp.asarray(v), causal=causal)
       np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                  atol=2e-6)
+
+
+KERNELS = ('flash_attention_fwd', 'flash_attention_bwd_dkv',
+           'flash_attention_bwd_dq')
+
+
+def _attention_losses():
+  """Freshly built each call (a traced function is cached by identity):
+  {caller: (loss, arguments)} through the bare kernel and through
+  MultiHeadAttention with the flash backend."""
+  q, k, v = (jnp.asarray(x) for x in _qkv(b=1, l=64, h=2, d=32, seed=7))
+  layer = transformer_lib.MultiHeadAttention(
+      num_heads=2, head_dim=32, attention_mode='flash')
+  x = jnp.asarray(np.random.RandomState(8).randn(1, 64, 48), jnp.float32)
+  params = layer.init(jax.random.PRNGKey(0), x)
+  return {
+      'flash_attention': (lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+          q, k, v, causal=True, block_q=32, block_k=16, block_q_bwd=16,
+          block_k_bwd=32))), (q, k, v)),
+      'MultiHeadAttention': (
+          lambda params, x: jnp.sum(jnp.sin(layer.apply(params, x))),
+          (params, x)),
+  }
+
+
+class TestNamedResiduals:
+  """The names on the custom VJP's residuals are the identity for every
+  caller that has no checkpoint policy asking for them."""
+
+  def test_the_names_exported_are_the_ones_given(self, jaxpr_calls):
+    loss, args = _attention_losses()['flash_attention']
+    _, tags = jaxpr_calls(jax.grad(loss), *args)
+    assert set(tags) == set(flash_lib.BACKWARD_READS) == {
+        flash_lib.FLASH_Q, flash_lib.FLASH_K, flash_lib.FLASH_V,
+        flash_lib.FLASH_OUT, flash_lib.FLASH_LSE}
+    assert len(set(flash_lib.BACKWARD_READS)) == 5
+    # The primal function is not the forward rule: no name outside a gradient.
+    assert not jaxpr_calls(loss, *args)[1]
+
+  @pytest.mark.parametrize('checkpointed', [False, True],
+                           ids=['plain', 'policy-less checkpoint'])
+  @pytest.mark.parametrize('caller', ['flash_attention',
+                                      'MultiHeadAttention'])
+  def test_without_a_policy_kernels_and_values_are_the_untagged_ones(
+      self, caller, checkpointed, jaxpr_calls, monkeypatch):
+    def grad():
+      loss, args = _attention_losses()[caller]
+      fn = jax.value_and_grad(
+          jax.checkpoint(loss) if checkpointed else loss,
+          argnums=tuple(range(len(args))))
+      return fn(*args), *jaxpr_calls(fn, *args)
+
+    got, calls, _ = grad()
+    # A checkpoint with no policy runs the forward kernel again, as ever.
+    assert [calls[k] for k in KERNELS] == [2 if checkpointed else 1, 1, 1]
+    # With the tags made the identity the module is the one before them.
+    monkeypatch.setattr(flash_lib, 'checkpoint_name', lambda x, name: x)
+    want, untagged, tags = grad()
+    assert not tags
+    assert [untagged[k] for k in KERNELS] == [calls[k] for k in KERNELS]
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+      np.testing.assert_array_equal(g, w)
+
+  def test_a_policy_that_asks_for_out_and_lse_drops_the_second_forward(
+      self, jaxpr_calls):
+    loss, args = _attention_losses()['flash_attention']
+    kept = jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(
+            flash_lib.FLASH_OUT, flash_lib.FLASH_LSE))
+    calls, _ = jaxpr_calls(jax.grad(kept, argnums=(0, 1, 2)), *args)
+    assert [calls[k] for k in KERNELS] == [1, 1, 1]
+    for g, w in zip(jax.grad(kept, argnums=(0, 1, 2))(*args),
+                    jax.grad(loss, argnums=(0, 1, 2))(*args)):
+      np.testing.assert_array_equal(g, w)

@@ -6,6 +6,7 @@ up to the uncut layer; the dropless layer under the most uneven routing."""
 
 import functools
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +18,7 @@ from tensor2robot_tpu.modes import ModeKeys
 from tensor2robot_tpu.parallel import grouped_matmul as gmm_lib
 from tensor2robot_tpu.parallel.flash_attention import flash_attention
 from tensor2robot_tpu.research.smallthinker import SmallThinkerModel
+from tensor2robot_tpu.research.smallthinker import smallthinker_model
 from benchmark.harness import smallthinker_reference as reference
 
 SMALL = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
@@ -109,6 +111,91 @@ class TestModelAgainstReference:
     from tensor2robot_tpu.models.abstract_model import AbstractT2RModel
 
     assert AbstractT2RModel.report_gradient_norm is False
+
+
+FLASH_KERNELS = ('flash_attention_fwd', 'flash_attention_bwd_dkv',
+                 'flash_attention_bwd_dq')
+
+
+def _block_stack(block_cls, blocks, windowed, attention_mode='flash'):
+  """(loss over parameters and input, parameters, input) of ``blocks``
+  blocks of ``block_cls``, on the flash kernels unless told otherwise: a
+  window layer with rotary positions, or a full layer with none."""
+  x = jax.random.normal(jax.random.PRNGKey(10), (2, 32, 64))
+
+  class Stack(nn.Module):
+
+    @nn.compact
+    def __call__(self, x):
+      for i in range(blocks):
+        x, _ = block_cls(
+            num_heads=4, num_kv_heads=2, head_dim=16, num_experts=8,
+            experts_held=(2, 4), expert_dim=32, top_k=3,
+            window=8 if windowed else None,
+            rope_theta=1.5e6 if windowed else None,
+            attention_mode=attention_mode,
+            moe_block_rows=8, name='block{}'.format(i))(x)
+      return jnp.sum(jnp.sin(x))
+
+  stack = Stack()
+  return stack.apply, stack.init(jax.random.PRNGKey(11), x), x
+
+
+class TestTheBlockCheckpointKeepsWhatTheAttentionBackwardReads:
+  """Pallas interpreter, tiny widths. ``nn.remat`` with no policy is the
+  checkpoint the model had before: it ran the flash forward twice a block."""
+
+  @pytest.mark.parametrize('blocks', [1, 2])
+  @pytest.mark.parametrize('windowed', [False, True], ids=['full', 'window'])
+  def test_one_forward_kernel_a_block_and_the_same_gradients(
+      self, windowed, blocks, jaxpr_calls):
+    results = {}
+    for name, block_cls in [
+        ('kept', smallthinker_model.CheckpointedBlock),
+        ('policy-less', nn.remat(transformer_lib.RouterFirstMoEBlock))]:
+      loss, params, x = _block_stack(block_cls, blocks, windowed)
+      grad = jax.value_and_grad(loss, argnums=(0, 1))
+      calls, _ = jaxpr_calls(grad, params, x)
+      results[name] = dict(kernels=[calls[k] for k in FLASH_KERNELS],
+                           products=calls['dot_general'],
+                           grads=jax.tree.leaves(grad(params, x)))
+    kept, before = results['kept'], results['policy-less']
+    assert kept['kernels'] == [blocks, blocks, blocks]
+    assert before['kernels'] == [2 * blocks, blocks, blocks]
+    # q, k and v are kept too: their three projections are not run again.
+    assert kept['products'] == before['products'] - 3 * blocks
+    got, want = kept['grads'], before['grads']
+    assert len(got) == len(want) == 1 + 10 * blocks + 1
+    # The kept arrays are the ones the second forward produced: on the CPU
+    # loss and every gradient leaf come out the same to the last bit.
+    for g, w in zip(got, want):
+      np.testing.assert_array_equal(g, w)
+
+  def test_the_names_kept_are_the_ones_the_kernels_give(self, jaxpr_calls):
+    loss, params, x = _block_stack(smallthinker_model.CheckpointedBlock, 1,
+                                   True)
+    # The policy is made from BACKWARD_READS; a tag renamed in the forward
+    # rule alone fails here and does not silently bring the second forward
+    # back.
+    _, tags = jaxpr_calls(jax.grad(loss), params, x)
+    assert set(tags) == set(transformer_lib.flash_lib.BACKWARD_READS)
+
+  def test_the_dense_backend_carries_no_names(self, jaxpr_calls):
+    loss, params, x = _block_stack(smallthinker_model.CheckpointedBlock, 1,
+                                   True, attention_mode='xla')
+    calls, tags = jaxpr_calls(jax.grad(loss), params, x)
+    assert not tags and not any(calls[k] for k in FLASH_KERNELS)
+
+  def test_the_model_checkpoints_its_blocks_with_that_policy(
+      self, small, jaxpr_calls, monkeypatch):
+    _, params, _, program = small
+    # 'auto' picks the dense backend on the CPU at this length; the chip at
+    # 8,192 tokens picks the flash kernels.
+    monkeypatch.setattr(transformer_lib, 'resolve_attention_mode',
+                        lambda mode, length: 'flash')
+    calls, tags = jaxpr_calls(jax.grad(program), params)
+    assert [calls[k] for k in FLASH_KERNELS] == [4, 4, 4]
+    assert set(tags) == set(transformer_lib.flash_lib.BACKWARD_READS)
 
 
 def _dense_attention(q, k, v, window):
