@@ -13,7 +13,6 @@ from tensor2robot_tpu.compile.artifact import (
     ARTIFACT_HITS_COUNTER,
     ARTIFACT_MISSES_COUNTER,
     ARTIFACT_SCHEMA,
-    COLDSTART_BENCH_KEYS,
     COMPILE_RECORD_KIND,
     DRIFT_COUNTER,
     FINGERPRINT_DRIFT,
@@ -31,7 +30,6 @@ __all__ = [
     'ARTIFACT_HITS_COUNTER',
     'ARTIFACT_MISSES_COUNTER',
     'ARTIFACT_SCHEMA',
-    'COLDSTART_BENCH_KEYS',
     'COMPILE_RECORD_KIND',
     'DRIFT_COUNTER',
     'FINGERPRINT_DRIFT',

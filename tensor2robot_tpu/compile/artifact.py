@@ -59,7 +59,7 @@ from tensor2robot_tpu.reliability.logutil import log_warning
 __all__ = [
     'ARTIFACT_SCHEMA', 'ARTIFACT_DIRNAME', 'COMPILE_RECORD_KIND',
     'FINGERPRINT_DRIFT', 'ARTIFACT_HITS_COUNTER', 'ARTIFACT_MISSES_COUNTER',
-    'DRIFT_COUNTER', 'COLDSTART_BENCH_KEYS', 'CompiledArtifact',
+    'DRIFT_COUNTER', 'CompiledArtifact',
     'ArtifactStore', 'artifact_key', 'program_sha', 'compile_lowered',
     'resolve_cache_winner', 'load_or_compile',
 ]
@@ -73,23 +73,6 @@ FINGERPRINT_DRIFT = 'fingerprint_drift'
 ARTIFACT_HITS_COUNTER = 'compile/artifact_hits'
 ARTIFACT_MISSES_COUNTER = 'compile/artifact_misses'
 DRIFT_COUNTER = 'compile/fingerprint_drift'
-
-# The bench's cold-start axis (schema-locked by bin/check_artifact_doctor
-# exactly like the E2E/REPLAY/RL key tuples): cold vs warm
-# time-to-first-step for the qtopt trainer measured in SUBPROCESSES
-# (a true process cold start, not a warm in-process jit cache), the
-# warm leg's backend-compile count around its first step (MUST be 0 —
-# the zero-compile cold-start contract as a number), serving
-# time-to-ready on a warm store, and the store's hit/miss counts.
-COLDSTART_BENCH_KEYS = (
-    'coldstart_time_to_first_step_s_cold',
-    'coldstart_time_to_first_step_s_warm',
-    'coldstart_warm_vs_cold',
-    'coldstart_warm_compiles',
-    'coldstart_serving_time_to_ready_warm_s',
-    'coldstart_artifact_hits',
-    'coldstart_artifact_misses',
-)
 
 
 @dataclasses.dataclass

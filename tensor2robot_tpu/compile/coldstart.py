@@ -1,4 +1,4 @@
-"""Measured zero-compile cold start: the COLDSTART bench leg.
+"""Measured zero-compile cold start of a trainer and a serving program.
 
 Builds the test-scale qtopt critic (the sim critic — a real
 Grasping44-spec-keyed QT-Opt model), binds its train step through the
@@ -23,10 +23,10 @@ step, and reports:
     ``serving/artifact.py`` path);
   * ``artifact_hits`` / ``artifact_misses`` — the store counters.
 
-Run it as a SUBPROCESS for a true process cold start (bench.py does:
-an in-process "warm" leg would also be warmed by jax's per-object and
-eager caches, which is exactly the measurement error the subprocess
-discipline exists to kill):
+Run it as a SUBPROCESS for a true process cold start (an in-process
+"warm" leg would also be warmed by jax's per-object and eager caches,
+which is exactly the measurement error the subprocess discipline
+exists to kill):
 
     python -m tensor2robot_tpu.compile.coldstart \
         --cache_path /tmp/store/tuning_cache.json --model_dir /tmp/run
@@ -53,9 +53,8 @@ def measure(cache_path: str, model_dir: str, batch_size: int = 8,
 
   ``model_name``: ``'sim'`` (the test-scale sim critic at
   height x width — what the test suite uses) or ``'grasping44'`` (the
-  REAL flagship 19-layer QT-Opt critic at camera resolution — what the
-  bench uses: its multi-second step compile makes the cold-vs-warm
-  delta unmistakable).
+  REAL flagship 19-layer QT-Opt critic at camera resolution: its
+  multi-second step compile makes the cold-vs-warm delta unmistakable).
   """
   import jax
   import numpy as np
@@ -113,7 +112,7 @@ def measure(cache_path: str, model_dir: str, batch_size: int = 8,
     features, labels = next(iterator)
     t_start = time.perf_counter()
     state = trainer.init_state(features, labels)
-    step_fn = trainer._compile_train_step()  # noqa: SLF001 — the bench
+    step_fn = trainer._compile_train_step()  # noqa: SLF001 — this
     # measures the exact first-call bind path the train loop drives.
     device_batch = trainer._put_batch(  # noqa: SLF001
         {'features': features.to_dict(), 'labels': labels.to_dict()})
@@ -186,7 +185,7 @@ def main(argv=None) -> int:
   parser.add_argument('--model', default='sim',
                       choices=('sim', 'grasping44'),
                       help='trainer model: test-scale sim critic or the '
-                           'flagship 19-layer QT-Opt critic (bench).')
+                           'flagship 19-layer QT-Opt critic.')
   args = parser.parse_args(argv)
   result = measure(args.cache_path, args.model_dir,
                    batch_size=args.batch_size, height=args.height,

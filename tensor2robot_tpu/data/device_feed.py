@@ -102,10 +102,10 @@ class HostDeviceFeed:
     dispatch: ``device_put`` returns after enqueueing the copy, and on a
     transfer-limited link a dispatch-only measurement would overestimate
     transfer capacity by orders of magnitude and the X-ray could never
-    attribute the stage bench names. Blocking here costs no overlap: this host thread waits
-    while the device still runs the PREVIOUS step (and the production
-    e2e path calls this from :class:`DoubleBufferedFeed`'s producer
-    thread, where the wait is free by construction).
+    attribute the stage. Blocking here costs no overlap: this host
+    thread waits while the device still runs the PREVIOUS step (and the
+    production e2e path calls this from :class:`DoubleBufferedFeed`'s
+    producer thread, where the wait is free by construction).
 
     Only the ``'train'`` channel feeds the ``pipeline/transfer`` stage
     counters — the X-ray's e2e flow meter counts train batches, so an
@@ -336,10 +336,10 @@ class PipelinedFeed:
 
   Wraps a host-batch iterator and a feed: a daemon producer thread
   decodes and ships batches k+1..k+depth while the device runs step k.
-  Depth 2 is the classic double buffer; deeper pipelines (the e2e bench
-  runs 4) keep the host->device link busy CONTINUOUSLY — with a shallow
-  buffer, any decode hiccup drains it and the link then idles while the
-  device computes, so the achieved MB/s sits below the link's capacity.
+  Depth 2 is the classic double buffer; deeper pipelines keep the
+  host->device link busy CONTINUOUSLY — with a shallow buffer, any
+  decode hiccup drains it and the link then idles while the device
+  computes, so the achieved MB/s sits below the link's capacity.
 
   Design invariants:
 

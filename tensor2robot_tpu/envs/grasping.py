@@ -66,7 +66,7 @@ class ScenarioConfig:
   """Per-slot randomization ranges; the defaults reproduce the numpy
   env's fixed constants (no randomization — the parity configuration).
 
-  ``randomized()`` is the scenario-sweep preset the loop/bench use: a
+  ``randomized()`` is the scenario-sweep preset the RL loop uses: a
   spread of grasp thresholds (object geometry), descent scales
   (dynamics), small camera shifts and sensor-noise levels. Buckets
   partition ``threshold_range`` into ``num_buckets`` equal difficulty

@@ -27,7 +27,7 @@ Both functions must be traceable (jit/vmap-safe) and totally
 deterministic given ``(state, action)`` — all randomness flows through
 per-slot PRNG keys carried IN the state, which is what makes the acting
 step's jit cache hold exactly one executable per signature (the
-zero-request-time-compile invariant the RL bench asserts).
+zero-request-time-compile invariant tests/test_rl_loop.py asserts).
 """
 
 from __future__ import annotations

@@ -10,8 +10,8 @@ sequence-sharded device mesh:
   * ``attention_mode='xla'``   — dense einsum attention (oracle; small L).
   * ``attention_mode='flash'`` — the Pallas blockwise kernel
     (parallel/flash_attention.py): O(L) memory, Pallas forward AND
-    backward; measured numbers live in docs/performance.md (fwd ~3.8x
-    XLA at L=16k; trains at L=32k where dense attention OOMs on a v5e).
+    backward; what it measures in a cell of the benchmark is in
+    PERF.md section 5.
   * ``attention_mode='ring'``  — ring attention over the mesh's sequence
     axis (parallel/ring_attention.py): O(L/N) per-device memory with k/v
     blocks rotating over ICI; trainable via its blockwise-recompute VJP.

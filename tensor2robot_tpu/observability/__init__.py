@@ -39,13 +39,13 @@ answers:
     forensics reports (``bin/t2r_telemetry doctor``; jax-free).
 
 Pipeline X-ray (ISSUE 7) makes the host->device data path a measured,
-per-stage quantity instead of a bench-time inference:
+per-stage quantity:
 
   * `pipeline_xray.py` — the stage model (read/decode/batch/transfer/
     device), source-side ``StageMeter`` counters every data layer
     reports into, the windowed ``PipelineXray`` bottleneck attribution
     (``t2r.pipeline.v1`` records in telemetry.jsonl), the
-    ``attribute_stages`` rule bench.py shares, and the pipeline anomaly
+    ``attribute_stages`` rule, and the pipeline anomaly
     kinds (``pipeline_stall`` / ``worker_starvation`` /
     ``transfer_regression``) feeding the capture loop.
 
@@ -71,11 +71,9 @@ bound-class evidence and makes MFU a live signal:
     arithmetic intensity, compute/memory/ragged bound class, % peak,
     fusion headroom; CPU degrades to intensity-only), and the
     ``perf/mfu`` / ``perf/hbm_bw_util`` gauges the trainer publishes
-    every log window from the SAME shared cost helper bench.py uses.
-    The watchdog's ``mfu_regression`` kind and doctor's roofline
-    verdict (naming the gating memory-bound family) read them; the
-    kernel microbench rig that consumes the ranking lives in
-    `tuning/kernelbench.py` + ``bin/t2r_kernelbench``.
+    every log window from `parallel/hlo_analysis.program_cost`. The
+    watchdog's ``mfu_regression`` kind and doctor's roofline verdict
+    (naming the gating memory-bound family) read them.
 
 Metric name catalog, forensics report schema, and goodput definitions:
 docs/observability.md.
@@ -114,7 +112,6 @@ from tensor2robot_tpu.observability.pipeline_xray import (
 from tensor2robot_tpu.observability.roofline import (
     HBM_BW_GAUGE,
     MFU_GAUGE,
-    ROOFLINE_BENCH_KEYS,
     ROOFLINE_SCHEMA,
     build_record as build_roofline_record,
     classify_bound,
@@ -177,7 +174,6 @@ __all__ = [
     'HEARTBEAT_FILENAME',
     'Histogram',
     'MFU_GAUGE',
-    'ROOFLINE_BENCH_KEYS',
     'ROOFLINE_SCHEMA',
     'PIPELINE_RECORD_SCHEMA',
     'PipelineXray',

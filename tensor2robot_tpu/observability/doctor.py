@@ -52,8 +52,8 @@ _SEVERITY_RANK = {CRITICAL: 0, WARNING: 1, INFO: 2, OK: 3}
 _GOODPUT_FLOOR = 0.10
 
 # MFU below this on a device with a peaks entry earns a roofline
-# verdict naming the gating memory-bound family (BENCH_r05's device
-# headline sits at 36.5%; a healthy run should not be under 25%).
+# verdict naming the gating memory-bound family (a healthy run should
+# not be under 25%).
 _MFU_FLOOR = 0.25
 
 

@@ -1,12 +1,9 @@
 """Pipeline X-ray: per-stage host->device dataflow tracing + attribution.
 
-The host->device data path was a black box between bench runs: bench.py
-measured stage rates offline (BENCH_r05 names ``e2e_bottleneck:
-"transfer"`` at 24.6 MB/s) but a live run had no stage-level throughput,
-queue-occupancy, or backpressure signal anywhere — a regression in the
-input path only showed up as a mysterious goodput ``data`` fraction.
-This module closes that gap with a stage model every data layer reports
-into (docs/observability.md "Pipeline X-ray"):
+Without stage-level throughput, queue-occupancy or backpressure signals
+a regression in the host->device input path only shows up as a
+mysterious goodput ``data`` fraction. This module is the stage model
+every data layer reports into (docs/observability.md "Pipeline X-ray"):
 
   * ``read``     — record I/O: the C++ loader's reader thread
                    (record_loader.cc stats export) or the Python
@@ -31,10 +28,8 @@ log cadence into per-stage CAPACITY estimates
 decode pool). Capacity — not raw throughput — is the attributable
 quantity: in steady state every stage's throughput equals the e2e rate
 by construction, but busy-time-derived capacity names the stage that
-would gate if everything upstream were infinite. The same attribution
-rule (:func:`attribute_stages`) is what ``bench.py`` uses for its
-``e2e_bottleneck`` field, so bench and live training report the SAME
-quantity.
+would gate if everything upstream were infinite
+(:func:`attribute_stages` is the attribution rule).
 
 Each ``observe()`` yields a ``t2r.pipeline.v1`` record (written to
 ``telemetry.jsonl`` as kind ``pipeline``) naming the gating stage and
@@ -75,7 +70,6 @@ __all__ = [
     'WORKER_STARVATION',
     'TRANSFER_REGRESSION',
     'STAGES',
-    'E2E_WIRE_BENCH_KEYS',
     'StageMeter',
     'XrayConfig',
     'PipelineXray',
@@ -84,26 +78,6 @@ __all__ = [
 ]
 
 PIPELINE_RECORD_SCHEMA = 't2r.pipeline.v1'
-
-# The transfer-path keys a successful bench e2e section must publish
-# (bench.py emits them and self-checks against this tuple; the jax-free
-# bin/check_pipeline_doctor gate schema-locks it — ISSUE 10). Kept here,
-# next to attribute_stages, because the wire rate these keys carry is
-# the 'transfer' input of the shared attribution rule.
-E2E_WIRE_BENCH_KEYS = (
-    'e2e_samples_per_sec',
-    'e2e_samples_per_sec_spread',
-    'e2e_bytes_per_example',
-    'e2e_transfer_compression',
-    'e2e_transfer_overlap',
-    'e2e_transfer_overlap_spread',
-    'transfer_mb_per_sec',
-    'transfer_mb_per_sec_spread',
-    'e2e_wire_examples_per_sec',
-    'e2e_wire_examples_per_sec_spread',
-    'e2e_bottleneck',
-    'e2e_headroom_vs_device',
-)
 
 # New watchdog anomaly kinds (counted into watchdog/anomalies like the
 # step-time/goodput/recompile/hbm kinds from observability/watchdog.py).
@@ -162,13 +136,11 @@ def attribute_stages(rates: Dict[str, Optional[float]]
                      ) -> Dict[str, object]:
   """Names the gating stage from per-stage examples/sec rates.
 
-  THE shared attribution rule: ``bench.py`` feeds it separately-measured
-  stage benches; :class:`PipelineXray` feeds it live busy-time capacity
-  estimates — both report the same ``bottleneck`` semantics. Stages with
-  missing/non-positive rates are skipped (an unmeasured stage is unknown,
-  not infinitely fast — but it must not win the argmin by defaulting to
-  zero). Ties break deterministically toward the lexicographically first
-  stage name.
+  :class:`PipelineXray` feeds it live busy-time capacity estimates.
+  Stages with missing/non-positive rates are skipped (an unmeasured
+  stage is unknown, not infinitely fast — but it must not win the argmin
+  by defaulting to zero). Ties break deterministically toward the
+  lexicographically first stage name.
 
   Returns ``{'bottleneck': <stage|None>, 'headroom_vs_device': <float|
   None>, 'rates': {stage: rate}}`` where headroom is the gating stage's
@@ -240,7 +212,7 @@ class PipelineXray:
     self.config = config or XrayConfig()
     self._registry = registry
     # Seed the counter baseline at construction: the registry is
-    # process-wide, so a prior Trainer/eval/bench phase in the same
+    # process-wide, so a prior Trainer/eval phase in the same
     # process may already hold pipeline counters — diffing the first
     # window against zero would fold that whole history into one
     # window's rates (busy fractions over 1.0, garbage capacities).

@@ -2,13 +2,10 @@
 
 The closed actor<->learner loop (rl/loop.py, ISSUE 12) reports one
 ``kind="rl"`` record per report window; this module is the schema's
-single home — record kind/schema, registry series names, the
-``RL_LOOP_BENCH_KEYS`` tuple ``bench.py`` self-checks its closed-loop
-axis against (and ``bin/check_rl_doctor`` schema-locks), and the
-per-scenario success-spread rule — kept in ``observability/`` (like
-``pipeline_xray.E2E_WIRE_BENCH_KEYS``) so the jax-free readers
-(``doctor``, ``t2r_telemetry``, the CI gate) and the jax-heavy writer
-share ONE definition without the gate importing jax.
+single home — record kind/schema, registry series names and the
+per-scenario success-spread rule — kept in ``observability/`` so the
+jax-free readers (``doctor``, ``t2r_telemetry``, the CI gate) and the
+jax-heavy writer share ONE definition without the gate importing jax.
 
 Record fields (every rate is a window delta over ``window_seconds``):
 
@@ -41,7 +38,7 @@ from __future__ import annotations
 
 from typing import Dict, Mapping, Optional
 
-__all__ = ['RL_RECORD_KIND', 'RL_RECORD_SCHEMA', 'RL_LOOP_BENCH_KEYS',
+__all__ = ['RL_RECORD_KIND', 'RL_RECORD_SCHEMA',
            'RL_EPISODES_COUNTER', 'RL_SUCCESSES_COUNTER',
            'RL_ENV_STEPS_COUNTER', 'RL_ACTOR_STEPS_COUNTER',
            'RL_LEARNER_STEPS_COUNTER', 'RL_TRANSITIONS_COUNTER',
@@ -68,23 +65,6 @@ RL_ACT_MS_HISTOGRAM = 'rl/act_step_ms'
 # Same family as the trainer's recompiles/train_step: the acting
 # program's jit cache size, ==1 healthy after warmup.
 ACT_RECOMPILE_GAUGE = 'recompiles/act_step'
-
-# The closed-loop bench axis keys a successful `bench.py` rl section
-# must publish (bench self-checks; bin/check_rl_doctor schema-locks).
-# The bars these keys carry — success measurably rising over wallclock
-# (`rl_success_curve` samples), zero request-time compiles in the
-# acting path (`rl_act_jit_cache` == 1) — ARE the loop's contract.
-RL_LOOP_BENCH_KEYS = (
-    'rl_num_envs',
-    'rl_episodes_per_sec',
-    'rl_episodes_per_sec_spread',
-    'rl_env_steps_per_sec',
-    'rl_success_rate_final',
-    'rl_success_curve',
-    'rl_swap_count',
-    'rl_scenario_success_spread',
-    'rl_act_jit_cache',
-)
 
 
 def scenario_success_spread(

@@ -15,10 +15,10 @@ peak_flops/peak_bandwidth):
     class (compute / memory / ragged), % of device peak, and roofline
     headroom — measured ms minus the roofline-bound ms, i.e. the
     predicted win from fusing that family to the roofline.
-  * ``publish_perf_gauges`` turns MFU from a once-per-bench number into
-    a LIVE signal: the trainer calls it every log window and the
-    ``perf/mfu`` / ``perf/hbm_bw_util`` gauges feed TensorBoard,
-    telemetry.jsonl, and the watchdog's ``mfu_regression`` anomaly.
+  * ``publish_perf_gauges`` makes MFU a LIVE signal: the trainer calls
+    it every log window and the ``perf/mfu`` / ``perf/hbm_bw_util``
+    gauges feed TensorBoard, telemetry.jsonl, and the watchdog's
+    ``mfu_regression`` anomaly.
   * ``PEAKS`` is the small per-``device_kind`` peaks table (dense bf16
     FLOP/s + HBM GB/s). Unknown kinds — CPU above all — degrade to
     ``mode='intensity-only'``: intensities still rank and classify by
@@ -280,11 +280,11 @@ def build_record(families: Sequence[Tuple[str, float]],
 def static_gating_family(cost_table: Dict[str, Dict[str, float]],
                          device_kind: str) -> Optional[str]:
   """Memory-bound family with the largest roofline-bound ms — from the
-  cost table ALONE, no measurement. What bench.py publishes before any
-  capture exists: the family whose best-case (roofline) time is the
-  biggest memory-bound share of the step, i.e. where a fused kernel has
-  the most predicted room. None when the device kind has no peaks entry
-  (intensity alone cannot place the ridge) or nothing is memory-bound.
+  cost table ALONE, no measurement: the family whose best-case
+  (roofline) time is the biggest memory-bound share of the step, i.e.
+  where a fused kernel has the most predicted room. None when the
+  device kind has no peaks entry (intensity alone cannot place the
+  ridge) or nothing is memory-bound.
   """
   peaks = device_peaks(device_kind)
   if not peaks:
@@ -355,19 +355,3 @@ def telemetry_payload(record: Dict[str, object],
       'gating_memory_bound_family': record.get('gating_memory_bound_family'),
       'families': families,
   }
-
-
-# Keys bench.py publishes for the roofline axis (BENCH_r06+), self-
-# checked like E2E_WIRE_BENCH_KEYS; -1/'' sentinels when an axis fails.
-ROOFLINE_BENCH_KEYS = (
-    'flops_per_step',
-    'hbm_bytes_per_step',
-    'arithmetic_intensity',
-    'flops_source',
-    'roofline_mode',
-    'roofline_bound',
-    'roofline_ridge_intensity',
-    'roofline_gating_family',
-    'mfu',
-    'hbm_bw_util',
-)

@@ -108,8 +108,7 @@ class Anomaly:
 
 class WatchdogConfig:
   """Thresholds; defaults tuned to fire on sustained 2x regressions, not
-  single-window jitter (shared-chip variance runs a few percent,
-  docs/performance.md)."""
+  single-window jitter."""
 
   def __init__(self,
                regression_ratio: float = 1.8,

@@ -25,10 +25,10 @@ Memory: per-device O(L*D) activations only — no score tensor ever reaches
 HBM, forward OR backward: since round 4 the backward is the same kernel
 family (two Pallas kernels, FlashAttention-2 structure, causal block
 skip — _flash_bwd_pallas) instead of an XLA scan. Numerics match the XLA
-oracle to f32 rounding (tests/test_flash_attention.py); measured numbers
-in docs/performance.md (B=1 H=8 D=128 causal, jax 0.9: fwd 7.7/12.1/29.6
-ms at L=4k/16k/32k vs XLA 13.1/46.4/OOM; fwd+bwd 10.8/18.1/60.4 ms vs
-XLA 11.3/uncompilable/uncompilable). This is the single-device
+oracle to f32 rounding (tests/test_flash_attention.py); what the three
+kernels measure in a cell of the benchmark is in PERF.md (section 5:
+time a step by kernel, the work executed beside the work needed, and
+``attention_roofline``). This is the single-device
 long-context path; ring_attention.py handles the cross-device dimension
 with its own shard-level blockwise accumulation.
 
@@ -719,7 +719,7 @@ def flash_attention(q, k, v,
   chained on-device timing): (1024, 1024) — grid-step count (fixed
   per-step overhead) and k/v re-fetch traffic are the levers, so bigger
   blocks win until the f32 score matrix presses the 16 MB scoped-VMEM
-  limit. Measured ms in docs/performance.md.
+  limit.
 
   Head dims below 128 are zero-padded up to 128 for the kernels: jax
   0.9's Mosaic rejects memref slices whose lane extent is not 128-aligned,

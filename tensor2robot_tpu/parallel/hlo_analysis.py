@@ -368,7 +368,7 @@ def hlo_program_cost(hlo_text: str) -> Dict[str, float]:
 
 
 def program_cost(compiled_or_text) -> Dict[str, object]:
-  """THE shared FLOPs/bytes accounting helper (bench, trainer, roofline).
+  """THE shared FLOPs/bytes accounting helper (trainer, roofline).
 
   Accepts a compiled executable or its ``as_text()`` string. Prefers the
   backend's own ``cost_analysis()`` (exact, fusion-aware); falls back to

@@ -5,8 +5,7 @@ The reference crops with ``tf.image.crop_to_bounding_box`` on host CPU
 device-side equivalent (`preprocessors/image_transformations.py`
 ``crop_images``) vmaps ``lax.dynamic_slice`` over the batch, which XLA
 lowers to a sequential while-loop over examples, followed by a separate
-uint8->float convert + conv-input relayout — together ~10 ms of the
-batch-512 QT-Opt train step (docs/performance.md per-op table).
+uint8->float convert + conv-input relayout.
 
 This kernel does the whole thing in one pipelined pass: each grid step
 pulls one uint8 frame into VMEM, rotates rows/lanes by the example's
@@ -21,9 +20,8 @@ isolation — but ~3% SLOWER inside the full batch-512 QT-Opt train step
 (183.6 ms f32-out / 180.3 ms bf16-out vs 178.4 ms), where XLA fuses the
 convert into neighboring ops and the opaque pallas_call re-introduces a
 fusion barrier + conv1-input relayout. The QT-Opt preprocessor therefore
-defaults this OFF (docs/performance.md "Measured dead ends"); the kernel
-stays as the measured record and for pipelines whose crop is not
-adjacent to a large fusible program.
+defaults this OFF (ROADMAP D3); the kernel stays as the measured record
+and for pipelines whose crop is not adjacent to a large fusible program.
 
 Mosaic constraints that shaped the kernel (jax 0.9):
 

@@ -37,7 +37,6 @@ from tensor2robot_tpu.replay.sampling import (
     make_policy,
 )
 from tensor2robot_tpu.replay.service import (
-    REPLAY_BENCH_KEYS,
     REPLAY_RECORD_KIND,
     REPLAY_RECORD_SCHEMA,
     ReplayConfig,
@@ -58,7 +57,6 @@ __all__ = [
     'LocalReplayClient',
     'POLICIES',
     'PrioritizedPolicy',
-    'REPLAY_BENCH_KEYS',
     'REPLAY_RECORD_KIND',
     'REPLAY_RECORD_SCHEMA',
     'RETENTIONS',

@@ -12,7 +12,7 @@ Two interchangeable clients behind one API:
     sleep.
   * :class:`LocalReplayClient` — the same API over an in-process
     :class:`~tensor2robot_tpu.replay.service.ReplayService` (tests,
-    single-host runs, bench preloads).
+    single-host runs).
 
 ``sample`` can ``wait`` for the store to fill: a learner that starts
 before its collectors is a normal boot order, not an error.

@@ -66,7 +66,7 @@ from tensor2robot_tpu.serving.batching import (
 
 __all__ = ['ReplayConfig', 'ReplayService', 'ReplayEmpty', 'SampleBatch',
            'REPLAY_RECORD_KIND', 'REPLAY_RECORD_SCHEMA',
-           'REPLAY_REJECTED_COUNTER', 'REPLAY_BENCH_KEYS']
+           'REPLAY_REJECTED_COUNTER']
 
 REPLAY_RECORD_KIND = 'replay'
 REPLAY_RECORD_SCHEMA = 't2r.replay.v1'
@@ -81,24 +81,6 @@ REPLAY_OCCUPANCY_EXAMPLES_GAUGE = 'replay/occupancy_examples'
 REPLAY_OCCUPANCY_BYTES_GAUGE = 'replay/occupancy_bytes'
 REPLAY_QUEUE_DEPTH_GAUGE = 'replay/sample_queue_depth'
 REPLAY_SAMPLE_MS_HISTOGRAM = 'replay/sample_ms'
-
-# The replay bench axis keys a successful `bench.py` replay section must
-# publish (bench self-checks against this tuple; the jax-free
-# bin/check_replay_doctor gate schema-locks it — ISSUE 11 acceptance).
-# Kept here, next to the record schema, because the parity bar these
-# keys carry (learner e2e within 5% of local disk, at-rest bytes within
-# 1.1x of the wire) IS the service's contract.
-REPLAY_BENCH_KEYS = (
-    'replay_writers',
-    'replay_append_examples_per_sec',
-    'replay_e2e_samples_per_sec',
-    'replay_e2e_samples_per_sec_spread',
-    'replay_e2e_vs_disk',
-    'replay_sample_p99_ms',
-    'replay_wire_bytes_per_example',
-    'replay_at_rest_bytes_per_example',
-    'replay_at_rest_overhead',
-)
 
 
 class ReplayEmpty(RuntimeError):
@@ -535,7 +517,7 @@ class ReplayService:
   # -- introspection ---------------------------------------------------------
 
   def stats(self) -> Dict[str, Any]:
-    """Cumulative service stats (frontend /healthz + bench)."""
+    """Cumulative service stats (frontend /healthz)."""
     shards = {str(i): shard.counters()
               for i, shard in enumerate(self._shards)}
     for index, entry in shards.items():

@@ -397,8 +397,7 @@ def make_sim_critic_model(height: int = 64, width: int = 80, **kwargs):
 
   Same spec keys and on-disk names as the Grasping44 flagship (so the
   replay/candidate helpers above work unchanged), tiny network, any
-  resolution. Used by tests/test_offpolicy.py; the bench uses the real
-  Grasping44 critic at full camera resolution.
+  resolution. Used by tests/test_offpolicy.py and the RL loop.
   """
   from tensor2robot_tpu.models.critic_model import CriticModel
 

@@ -168,7 +168,7 @@ class DefaultGrasping44ImagePreprocessor(SpecTransformationPreprocessor):
     dynamic-slice + separate float convert. Numerics match the XLA path
     to 1 ulp with identical crop-offset sampling — but measured in the
     FULL batch-512 train step the kernel is ~3% SLOWER (183.6/180.3 ms
-    f32/bf16-out vs 178.4 ms; docs/performance.md "Measured dead ends")
+    f32/bf16-out vs 178.4 ms on an installation that is gone; ROADMAP D3)
     despite being 7.5x faster in isolation: XLA fuses the convert into
     neighboring ops and the opaque pallas_call re-introduces a fusion
     barrier + conv1-input relayout. Default (``None``) therefore resolves

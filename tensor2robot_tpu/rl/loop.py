@@ -31,9 +31,8 @@ off-policy organ set the previous PRs built, concurrently:
     and claims exactly one budgeted capture while the learner keeps
     stepping (tests/test_rl_loop.py).
 
-``bin/t2r_rl_loop`` is the entry point; ``bench.py`` publishes the
-closed-loop axis (``RL_LOOP_BENCH_KEYS``); docs/rl_loop.md is the
-operator contract.
+``bin/t2r_rl_loop`` is the entry point; docs/rl_loop.md is the operator
+contract.
 """
 
 from __future__ import annotations
@@ -991,7 +990,7 @@ def build_grasping_loop(model_dir: str,
   ``replay``: None (an in-process ReplayService is created and owned by
   the loop), a ``host:port``/URL endpoint string, a ReplayService, or
   any client-API object. The critic is the test-scale sim critic at the
-  env resolution with the adam recipe the off-policy bench uses; the
+  env resolution with the adam recipe tests/test_offpolicy.py uses; the
   env randomizes scenarios per slot unless ``scenario_config`` pins
   them.
   """

@@ -29,8 +29,7 @@ that loop in-process, TPU-first:
 The target forward defaults to batch-statistics mode (TRAIN-mode BN,
 state untouched): early in training the running stats a PREDICT forward
 would use are cold, and bootstrapped targets computed through them are
-systematically wrong for thousands of steps (the round-2 practitioner
-note on the convergence benchmark, docs/performance.md).
+systematically wrong for thousands of steps.
 """
 
 from __future__ import annotations
@@ -284,7 +283,7 @@ def concat_ranking_pairs(pairs):
   Returns ``(combined, arm_rows)``: a single feature dict with all arms
   stacked along the batch dim in pair order (better0, worse0, better1,
   worse1, ...), and the per-arm row counts needed to split scores back
-  out. Callers that evaluate on-device repeatedly (bench.py) concatenate
+  out. Callers that evaluate on-device repeatedly concatenate
   once, ``device_put`` the combined batch, and score each eval with
   :func:`ranking_accuracy_from_scores`.
   """

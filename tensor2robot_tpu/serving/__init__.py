@@ -30,7 +30,6 @@ from tensor2robot_tpu.serving.batcher import (
     split_outputs,
 )
 from tensor2robot_tpu.serving.fleet import (
-    SERVING_FLEET_BENCH_KEYS,
     SERVING_FLEET_RECORD_KIND,
     SERVING_FLEET_SCHEMA,
     ServingFleet,
@@ -65,7 +64,6 @@ __all__ = [
     'RequestRejected',
     'RoutedResult',
     'RouterConfig',
-    'SERVING_FLEET_BENCH_KEYS',
     'SERVING_FLEET_RECORD_KIND',
     'SERVING_FLEET_SCHEMA',
     'SERVING_RECORD_KIND',

@@ -24,8 +24,7 @@ serving-specific:
 The contract is unchanged: a warm restart deserializes and compiles
 NOTHING; a cold start (or a stale/corrupt artifact) falls back to one
 AOT compile and re-persists; either way there is nothing left to
-compile when the first request arrives (``serving.request_time_compiles
-== 0`` in the bench).
+compile when the first request arrives.
 """
 
 from __future__ import annotations

@@ -51,7 +51,7 @@ from tensor2robot_tpu.serving.router import (
 
 __all__ = ['ServingFleet', 'ServingFleetConfig', 'replica_host_meta',
            'router_host_meta', 'SERVING_FLEET_RECORD_KIND',
-           'SERVING_FLEET_SCHEMA', 'SERVING_FLEET_BENCH_KEYS',
+           'SERVING_FLEET_SCHEMA',
            'FLEET_SCALE_UPS_COUNTER', 'FLEET_SCALE_DOWNS_COUNTER']
 
 SERVING_FLEET_RECORD_KIND = 'serving_fleet'
@@ -59,26 +59,6 @@ SERVING_FLEET_SCHEMA = 't2r.serving_fleet.v1'
 
 FLEET_SCALE_UPS_COUNTER = 'serving_fleet/scale_ups'
 FLEET_SCALE_DOWNS_COUNTER = 'serving_fleet/scale_downs'
-
-# The serving-fleet bench axis, schema-locked by bin/check_serving_slo
-# (same discipline as E2E_WIRE/REPLAY/RL_LOOP/COLDSTART keys): the
-# throughput-at-SLO scaling curve vs replica count, the zero-compile
-# contracts (request time AND artifact-warm scale-up), the scale-up
-# readiness latency, and the mid-load rolling swap outcome.
-SERVING_FLEET_BENCH_KEYS = (
-    'serving_fleet_actions_per_sec_r1',
-    'serving_fleet_actions_per_sec_r2',
-    'serving_fleet_actions_per_sec_r4',
-    'serving_fleet_p99_ms_r1',
-    'serving_fleet_p99_ms_r2',
-    'serving_fleet_p99_ms_r4',
-    'serving_fleet_scaling_monotonic',
-    'serving_fleet_request_time_compiles',
-    'serving_fleet_scaleup_compiles',
-    'fleet_scaleup_time_to_ready_s',
-    'serving_fleet_swap_failed',
-    'serving_fleet_swap_versions_served',
-)
 
 
 def router_host_meta(max_replicas: int) -> Dict[str, object]:
@@ -146,7 +126,7 @@ class ServingFleet:
       when the fleet runs without one); pass it to the PolicyServer so
       the replica reports into its own stream. The production factory
       deserializes the persisted serving artifact, so every replica
-      after the first costs zero XLA compiles (asserted in the bench).
+      after the first costs zero XLA compiles.
     config: :class:`ServingFleetConfig`.
     model_dir: the fleet-shaped serving dir (see module docstring);
       None = registry metrics only.
@@ -338,8 +318,8 @@ class ServingFleet:
     """Adds one replica; returns ``(replica_id, time_to_ready_s)``.
 
     Time-to-ready covers the factory (artifact deserialize + server
-    start) through rotation entry — the ``fleet_scaleup_time_to_ready_s``
-    bench quantity. Raises when the fleet is at ``max_replicas``.
+    start) through rotation entry. Raises when the fleet is at
+    ``max_replicas``.
     """
     if len(self.router.replica_ids()) >= self.config.max_replicas:
       raise RuntimeError('fleet already at max_replicas={}'.format(

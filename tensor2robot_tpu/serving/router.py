@@ -789,7 +789,7 @@ class FleetRouter:
             'latency': summary}
 
   def stats(self) -> Dict[str, Any]:
-    """Cumulative router stats (frontend /healthz + bench)."""
+    """Cumulative router stats (frontend /healthz)."""
     with self._lock:
       replica_count = len(self._handles)
       healthy = len(set(self._handles) - self._ejected)

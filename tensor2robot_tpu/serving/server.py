@@ -12,7 +12,7 @@ Design invariants:
     given — normally a :mod:`serving.artifact` AOT executable built at
     startup from the tuning-cache winner. Every batch has the same
     padded shape, so there is nothing left for XLA to specialize at
-    request time (the bench asserts this via ``jax/compiles``).
+    request time.
   * **Versioned params, drain-free hot swap.** ``swap_params`` replaces
     one immutable ``(version, variables)`` snapshot reference; a batch
     reads the snapshot ONCE before executing, so in-flight batches
@@ -514,7 +514,7 @@ class PolicyServer:
   # -- introspection ---------------------------------------------------------
 
   def stats(self) -> Dict[str, object]:
-    """Cumulative serving stats (frontend /healthz + bench)."""
+    """Cumulative serving stats (frontend /healthz)."""
     return {
         'requests_total': self._requests_counter.value,
         'batches_total': self._batches_counter.value,

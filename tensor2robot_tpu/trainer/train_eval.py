@@ -519,9 +519,9 @@ class Trainer:
 
   def _step_cost(self) -> Optional[Dict[str, object]]:
     """Per-device train-step FLOPs/bytes through THE shared cost model
-    (parallel/hlo_analysis.program_cost) — the same helper bench.py's
-    flops_per_step resolves through, so the live ``perf/mfu`` gauge and
-    the bench headline agree by construction. Resolution order mirrors
+    (parallel/hlo_analysis.program_cost), which the ``perf/mfu`` and
+    ``perf/hbm_bw_util`` gauges and the forensics roofline record read
+    too, so they agree by construction. Resolution order mirrors
     ``_train_step_hlo``: persisted artifact HLO, then the live tuned
     executable, then a one-off relower from the recorded abstract args.
     Resolved once and cached (False = resolved to nothing)."""
@@ -774,7 +774,7 @@ class Trainer:
     with and without a cache entry. ``from_cache`` distinguishes a
     cache-resolved winner from a directly-passed config: a direct
     config's ``model_overrides`` were applied by the caller at model
-    construction (bench.py does), a cache-resolved one's were NOT.
+    construction, a cache-resolved one's were NOT.
     """
     from tensor2robot_tpu import tuning
 
@@ -825,7 +825,7 @@ class Trainer:
       config, from_cache = self._resolve_tuned_config(args)
       if config is not None and config.model_overrides and not from_cache:
         # Direct-form config: the caller applied the layout overrides at
-        # model construction (bench.py does); only the flags compile here.
+        # model construction; only the flags compile here.
         _log('Tuned config %s carries model_overrides %s — applied at '
              'model construction, not here.', config.config_id,
              sorted(config.model_overrides))

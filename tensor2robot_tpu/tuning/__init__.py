@@ -1,10 +1,8 @@
 """Offline compile-config autotuning for jitted train steps.
 
-The round-5 VERDICT named the one headline lever never pulled: a
-systematic sweep of ``xla_tpu_*`` scheduler/vmem/fusion flags and conv
-``dimension_numbers``/layout variants on the batch-512 step — the
-compiler-level tuning pjit-era TPU stacks report as decisive
-(arxiv 2204.06514). This package is that sweep, made a reusable tool:
+A systematic sweep of ``xla_tpu_*`` scheduler/vmem/fusion flags and conv
+``dimension_numbers``/layout variants on a train step, as a reusable
+tool:
 
   * ``search_space``      — curated, bounded candidate sets per backend
                             (compiler options + model layout overrides);
@@ -18,17 +16,10 @@ compiler-level tuning pjit-era TPU stacks report as decisive
                             device_kind, jax version) so production runs
                             pay for the sweep once.
 
-``trainer/train_eval.py`` (the ``tuned_config`` arg) and ``bench.py``
-load cache entries at startup and apply them to the train-step compile;
-forensics reports carry the active config id so a regression is
-attributable to the config that produced it.
-
-``kernelbench`` (ISSUE 19) turns the same chained timing harness on
-individual kernels: registered candidates (``layers/pallas_wgrad`` is
-the first) vs their fused-XLA baselines, publishing schema-locked
-``KERNEL_BENCH_KEYS`` rows appended to ``kernelbench.json`` next to the
-tuning cache (``bin/t2r_kernelbench``) — the rig ROADMAP item 1's
-kernel work lands numbers against.
+``trainer/train_eval.py`` (the ``tuned_config`` arg) loads cache entries
+at startup and applies them to the train-step compile; forensics reports
+carry the active config id so a regression is attributable to the config
+that produced it.
 """
 
 from tensor2robot_tpu.tuning.autotuner import (
@@ -37,14 +28,6 @@ from tensor2robot_tpu.tuning.autotuner import (
     measure_chained,
     sweep,
 )
-from tensor2robot_tpu.tuning.kernelbench import (
-    KERNEL_BENCH_KEYS,
-    KERNEL_BENCH_SCHEMA,
-    default_results_path,
-    read_results,
-    register,
-)
-from tensor2robot_tpu.tuning.kernelbench import run as run_kernelbench
 from tensor2robot_tpu.tuning.cache import (
     ConfigCache,
     abstract_signature,
@@ -60,17 +43,11 @@ __all__ = [
     'CandidateResult',
     'CompileConfig',
     'ConfigCache',
-    'KERNEL_BENCH_KEYS',
-    'KERNEL_BENCH_SCHEMA',
     'SweepResult',
     'abstract_signature',
     'cache_key',
     'candidate_configs',
     'default_cache_path',
-    'default_results_path',
     'measure_chained',
-    'read_results',
-    'register',
-    'run_kernelbench',
     'sweep',
 ]

@@ -9,7 +9,7 @@ charge the per-dispatch round trip (most of the step for ms-scale
 programs) to every candidate equally — hiding exactly the
 scheduler-flag effects the sweep exists to find. The spread statistic is
 max-min over the best ``reps - 1`` repetitions (one stalled repetition
-cannot set it; same statistic as bench.py).
+cannot set it).
 
 Candidates that fail to COMPILE (e.g. a curated flag the local jaxlib
 does not know) are recorded with their error and excluded from winner
@@ -105,12 +105,10 @@ class SweepResult:
 def robust_median_spread(times: Sequence[float]) -> Tuple[float, float]:
   """(median, max-min over the best ``len-1``) of raw repetition times.
 
-  THE dispersion statistic for every published timing — bench.py's
-  ``*_spread`` fields and the sweep's ``spread_s`` both call this, so
-  they cannot drift apart. Dropping the single worst repetition before
-  taking the range keeps one stalled repetition from setting the field,
-  while a genuinely unstable measurement (2+ slow reps) still reports a
-  large spread.
+  The dispersion statistic behind the sweep's ``spread_s``. Dropping the
+  single worst repetition before taking the range keeps one stalled
+  repetition from setting the field, while a genuinely unstable
+  measurement (2+ slow reps) still reports a large spread.
   """
   times = sorted(times)
   median = times[len(times) // 2]

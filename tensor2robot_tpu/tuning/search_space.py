@@ -33,14 +33,14 @@ class CompileConfig:
 
   Attributes:
     config_id: short stable identifier ('vmem-96m', 'latency-sched', ...).
-      Forensics reports and bench records carry it verbatim.
+      Forensics reports carry it verbatim.
     compiler_options: per-compile XLA options. Values keep their native
       python types (bool/int/str) — the PJRT layer rejects stringified
       bools ("'true' is not a valid bool value").
     model_overrides: model-constructor kwargs for layout variants (e.g.
       {'conv_variant': 'nchw'} or {'space_to_depth': True} for
       Grasping44's network_kwargs). Applied by harnesses that rebuild
-      the model per candidate (bench.py); the trainer hook applies
+      the model per candidate; the trainer hook applies
       compiler_options only — a layout override changes the program, so
       it must come in through the model, not the compile.
     donate: whether the candidate step donates its state argument.
@@ -67,8 +67,8 @@ def _tpu_candidates(include_layouts: bool) -> List[CompileConfig]:
 
   Sources: the pjit-era tuning literature (arxiv 2204.06514 §4: compiler
   scheduling + fusion flags moved their MFU), public XLA:TPU flag surveys
-  (t5x/maxtext launch configs), and this repo's own per-op ceiling case
-  (docs/performance.md): the headline is conv-emitter-bound, so the
+  (t5x/maxtext launch configs), and this repo's own breakdown
+  (PERF.md section 5): the conv fusions gate the step, so the
   plausible levers are vmem budget (deeper conv pipelining), the
   latency-hiding scheduler (dispatch/overlap), and fusion aggressiveness
   around the convs.

@@ -1,10 +1,10 @@
 """Dependency-free xplane.pb reader: per-op device-time attribution.
 
 ``jax.profiler.trace`` writes TensorBoard xplane protos, but this image
-(and many serving hosts) carries no profiler proto bindings — so the
-round-5 headline-tail attribution (docs/performance.md) walks the wire
-format directly, on the SAME protobuf-free primitives the framework's
-tf.Example codec uses (`data/wire.py` `_iter_fields`, which raises on
+(and many serving hosts) carries no profiler proto bindings — so
+per-op attribution walks the wire format directly, on the SAME
+protobuf-free primitives the framework's tf.Example codec uses
+(`data/wire.py` `_iter_fields`, which raises on
 malformed varints and unsupported wire types, so truncated or corrupt
 captures fail loudly instead of desynchronizing into garbage totals).
 

@@ -1,8 +1,8 @@
 """Pallas flash attention numerics vs the XLA oracle.
 
 Runs through the Pallas interpreter on the CPU test mesh; the compiled
-TPU path shares the same kernel (bench: docs/performance.md — 1.4x at
-L=8192, and it runs L>=16384 where XLA's materialized scores OOM).
+TPU path shares the same kernel (what it measures in a cell: PERF.md
+section 5).
 """
 
 import numpy as np

@@ -391,26 +391,3 @@ class TestFastMaxPool:
     got = pooling.max_pool(x, (2, 2, 2), strides=(2, 2, 2),
                            padding='VALID')
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))
-
-
-class TestPallasWgrad:
-  """Interpret-mode parity for the Pallas 5x5 wgrad record kernel
-  (layers/pallas_wgrad.py — the measured evidence that XLA's conv
-  emitter wins on v5e; see its module docstring)."""
-
-  def test_matches_xla_wgrad(self):
-    from tensor2robot_tpu.layers.pallas_wgrad import conv5x5_wgrad
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(4, 19, 23, 64), jnp.bfloat16)
-    dy = jnp.asarray(rng.randn(4, 19, 23, 64), jnp.bfloat16)
-    got = np.asarray(conv5x5_wgrad(x, dy, interpret=True), np.float32)
-
-    def conv(w):
-      return jax.lax.conv_general_dilated(
-          x, w, (1, 1), 'SAME',
-          dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
-    _, vjp = jax.vjp(conv, jnp.zeros((5, 5, 64, 64), jnp.bfloat16))
-    want = np.asarray(vjp(dy)[0], np.float32)
-    err = np.abs(got - want) / (np.abs(want) + 1.0)
-    assert got.shape == (5, 5, 64, 64)
-    assert err.max() < 0.05

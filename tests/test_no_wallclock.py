@@ -44,8 +44,8 @@ import os
 # timings and the coldstart time-to-first-step measurement are
 # durations a wall-clock jump must not corrupt — a fabricated
 # negative compile_ms would poison the cold-start trajectory table.
-# ISSUE 14's fleet modules (serving/router.py, serving/fleet.py,
-# serving/fleet_bench.py) ride the existing 'serving' entry: replica
+# ISSUE 14's fleet modules (serving/router.py, serving/fleet.py) ride
+# the existing 'serving' entry: replica
 # heartbeat ages, ejection staleness, scale-up time-to-ready and the
 # fleet report windows are ALL durations (monotonic by construction —
 # an NTP step must not eject a healthy replica or fake a scale-up

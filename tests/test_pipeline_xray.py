@@ -461,8 +461,8 @@ class TestXrayLoop:
     latest = pipelines[-1]
     assert latest['schema'] == 't2r.pipeline.v1'
     assert latest['bottleneck'] in xray_lib.STAGES
-    # The record's own stage capacities re-attribute to the same gate —
-    # the rule bench.py shares (observability/pipeline_xray.py).
+    # The record's own stage capacities re-attribute to the same gate
+    # (observability/pipeline_xray.py::attribute_stages).
     rates = {stage: info.get('examples_per_sec_capacity')
              for stage, info in latest['stages'].items()}
     assert xray_lib.attribute_stages(rates)['bottleneck'] == \

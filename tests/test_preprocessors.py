@@ -562,9 +562,9 @@ class TestDeviceDecodePreprocessor:
 class TestFusedCropConvert:
   """preprocessors/pallas_crop.py vs the XLA dynamic-slice path.
 
-  Runs the kernel in interpret mode on CPU; the on-chip parity record is
-  docs/performance.md (1-ulp vs the XLA path — the in-kernel divide
-  compiles to a reciprocal multiply).
+  Runs the kernel in interpret mode on CPU (on the chip it is 1 ulp from
+  the XLA path — the in-kernel divide compiles to a reciprocal
+  multiply).
   """
 
   def _ref(self, imgs, offs, target):

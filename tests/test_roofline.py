@@ -4,12 +4,11 @@ The accounting first: the HLO-parse cost model must match hand-computed
 FLOPs/bytes EXACTLY on a synthetic module, and match the backend's own
 ``cost_analysis()`` exactly on a toy jitted program (matmul + tanh +
 elementwise) — then within 5% on the real Grasping44 critic step, the
-parity that lets bench.py, the trainer's live gauges, and the forensics
-roofline record share ONE cost helper. Then the plumbing: build_record's
+parity that lets the trainer's live gauges and the forensics roofline
+record share ONE cost helper. Then the plumbing: build_record's
 sum-reconciliation invariant, the watchdog's ``mfu_regression``
 detection (and its silence on CPU where the MFU gauge never publishes),
-the capture -> ``t2r.roofline.v1`` loop under an injected slow step, the
-kernelbench rig publishing every ``KERNEL_BENCH_KEYS`` field on CPU, and
+the capture -> ``t2r.roofline.v1`` loop under an injected slow step, and
 the ``bin/check_roofline_doctor`` fixtures replayed through doctor.
 """
 
@@ -31,7 +30,6 @@ from tensor2robot_tpu.observability import watchdog as watchdog_lib
 from tensor2robot_tpu.parallel import hlo_analysis
 from tensor2robot_tpu.reliability import fault_injection
 from tensor2robot_tpu.trainer import Trainer
-from tensor2robot_tpu.tuning import kernelbench
 from tensor2robot_tpu.utils.mocks import MockInputGenerator, MockT2RModel
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -385,57 +383,6 @@ class TestCaptureRoofline:
         for r in records if r['kind'] == 'anomaly')
     scalars = fresh_registry.scalars()
     assert scalars.get('watchdog/anomalies/mfu_regression', 0.0) == 0.0
-
-
-# -- kernelbench rig ---------------------------------------------------------
-
-
-class TestKernelbench:
-
-  def test_cpu_run_publishes_every_key_with_measured_speedup(
-      self, tmp_path):
-    out_path = str(tmp_path / 'kernelbench.json')
-    record = kernelbench.run(kernels=['pallas_wgrad'], n_steps=2,
-                             reps=2, out_path=out_path)
-    assert record['schema'] == kernelbench.KERNEL_BENCH_SCHEMA
-    (row,) = record['results']
-    assert 'error' not in row, row
-    assert 'schema_missing' not in row
-    for key in kernelbench.KERNEL_BENCH_KEYS:
-      assert key in row
-    assert row['ms'] > 0 and row['xla_ms'] > 0
-    assert row['speedup_vs_xla'] == pytest.approx(
-        row['xla_ms'] / row['ms'], rel=1e-3)
-    # CPU has no peaks entry: % peak honestly sentinels at -1.0.
-    assert row['pct_peak'] == -1.0
-    assert row['gflop_per_s'] > 0
-    # Persisted next to the tuning cache, bounded, re-readable.
-    runs = kernelbench.read_results(out_path)
-    assert len(runs) == 1
-    assert runs[0]['results'][0]['kernel'] == 'pallas_wgrad'
-
-  def test_broken_kernel_is_a_row_not_a_crash(self, tmp_path):
-    @kernelbench.register('broken_test_kernel')
-    def _broken(shape=None, dtype=None):
-      raise RuntimeError('intentionally broken')
-
-    try:
-      record = kernelbench.run(kernels=['broken_test_kernel'],
-                               persist=False)
-    finally:
-      kernelbench.REGISTRY.pop('broken_test_kernel', None)
-    (row,) = record['results']
-    assert 'intentionally broken' in row['error']
-    assert row['ms'] == -1.0
-    for key in kernelbench.KERNEL_BENCH_KEYS:
-      assert key in row
-
-  def test_default_results_path_sits_next_to_tuning_cache(
-      self, monkeypatch, tmp_path):
-    monkeypatch.setenv('T2R_TUNING_CACHE',
-                       str(tmp_path / 'cache' / 'tuning_cache.json'))
-    assert kernelbench.default_results_path() == \
-        str(tmp_path / 'cache' / 'kernelbench.json')
 
 
 # -- doctor + CI gate --------------------------------------------------------

@@ -37,7 +37,6 @@ from tensor2robot_tpu.serving import (
     ReplicaHandle,
     RequestRejected,
     RouterConfig,
-    SERVING_FLEET_BENCH_KEYS,
     ServingConfig,
     ServingFleet,
     ServingFleetConfig,
@@ -1027,52 +1026,3 @@ class TestFleetDoctor:
             and (f.get('detail') or {}).get('kind')
             == 'fleet_replica_over_slo']
     assert warn and warn[0]['detail']['replica'] == '1'
-
-
-class TestFleetBenchSchema:
-
-  def test_bench_keys_are_locked(self):
-    assert SERVING_FLEET_BENCH_KEYS == (
-        'serving_fleet_actions_per_sec_r1',
-        'serving_fleet_actions_per_sec_r2',
-        'serving_fleet_actions_per_sec_r4',
-        'serving_fleet_p99_ms_r1',
-        'serving_fleet_p99_ms_r2',
-        'serving_fleet_p99_ms_r4',
-        'serving_fleet_scaling_monotonic',
-        'serving_fleet_request_time_compiles',
-        'serving_fleet_scaleup_compiles',
-        'fleet_scaleup_time_to_ready_s',
-        'serving_fleet_swap_failed',
-        'serving_fleet_swap_versions_served',
-    )
-
-  @pytest.mark.slow
-  def test_fleet_bench_runnable_emits_the_schema(self):
-    """The bench subprocess end to end (2 replicas, short windows):
-    every locked key present, zero compiles at request time and across
-    the artifact-warm scale-out."""
-    env = dict(os.environ)
-    env['JAX_PLATFORMS'] = 'cpu'
-    env['XLA_FLAGS'] = (env.get('XLA_FLAGS', '') +
-                        ' --xla_cpu_multi_thread_eigen=false').strip()
-    result = subprocess.run(
-        [sys.executable, '-m', 'tensor2robot_tpu.serving.fleet_bench',
-         '--duration', '1.5', '--replica_counts', '1,2'],
-        capture_output=True, text=True, timeout=600, env=env,
-        cwd=REPO_ROOT)
-    assert result.returncode == 0, result.stdout + result.stderr
-    out = json.loads(result.stdout.strip().splitlines()[-1])
-    for key in ('serving_fleet_actions_per_sec_r1',
-                'serving_fleet_actions_per_sec_r2',
-                'serving_fleet_scaling_monotonic',
-                'serving_fleet_request_time_compiles',
-                'serving_fleet_scaleup_compiles',
-                'fleet_scaleup_time_to_ready_s',
-                'serving_fleet_swap_failed',
-                'serving_fleet_swap_versions_served'):
-      assert key in out, key
-    assert out['serving_fleet_request_time_compiles'] == 0
-    assert out['serving_fleet_scaleup_compiles'] == 0
-    assert out['serving_fleet_swap_failed'] == 0
-    assert out['serving_fleet_swap_versions_served'] == [1, 2]

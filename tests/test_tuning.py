@@ -348,8 +348,8 @@ class TestTrainerHook:
 
   def test_model_overrides_only_config_sets_id_without_aot(self, tmp_path):
     # Layout overrides apply at model construction; the trainer hook
-    # records the id (attribution: the CALLER applied them, as bench.py
-    # does) but must not AOT-compile.
+    # records the id (attribution: the CALLER applied them) but must not
+    # AOT-compile.
     config = CompileConfig('layout-only',
                            model_overrides={'conv_variant': 'nchw'})
     trainer = self._train(tmp_path, config)
