@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
+from tensor2robot_tpu.parallel import grouped_matmul as gmm_lib
 from tensor2robot_tpu.parallel.sharding import constrain
 
 
@@ -252,6 +252,7 @@ def buffer_rows(tokens: int, top_k: int, held: int, block_rows: int) -> int:
   return -(-rows // block_rows) * block_rows
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def group_pairs(expert_index: jnp.ndarray, first: int, held: int,
                 block_rows: int):
   """Lays the pairs whose expert is in [first, first + held) out in rows.
@@ -265,6 +266,15 @@ def group_pairs(expert_index: jnp.ndarray, first: int, held: int,
     tile_group [M/block_rows] expert (0..held-1) of each tile
     num_tiles  [1]    tiles in use
     counts     [held] pairs of each held expert
+    tile_chunks, tile_num_chunks  for each tile of ``token_block`` tokens the
+                      ``chunk_rows``-row chunks of the buffer that hold its
+                      pairs' rows, expert after expert, and how many they are
+                      ([tiles x max_tile_chunks] flat and [tiles]; the sizes
+                      are parallel/grouped_matmul.py's): what the kernels that
+                      move rows walk
+    pad_chunks [held x block_rows/chunk_rows] the chunks that are padding
+                      alone, behind each expert's last row (-1: none):
+                      ``moe_take_rows`` writes zeros there
 
   No scatter: rows come from one sort of the pairs by expert, the pairs'
   rows from a running count.
@@ -281,8 +291,7 @@ def group_pairs(expert_index: jnp.ndarray, first: int, held: int,
   padded = -(-counts // block_rows) * block_rows
   ends = jnp.cumsum(padded)
   starts = ends - padded
-  starts_pad = jnp.concatenate([starts, jnp.full((1,), rows, jnp.int32)])
-  pair_row = jnp.where(is_held, starts_pad[local] + rank, rows)
+  pair_row = jnp.where(is_held, (onehot * starts).sum(1) + rank, rows)
 
   # Pairs in expert order (absent experts last), token order within.
   in_order = jnp.sort(local * pairs + jnp.arange(pairs, dtype=jnp.int32))
@@ -295,72 +304,104 @@ def group_pairs(expert_index: jnp.ndarray, first: int, held: int,
   row_pair = jnp.where(
       real, in_order[jnp.minimum(first_pair[group] + offset, pairs - 1)],
       pairs)
+
+  tile_group = jnp.minimum(
+      jnp.searchsorted(ends, jnp.arange(0, rows, block_rows, dtype=jnp.int32),
+                       side='right'), held - 1)
+
+  # An expert's rows are in token order, so the rows a tile of tokens owns in
+  # one expert are one run: it starts where the earlier tiles' pairs end.
+  token_block = gmm_lib.token_block(tokens)
+  chunk = gmm_lib.chunk_rows(block_rows)
+  max_chunks = gmm_lib.max_tile_chunks(tokens, k, held, block_rows)
+  run_rows = onehot.reshape(tokens // token_block, token_block * k,
+                            held).sum(1)                      # [tiles, held]
+  run_start = starts[None, :] + jnp.cumsum(run_rows, axis=0) - run_rows
+  first_chunk = run_start // chunk
+  run_chunks = jnp.where(
+      run_rows > 0, (run_start + run_rows - 1) // chunk - first_chunk + 1, 0)
+  chunk_ends = jnp.cumsum(run_chunks, axis=1)
+  slot = jnp.arange(max_chunks, dtype=jnp.int32)[None, :, None]
+  slot_expert = jnp.sum(chunk_ends[:, None, :] <= slot, axis=-1)
+  of_expert = slot_expert[..., None] == jnp.arange(held)      # one-hot, no gather
+  tile_chunks = jnp.sum(
+      of_expert * (first_chunk - chunk_ends + run_chunks)[:, None, :],
+      axis=-1) + slot[..., 0]
+  # Chunks of padding alone: behind an expert's last row, to its tiles' end.
+  pad_chunks = ((starts + counts + chunk - 1) // chunk)[:, None] + jnp.arange(
+      block_rows // chunk, dtype=jnp.int32)[None, :]
+  pad_chunks = jnp.where(pad_chunks < (ends // chunk)[:, None], pad_chunks, -1)
   return {
       'row_pair': row_pair.astype(jnp.int32),
       'pair_row': pair_row.reshape(tokens, k).astype(jnp.int32),
-      'tile_group': group[::block_rows].astype(jnp.int32),
+      'tile_group': tile_group.astype(jnp.int32),
       'num_tiles': (ends[-1:] // block_rows).astype(jnp.int32),
       'counts': counts,
+      'tile_chunks': tile_chunks.reshape(-1).astype(jnp.int32),
+      'tile_num_chunks': chunk_ends[:, -1].astype(jnp.int32),
+      'pad_chunks': pad_chunks.reshape(-1).astype(jnp.int32),
   }
 
 
-def _take_rows(table, index):
-  """table[index], zeros where index == len(table) (a padding row, or a
-  pair whose expert is absent)."""
-  return table.at[index].get(mode='fill', fill_value=0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def dispatch_rows(x, layout, block_rows):
+  """[M, d]: the row of each pair held here is its token's row of ``x``;
+  padding inside a tile in use is zeros; later tiles are not written.
+  ``layout`` is ``group_pairs``'s without its ``row_pair``.
+
+  The backward pass is a gather too: a token's gradient is the sum over
+  its pairs of their rows' gradients. Both ways only rows in use move
+  (parallel/grouped_matmul.py: ``moe_take_rows``, ``moe_sum_rows``)."""
+  return gmm_lib.moe_take_rows(x, None, layout, block_rows=block_rows)
 
 
-@jax.custom_vjp
-def dispatch_rows(x, row_token, pair_row):
-  """[M, d]: row m holds token ``row_token[m]`` (zeros where that is T).
-
-  The backward pass is written as gathers too: a token's gradient is the
-  sum over its pairs of their rows' gradients."""
-  del pair_row
-  return _take_rows(x, row_token)
+def _dispatch_fwd(x, layout, block_rows):
+  return dispatch_rows(x, layout, block_rows), layout
 
 
-def _dispatch_fwd(x, row_token, pair_row):
-  return _take_rows(x, row_token), pair_row
-
-
-def _dispatch_bwd(pair_row, d_rows):
-  dx = sum(_take_rows(d_rows, pair_row[:, j]).astype(jnp.float32)
-           for j in range(pair_row.shape[1]))
-  return dx.astype(d_rows.dtype), None, None
+def _dispatch_bwd(block_rows, layout, d_rows):
+  del block_rows
+  return gmm_lib.moe_sum_rows(d_rows, None, layout,
+                              out_dtype=d_rows.dtype), None
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def combine_rows(rows, weight, pair_row, row_token, row_weight_index):
+def combine_rows(rows, weight, layout):
   """[T, d] f32: token t's sum over its pairs of weight x the pair's row
-  (a pair whose row is M, its expert absent, adds nothing)."""
-  del row_token, row_weight_index
-  return sum(weight[:, j, None] *
-             _take_rows(rows, pair_row[:, j]).astype(jnp.float32)
-             for j in range(pair_row.shape[1]))
+  (a pair whose expert is absent has no row and adds nothing)."""
+  return gmm_lib.moe_sum_rows(rows, weight, layout)
 
 
-def _combine_fwd(rows, weight, pair_row, row_token, row_weight_index):
-  out = combine_rows(rows, weight, pair_row, row_token, row_weight_index)
-  return out, (rows, weight, pair_row, row_token, row_weight_index)
+def _combine_fwd(rows, weight, layout):
+  return combine_rows(rows, weight, layout), (rows, weight, layout)
 
 
 def _combine_bwd(residuals, d_out):
-  rows, weight, pair_row, row_token, row_weight_index = residuals
-  row_weight = _take_rows(weight.reshape(-1), row_weight_index)
-  # Gathered at the rows' own width: the buffer is several times the tokens.
-  d_rows = (row_weight[:, None] * _take_rows(d_out.astype(rows.dtype),
-                                             row_token)).astype(rows.dtype)
-  d_weight = jnp.stack(
-      [jnp.sum(d_out * _take_rows(rows, pair_row[:, j]).astype(jnp.float32),
-               axis=-1) for j in range(pair_row.shape[1])], axis=1)
-  return d_rows, d_weight.astype(weight.dtype), None, None, None
+  rows, weight, layout = residuals
+  # At the rows' own width: the buffer is several times the tokens.
+  d_rows = gmm_lib.moe_take_rows(
+      d_out.astype(rows.dtype), weight, layout,
+      block_rows=rows.shape[0] // layout['tile_group'].shape[0])
+  d_weight = gmm_lib.moe_sum_rows(rows, None, layout, d_out=d_out)
+  return d_rows, d_weight.astype(weight.dtype), None
 
 
 combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _pairs_moved(layout, block_rows: int):
+  """Pairs whose row lies in a chunk listed for their token's tile."""
+  pair_row = layout['pair_row']
+  tiles = layout['tile_num_chunks'].shape[0]
+  chunk = gmm_lib.chunk_rows(block_rows)
+  listed = layout['tile_chunks'].reshape(tiles, 1, -1)
+  listed = jnp.where(jnp.arange(listed.shape[-1]) <
+                     layout['tile_num_chunks'][:, None, None], listed, -1)
+  moved = (pair_row // chunk).reshape(tiles, -1, 1) == listed
+  return jnp.sum(jnp.any(moved, axis=-1))
 
 
 class DroplessMoE(nn.Module):
@@ -375,10 +416,12 @@ class DroplessMoE(nn.Module):
 
   Returns ``(y, stats)``; ``stats`` are scalars of this call:
   ``pairs_held`` (pairs computed here), ``load_max_over_mean`` (largest
-  expert's pairs over the mean, over the experts held) and
-  ``dropped_pairs`` (pairs of a held expert that were not computed: 0 by
-  construction, the buffer holds any routing; counted from the layout so
-  that a fault in it would show).
+  expert's pairs over the mean, over the experts held), ``dropped_pairs``
+  (pairs of a held expert that were not computed: 0 by construction, the
+  buffer holds any routing; counted from the layout, as the pairs whose row
+  lies in no chunk the kernels move for their token's tile, so that a fault
+  in it would show) and ``rows_in_use`` (tiles in use x ``block_rows``: over
+  ``buffer_rows`` it is the share of the buffer that is walked).
   """
 
   num_experts: int
@@ -391,9 +434,7 @@ class DroplessMoE(nn.Module):
 
   @nn.compact
   def __call__(self, u: jnp.ndarray, router_logits: jnp.ndarray):
-    from tensor2robot_tpu.parallel import grouped_matmul as gmm_lib
-
-    tokens, d = u.shape
+    d = u.shape[1]
     first, held = self.experts_held
     if not 0 <= first <= first + held <= self.num_experts or held < 1:
       raise ValueError('experts_held {} is no range of the {} experts.'
@@ -410,9 +451,8 @@ class DroplessMoE(nn.Module):
       expert_index, weight = route_top_k(router_logits, k)
     with jax.named_scope('moe_group'):
       layout = group_pairs(expert_index, first, held, self.block_rows)
-      row_pair, pair_row = layout['row_pair'], layout['pair_row']
-      row_token = jnp.where(row_pair < tokens * k, row_pair // k, tokens)
-      rows = dispatch_rows(u.astype(self.dtype), row_token, pair_row)
+      del layout['row_pair']   # the inverse map: no kernel reads it
+      rows = dispatch_rows(u.astype(self.dtype), layout, self.block_rows)
     with jax.named_scope('moe_experts'):
       product = functools.partial(
           gmm_lib.grouped_matmul, tile_group=layout['tile_group'],
@@ -423,14 +463,16 @@ class DroplessMoE(nn.Module):
                 gate_up[:, self.expert_dim:])
       out_rows = product(hidden, w_down.astype(self.dtype))
     with jax.named_scope('moe_combine'):
-      y = combine_rows(out_rows, weight, pair_row, row_token, row_pair)
+      y = combine_rows(out_rows, weight, layout)
 
     counts = layout['counts'].astype(jnp.float32)
     pairs_held = counts.sum()
     stats = {
         'pairs_held': pairs_held,
         'load_max_over_mean': counts.max() / jnp.maximum(counts.mean(), 1.0),
-        'dropped_pairs': pairs_held - jnp.sum(
-            row_pair < tokens * k).astype(jnp.float32),
+        'dropped_pairs': pairs_held - _pairs_moved(
+            layout, self.block_rows).astype(jnp.float32),
+        'rows_in_use': (layout['num_tiles'][0] *
+                        self.block_rows).astype(jnp.float32),
     }
     return y, stats

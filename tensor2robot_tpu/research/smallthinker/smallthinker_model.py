@@ -47,7 +47,7 @@ from tensor2robot_tpu.specs.struct import SpecStruct
 from tensor2robot_tpu.specs.tensor_spec import TensorSpec
 
 MOE_STATS = ('moe/pairs_held', 'moe/expert_load_max_over_mean',
-             'moe/dropped_pairs')
+             'moe/dropped_pairs', 'moe/rows_in_use')
 
 # A block under ``jax.checkpoint`` that keeps, for its backward pass, the
 # residuals the flash kernels' forward rule names (at 2 x 8192 tokens, 28 and
@@ -147,6 +147,7 @@ class SmallThinkerNet(nn.Module):
         'moe/expert_load_max_over_mean':
             sum(s['load_max_over_mean'] for s in stats) / len(stats),
         'moe/dropped_pairs': sum(s['dropped_pairs'] for s in stats),
+        'moe/rows_in_use': sum(s['rows_in_use'] for s in stats),
     }
     if mode == ModeKeys.PREDICT:
       outputs['last_logits'] = jnp.dot(

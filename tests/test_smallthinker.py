@@ -198,6 +198,55 @@ class TestTheBlockCheckpointKeepsWhatTheAttentionBackwardReads:
     assert set(tags) == set(transformer_lib.flash_lib.BACKWARD_READS)
 
 
+def _equations(jaxpr):
+  """Every equation of a jaxpr, nested ones included, kernels' bodies not."""
+  for eqn in jaxpr.eqns:
+    yield eqn
+    if eqn.primitive.name != 'pallas_call':
+      for inner in jax.core.jaxprs_in_params(eqn.params):
+        yield from _equations(inner)
+
+
+class TestTheExpertLayerMovesOnlyTheRowsInUse:
+  """The model's step at the tiny size, as a jaxpr: nothing runs."""
+
+  def test_no_gather_over_the_whole_buffer_and_the_kernels_counted(
+      self, small):
+    _, params, _, program = small
+    # 2 x 32 tokens, 3 choices, 4 of 8 experts held, tiles of 8 rows.
+    rows = moe_lib.buffer_rows(64, 3, 4, 8)
+    equations = list(
+        _equations(jax.make_jaxpr(jax.grad(program))(params).jaxpr))
+    gathers = [[v.aval.shape for v in (eqn.invars[0], eqn.outvars[0])]
+               for eqn in equations if eqn.primitive.name == 'gather']
+    assert gathers, 'the embedding is a gather'
+    for shapes in gathers:
+      assert not any(shape and shape[0] == rows for shape in shapes), shapes
+    kernels = [eqn.params['name'] for eqn in equations
+               if eqn.primitive.name == 'pallas_call']
+    # A block: rows are taken for the forward pass, again when the block is
+    # computed again for its backward pass, and for the combine's gradient;
+    # summed for the combine (its second forward is dead: the block's output
+    # is no residual), for the dispatch's gradient and, dotted with the
+    # output's gradient in place of the sum, for the weights' gradient.
+    assert kernels.count('moe_take_rows') == 4 * 3
+    assert kernels.count('moe_sum_rows') == 4 * 3
+
+  def test_the_step_reports_the_rows_in_use(self, small):
+    model, _, tokens, _ = small
+    state = model.create_train_state(jax.random.PRNGKey(1),
+                                     {'tokens': tokens}, None)
+    _, metrics = jax.jit(model.train_step)(state, {'tokens': tokens}, None,
+                                           jax.random.PRNGKey(2))
+    in_use = float(metrics['moe/rows_in_use'])
+    # Whole tiles of 8 rows that hold the pairs, four layers: at least the
+    # pairs, at most 7 rows of padding more for each of 4 experts a layer.
+    assert in_use % 8 == 0
+    pairs = float(metrics['moe/pairs_held'])
+    assert pairs <= in_use <= pairs + 4 * 4 * 7
+    assert in_use < 4 * moe_lib.buffer_rows(64, 3, 4, 8)
+
+
 def _dense_attention(q, k, v, window):
   b, l, h, d = q.shape
   group = h // k.shape[2]
