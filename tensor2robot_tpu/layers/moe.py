@@ -404,6 +404,9 @@ def _pairs_moved(layout, block_rows: int):
   return jnp.sum(jnp.any(moved, axis=-1))
 
 
+GATE_ACTIVATIONS = {'relu': nn.relu, 'silu': nn.silu}
+
+
 class DroplessMoE(nn.Module):
   """Top-k routed gated experts without a capacity: [T, d] -> [T, d] f32.
 
@@ -411,8 +414,10 @@ class DroplessMoE(nn.Module):
   ``experts_held`` = (first index, count) says which of them live here.
   ``__call__(u, router_logits)`` takes the logits from the caller, because
   where the router reads is the block's business (before attention in the
-  model this was written for). Experts are gated: ``(relu(u Wg) * (u Wu))
-  Wd``, no bias. Weights are f32 parameters, products run in ``dtype``.
+  model this was written for). Experts are gated: ``(act(u Wg) * (u Wu))
+  Wd``, no bias, ``act`` the ``gate_activation``: ``'relu'`` (ReGLU) or
+  ``'silu'`` (SwiGLU). Weights are f32 parameters, products run in
+  ``dtype``.
 
   Returns ``(y, stats)``; ``stats`` are scalars of this call:
   ``pairs_held`` (pairs computed here), ``load_max_over_mean`` (largest
@@ -428,6 +433,7 @@ class DroplessMoE(nn.Module):
   experts_held: Tuple[int, int]
   expert_dim: int
   top_k: int
+  gate_activation: str = 'relu'
   block_rows: int = 256
   down_init_std: float = 0.02   # of w_down, which writes into the residual
   dtype: jnp.dtype = jnp.float32
@@ -439,6 +445,10 @@ class DroplessMoE(nn.Module):
     if not 0 <= first <= first + held <= self.num_experts or held < 1:
       raise ValueError('experts_held {} is no range of the {} experts.'
                        .format(self.experts_held, self.num_experts))
+    if self.gate_activation not in GATE_ACTIVATIONS:
+      raise ValueError('gate_activation {!r} is none of {}.'.format(
+          self.gate_activation, sorted(GATE_ACTIVATIONS)))
+    activation = GATE_ACTIVATIONS[self.gate_activation]
     k = min(self.top_k, self.num_experts)
     init = nn.initializers.normal(0.02)
     w_gate = self.param('w_gate', init, (held, d, self.expert_dim),
@@ -459,7 +469,7 @@ class DroplessMoE(nn.Module):
           num_tiles=layout['num_tiles'], block_m=self.block_rows)
       gate_up = product(rows, jnp.concatenate(
           [w_gate.astype(self.dtype), w_up.astype(self.dtype)], axis=-1))
-      hidden = (nn.relu(gate_up[:, :self.expert_dim]) *
+      hidden = (activation(gate_up[:, :self.expert_dim]) *
                 gate_up[:, self.expert_dim:])
       out_rows = product(hidden, w_down.astype(self.dtype))
     with jax.named_scope('moe_combine'):

@@ -29,7 +29,7 @@ causal mask the kernels implement.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -52,11 +52,15 @@ _FLASH_MIN_LENGTH = 2048
 
 
 def scaled_dot_attention(q, k, v, causal: bool,
-                         window: Optional[int] = None) -> jnp.ndarray:
+                         window: Optional[int] = None,
+                         block_diffusion: Optional[Tuple[int, int]] = None
+                         ) -> jnp.ndarray:
   """Dense [B, L, H, D] attention in f32 accumulation (the oracle path).
 
   k/v with fewer heads than q are grouped-query heads (query head n reads
-  head n // group); ``window`` keeps columns j with 0 <= i - j < window."""
+  head n // group); ``window`` keeps columns j with 0 <= i - j < window;
+  ``block_diffusion`` = (length, block), not causal, is the mask of
+  ``flash_attention``'s argument of that name over 2 x length positions."""
   scale = 1.0 / np.sqrt(q.shape[-1])
   group = q.shape[2] // k.shape[2]
   if group > 1:
@@ -70,6 +74,9 @@ def scaled_dot_attention(q, k, v, causal: bool,
       mask = jnp.logical_and(
           mask, jnp.triu(jnp.ones((l_q, l_k), bool), k=l_k - l_q - window + 1))
     scores = jnp.where(mask, scores, -jnp.inf)
+  if block_diffusion is not None:
+    scores = jnp.where(flash_lib.block_diffusion_mask(*block_diffusion),
+                       scores, -jnp.inf)
   probs = jax.nn.softmax(scores, axis=-1)
   return jnp.einsum('bhqk,bkhd->bqhd', probs, v.astype(jnp.float32)
                     ).astype(q.dtype)
@@ -91,22 +98,27 @@ def resolve_attention_mode(mode: str, seq_length: int) -> str:
 
 def run_attention(q, k, v, *, mode: str, causal: bool,
                   mesh=None, seq_axis: str = 'data',
-                  window: Optional[int] = None) -> jnp.ndarray:
+                  window: Optional[int] = None,
+                  block_diffusion: Optional[Tuple[int, int]] = None
+                  ) -> jnp.ndarray:
   """Dispatches [B, L, H, D] self-attention to the selected backend.
 
-  Grouped-query heads (k/v with fewer heads) and ``window`` are the dense
-  and flash backends'; the ring backend has neither."""
+  Grouped-query heads (k/v with fewer heads), ``window`` and the
+  ``block_diffusion`` mask are the dense and flash backends'; the ring
+  backend has none of them."""
   mode = resolve_attention_mode(mode, q.shape[1])
   if mode == 'xla':
-    return scaled_dot_attention(q, k, v, causal, window)
+    return scaled_dot_attention(q, k, v, causal, window, block_diffusion)
   if mode == 'flash':
-    return flash_lib.flash_attention(q, k, v, causal=causal, window=window)
+    return flash_lib.flash_attention(q, k, v, causal=causal, window=window,
+                                     block_diffusion=block_diffusion)
   if mode == 'ring':
     if mesh is None:
       raise ValueError("attention_mode='ring' requires a mesh.")
-    if window is not None or k.shape[2] != q.shape[2]:
-      raise ValueError("attention_mode='ring' has no window and no "
-                       'grouped-query heads.')
+    if window is not None or k.shape[2] != q.shape[2] or \
+        block_diffusion is not None:
+      raise ValueError("attention_mode='ring' has no window, no "
+                       'grouped-query heads and no block-diffusion mask.')
     return ring_lib.ring_self_attention(q, k, v, mesh, seq_axis=seq_axis,
                                         causal=causal)
   raise ValueError('Unknown attention mode: {!r}'.format(mode))
@@ -297,12 +309,16 @@ class RMSNorm(nn.Module):
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
 
 
-def rotary_positions(x: jnp.ndarray, theta: float) -> jnp.ndarray:
-  """Rotary position embedding of [B, L, H, D] by the position in the
-  sequence, rotate-half pairing (dimension i with i + D/2), in f32."""
+def rotary_positions(x: jnp.ndarray, theta: float,
+                     positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+  """Rotary position embedding of [B, L, H, D], rotate-half pairing
+  (dimension i with i + D/2), in f32. ``positions`` [L] are the position
+  ids of the rows (None: the index in the sequence)."""
   d = x.shape[-1]
   inverse = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-  angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inverse[None]
+  if positions is None:
+    positions = jnp.arange(x.shape[1])
+  angle = positions.astype(jnp.float32)[:, None] * inverse[None]
   cos, sin = (jnp.concatenate([f(angle), f(angle)], axis=-1)[None, :, None]
               for f in (jnp.cos, jnp.sin))
   x = x.astype(jnp.float32)
@@ -310,25 +326,65 @@ def rotary_positions(x: jnp.ndarray, theta: float) -> jnp.ndarray:
   return x * cos + jnp.concatenate([-second, first], axis=-1) * sin
 
 
+def blocked_cross_entropy(hidden, head, targets, weights, block_tokens: int,
+                          dtype) -> jnp.ndarray:
+  """Sum over all tokens of ``weights`` x the cross-entropy of the token's
+  logits (``hidden`` @ ``head``) against its target, in f32.
+
+  hidden [B, L, d], head [d, V], targets and weights [B, L]. The logits are
+  formed ``block_tokens`` tokens at a time under ``jax.checkpoint``: a
+  block's [block, V] f32 logits live only inside its own forward and
+  backward."""
+  b, l, d = hidden.shape
+  n = b * l
+  block = max(c for c in range(1, min(block_tokens, n) + 1) if n % c == 0)
+  # Cast once, outside the loop: the loop's backward pass then stacks the
+  # rows' gradients at this width, not in float32.
+  head, hidden = head.astype(dtype), hidden.astype(dtype)
+
+  @jax.checkpoint
+  def block_loss(args):
+    rows, target, weight = args
+    logits = jnp.dot(rows, head, preferred_element_type=jnp.float32)
+    picked = jnp.take_along_axis(logits, target[:, None], axis=-1)[:, 0]
+    return jnp.sum(weight * (jax.nn.logsumexp(logits, axis=-1) - picked))
+
+  with jax.named_scope('head_loss'):
+    sums = jax.lax.map(
+        block_loss, (hidden.reshape(n // block, block, d),
+                     targets.reshape(n // block, block),
+                     weights.astype(jnp.float32).reshape(n // block, block)))
+  return jnp.sum(sums)
+
+
 class GroupedQueryAttention(nn.Module):
-  """Causal self-attention with ``num_heads`` query heads over
-  ``num_kv_heads`` key/value heads (query head n reads k/v head
-  n // group), separate bias-free q/k/v/out projections, optionally a
-  sliding ``window`` and rotary positions (``rope_theta``; None: the layer
-  carries no positions at all). Backends as ``run_attention``: the Pallas
-  kernels index the shared k/v heads, nothing is repeated in HBM."""
+  """Self-attention with ``num_heads`` query heads over ``num_kv_heads``
+  key/value heads (query head n reads k/v head n // group), separate
+  bias-free q/k/v/out projections, optionally a sliding ``window`` and
+  rotary positions (``rope_theta``; None: the layer carries no positions
+  at all), at the ``positions`` [L] the caller gives (None: the index in
+  the sequence). ``qk_norm``: an RMS norm over the head dimension of q and
+  of k, one learned scale each, before the rotation. The mask is causal,
+  or with ``block_diffusion`` = (length, block) that of block-diffusion
+  training over [noised ; clean] (``flash_attention`` has the rules).
+  Backends as ``run_attention``: the Pallas kernels index the shared k/v
+  heads, nothing is repeated in HBM."""
 
   num_heads: int
   num_kv_heads: int
   head_dim: int
   window: Optional[int] = None
   rope_theta: Optional[float] = None
+  qk_norm: bool = False
+  eps: float = 1e-6             # of the q/k norms
+  block_diffusion: Optional[Tuple[int, int]] = None
   attention_mode: str = 'auto'
   out_init_std: float = 0.02    # of `out`, which writes into the residual
   dtype: jnp.dtype = jnp.float32
 
   @nn.compact
-  def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+  def __call__(self, x: jnp.ndarray,
+               positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     b, l, d = x.shape
     init = nn.initializers.normal(0.02)
 
@@ -340,25 +396,35 @@ class GroupedQueryAttention(nn.Module):
     q = project('q', self.num_heads)
     k = project('k', self.num_kv_heads)
     v = project('v', self.num_kv_heads)
+    if self.qk_norm:
+      q = RMSNorm(self.eps, name='q_norm')(q).astype(self.dtype)
+      k = RMSNorm(self.eps, name='k_norm')(k).astype(self.dtype)
     if self.rope_theta is not None:
-      q, k = (rotary_positions(t, self.rope_theta).astype(self.dtype)
-              for t in (q, k))
+      q, k = (rotary_positions(t, self.rope_theta, positions).astype(
+          self.dtype) for t in (q, k))
     with jax.named_scope('attention'):
-      out = run_attention(q, k, v, mode=self.attention_mode, causal=True,
-                          window=self.window)
+      out = run_attention(q, k, v, mode=self.attention_mode,
+                          causal=self.block_diffusion is None,
+                          window=self.window,
+                          block_diffusion=self.block_diffusion)
     return nn.Dense(d, use_bias=False, dtype=self.dtype,
                     kernel_init=nn.initializers.normal(self.out_init_std),
                     name='out')(out.reshape(b, l, -1))
 
 
-class RouterFirstMoEBlock(nn.Module):
-  """Pre-norm block whose router reads the block's INPUT, before attention:
+class MoEBlock(nn.Module):
+  """Pre-norm block of grouped-query attention and routed experts:
 
-    r = x W_r (f32);  x1 = x + attn(rmsnorm(x));  out = x1 + moe(rmsnorm(x1), r)
+    x1 = x + attn(rmsnorm(x));  u = rmsnorm(x1);  out = x1 + moe(u, r)
 
-  with grouped-query attention (window and rotary positions per layer kind)
-  and the dropless expert layer told which experts it holds
-  (layers/moe.py::DroplessMoE). Returns (out, the expert layer's stats)."""
+  whose router logits r (f32) read what ``router_reads`` says: ``'input'``,
+  the block's INPUT before attention, r = x W_r (the router-first block),
+  or ``'normed'``, the normed post-attention stream, r = u W_r (the usual
+  place). That is the one difference between the two; the other fields
+  are the attention's (window, rotary positions, q/k norm, the
+  block-diffusion mask) and the dropless expert layer's, which is told
+  which experts it holds (layers/moe.py::DroplessMoE) and how its gate is
+  activated. Returns (out, the expert layer's stats)."""
 
   num_heads: int
   num_kv_heads: int
@@ -370,35 +436,50 @@ class RouterFirstMoEBlock(nn.Module):
   window: Optional[int] = None
   rope_theta: Optional[float] = None
   eps: float = 1e-6
+  router_reads: str = 'input'
+  qk_norm: bool = False
+  block_diffusion: Optional[Tuple[int, int]] = None
+  gate_activation: str = 'relu'
   attention_mode: str = 'auto'
   moe_block_rows: int = 256
   residual_init_std: float = 0.02  # of the two matrices that write into x
   dtype: jnp.dtype = jnp.float32
 
   @nn.compact
-  def __call__(self, x: jnp.ndarray):
+  def __call__(self, x: jnp.ndarray,
+               positions: Optional[jnp.ndarray] = None):
     from tensor2robot_tpu.layers.moe import DroplessMoE
 
+    if self.router_reads not in ('input', 'normed'):
+      raise ValueError('router_reads {!r} is neither \'input\' nor '
+                       '\'normed\'.'.format(self.router_reads))
     b, l, d = x.shape
-    router_logits = nn.Dense(
+    router = nn.Dense(
         self.num_experts, use_bias=False, dtype=jnp.float32,
         precision=jax.lax.Precision.HIGHEST,
-        kernel_init=nn.initializers.normal(0.02), name='router')(
-            x.astype(jnp.float32))
+        kernel_init=nn.initializers.normal(0.02), name='router')
+    if self.router_reads == 'input':
+      router_logits = router(x.astype(jnp.float32))
     h = RMSNorm(self.eps, name='norm_attn')(x).astype(self.dtype)
     x = x + GroupedQueryAttention(
         num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
         head_dim=self.head_dim, window=self.window,
-        rope_theta=self.rope_theta, attention_mode=self.attention_mode,
+        rope_theta=self.rope_theta, qk_norm=self.qk_norm, eps=self.eps,
+        block_diffusion=self.block_diffusion,
+        attention_mode=self.attention_mode,
         out_init_std=self.residual_init_std, dtype=self.dtype,
-        name='attn')(h)
-    u = RMSNorm(self.eps, name='norm_moe')(x).astype(self.dtype)
+        name='attn')(h, positions)
+    u = RMSNorm(self.eps, name='norm_moe')(x)
+    if self.router_reads == 'normed':
+      router_logits = router(u)
     y, stats = DroplessMoE(
         num_experts=self.num_experts, experts_held=tuple(self.experts_held),
         expert_dim=self.expert_dim, top_k=self.top_k,
+        gate_activation=self.gate_activation,
         block_rows=self.moe_block_rows,
         down_init_std=self.residual_init_std, dtype=self.dtype, name='moe')(
-            u.reshape(b * l, d), router_logits.reshape(b * l, -1))
+            u.astype(self.dtype).reshape(b * l, d),
+            router_logits.reshape(b * l, -1))
     return x + y.reshape(b, l, d).astype(x.dtype), stats
 
 

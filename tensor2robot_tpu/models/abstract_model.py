@@ -85,6 +85,10 @@ class AbstractT2RModel(ModelInterface):
   # every top-level entry of the parameter tree, so that a check can tell
   # WHICH part's gradient is off; the others compute nothing.
   report_gradient_norm = False
+  # Names of scalar step metrics whose values the trainer's step watcher
+  # writes into each ``train.step_done`` event of the span ring (read on
+  # the watcher's thread once the step has finished on the device).
+  traced_step_metrics = ()
 
   def __init__(self,
                preprocessor_cls: Optional[Callable[..., AbstractPreprocessor]] = None,

@@ -52,7 +52,7 @@ function is never seen by a policy.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -113,15 +113,144 @@ def _block_in_band(i_q, i_k, block_q: int, block_k: int,
   band. Blocks outside are skipped on BOTH sides."""
   needed = i_q * block_q + block_q - 1 >= i_k * block_k
   if window is not None:
-    needed = jnp.logical_and(
-        needed, i_q * block_q - (i_k * block_k + block_k - 1) < window)
+    needed = needed & (
+        i_q * block_q - (i_k * block_k + block_k - 1) < window)
   return needed
+
+
+def _block_index(position, block: int):
+  """``position // block`` for non-negative positions (a shift where
+  ``block`` is a power of two: the kernels run it on every tile)."""
+  if block & (block - 1) == 0:
+    return position >> (block.bit_length() - 1)
+  return position // block
+
+
+def _in_block_diffusion(q_pos, k_pos, length: int, block: int):
+  """The block-diffusion mask over the 2 x ``length`` positions [noised ;
+  clean] (``flash_attention``'s docstring has the four rules), for int32
+  positions that broadcast against each other. Every key gets a code, its
+  block for a clean key and ``blocks`` + its block for a noised one; a
+  query then sees the clean codes below a threshold (its block, or its
+  block + 1 for a clean query) and one noised code (its own block's, none
+  for a clean query): two comparisons an element, on codes that cost a row
+  and a column of a tile."""
+  blocks = length // block
+  q_noised = q_pos < length
+  q_block = _block_index(jnp.where(q_noised, q_pos, q_pos - length), block)
+  below = jnp.where(q_noised, q_block, q_block + 1)
+  own = jnp.where(q_noised, q_block + blocks, -1)
+  k_noised = k_pos < length
+  k_code = _block_index(jnp.where(k_noised, k_pos, k_pos - length),
+                        block) + jnp.where(k_noised, blocks, 0)
+  return (k_code < below) | (k_code == own)
+
+
+def _block_in_block_diffusion(i_q, i_k, block_q: int, block_k: int,
+                              length: int, block: int, xp=jnp):
+  """Whether tile (i_q, i_k) holds any pair of the block-diffusion mask:
+  its noised rows against its noised columns (block ranges that meet), its
+  noised rows against its clean columns (a clean block strictly below the
+  last noised row's), its clean rows against its clean columns (a clean
+  block at or below the last clean row's). A tile may straddle the border
+  between the halves. ``xp`` is ``jnp`` inside a kernel (scalars of the
+  grid) and ``numpy`` on the host (the whole grid at once)."""
+  first_row, last_row = i_q * block_q, i_q * block_q + block_q - 1
+  first_col, last_col = i_k * block_k, i_k * block_k + block_k - 1
+  index = lambda position: _block_index(position, block)
+  noised_rows, clean_rows = first_row < length, last_row >= length
+  noised_cols, clean_cols = first_col < length, last_col >= length
+  last_noised_row = index(xp.minimum(last_row, length - 1))
+  first_clean_col = index(xp.maximum(first_col, length) - length)
+  own = (noised_rows & noised_cols &
+         (index(first_row) <= index(xp.minimum(last_col, length - 1))) &
+         (index(first_col) <= last_noised_row))
+  earlier = noised_rows & clean_cols & (first_clean_col < last_noised_row)
+  causal = clean_rows & clean_cols & (
+      first_clean_col <= index(xp.maximum(last_row, length) - length))
+  return own | earlier | causal
+
+
+def _tile_mask(q_base, k_base, block_q: int, block_k: int,
+               window: Optional[int], diffusion):
+  """[block_q, block_k] bool of one tile whose first row and column sit at
+  ``q_base`` and ``k_base``: the causal band, or with ``diffusion`` =
+  (length, block) the block-diffusion mask."""
+  if diffusion is not None:
+    q_pos = q_base + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    k_pos = k_base + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    return _in_block_diffusion(q_pos, k_pos, *diffusion)
+  q_pos = q_base + jax.lax.broadcasted_iota(
+      jnp.int32, (block_q, block_k), 0)
+  k_pos = k_base + jax.lax.broadcasted_iota(
+      jnp.int32, (block_q, block_k), 1)
+  return _in_band(q_pos, k_pos, window)
+
+
+def _tile_needed(i_q, i_k, block_q: int, block_k: int,
+                 window: Optional[int], diffusion, xp=jnp):
+  """The tile-level predicate of the mask ``_tile_mask`` applies."""
+  if diffusion is not None:
+    return _block_in_block_diffusion(i_q, i_k, block_q, block_k, *diffusion,
+                                     xp=xp)
+  return _block_in_band(i_q, i_k, block_q, block_k, window)
+
+
+def block_diffusion_mask(length: int, block: int):
+  """[2 length, 2 length] bool, row = query: the mask the kernels apply
+  with ``block_diffusion=(length, block)``, for the dense backend and the
+  tests."""
+  position = jnp.arange(2 * length, dtype=jnp.int32)
+  return _in_block_diffusion(position[:, None], position[None, :], length,
+                             block)
+
+
+def mask_pairs(l_q: int, l_k: int, causal: bool, window: Optional[int],
+               diffusion) -> int:
+  """Pairs (i, j) the mask keeps, by formula."""
+  if diffusion is not None:
+    length, block = diffusion
+    return length * length + length * block
+  if not causal:
+    return l_q * l_k
+  shift = l_k - l_q             # row i sees columns j <= i + shift
+  rows = np.arange(l_q, dtype=np.int64) + shift
+  first = np.zeros_like(rows) if window is None else np.maximum(
+      rows - window + 1, 0)
+  return int(np.sum(np.clip(np.minimum(rows, l_k - 1) - first + 1, 0, None)))
+
+
+def tiles_computed(n_q: int, n_k: int, block_q: int, block_k: int,
+                   causal: bool, window: Optional[int], diffusion) -> int:
+  """Tiles of the [n_q, n_k] grid that the kernels compute: the count of
+  the predicate they branch on, evaluated on the host."""
+  if not causal and diffusion is None:
+    return n_q * n_k
+  return int(np.sum(_tile_needed(
+      np.arange(n_q, dtype=np.int32)[:, None],
+      np.arange(n_k, dtype=np.int32)[None, :], block_q, block_k, window,
+      diffusion, xp=np)))
+
+
+def _set_pair_gauges(suffix: str, bh: int, l_q: int, l_k: int, block_q: int,
+                     block_k: int, causal: bool, window, diffusion):
+  """Host side, when a masked call is traced: the pairs the mask keeps and
+  the pairs of the tiles the kernels compute, a call (all heads)."""
+  from tensor2robot_tpu.observability import get_registry
+
+  registry = get_registry()
+  registry.gauge('attention/mask_pairs_needed').set(
+      float(bh * mask_pairs(l_q, l_k, causal, window, diffusion)))
+  registry.gauge('attention/mask_pairs_computed' + suffix).set(float(
+      bh * block_q * block_k * tiles_computed(
+          l_q // block_q, l_k // block_k, block_q, block_k, causal, window,
+          diffusion)))
 
 
 def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
                   scale: float, causal: bool, block_q: int, block_k: int,
                   q_offset, k_offset, i_q, i_k,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, diffusion=None):
   """The shared online-softmax block update both kernels run.
 
   Reads one q/k/v block from refs, scores it, and folds it into the
@@ -142,12 +271,10 @@ def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
   v = v_ref[0].astype(jnp.float32)
   s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                           preferred_element_type=jnp.float32) * scale
-  if causal:
-    q_pos = (q_offset + i_q * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0))
-    k_pos = (k_offset + i_k * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1))
-    s = jnp.where(_in_band(q_pos, k_pos, window), s, NEG_INF)
+  if causal or diffusion is not None:
+    s = jnp.where(_tile_mask(q_offset + i_q * block_q,
+                             k_offset + i_k * block_k, block_q, block_k,
+                             window, diffusion), s, NEG_INF)
 
   m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)   # [bq, 1]
   l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
@@ -167,7 +294,8 @@ def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                   l_ref, *, scale: float, causal: bool, block_q: int,
-                  block_k: int, window: Optional[int] = None):
+                  block_k: int, window: Optional[int] = None,
+                  diffusion=None):
   """One step of the k-outer / q-inner sweep within a q TILE.
 
   The grid is (bh, n_q_outer, n_k, n_q_inner): within one q tile
@@ -201,12 +329,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     _block_update(q_ref, k_ref, v_ref, acc_ref.at[rows, :],
                   m_ref.at[rows, :], l_ref.at[rows, :], scale=scale,
                   causal=causal, block_q=block_q, block_k=block_k,
-                  q_offset=0, k_offset=0, i_q=i_q, i_k=i_k, window=window)
+                  q_offset=0, k_offset=0, i_q=i_q, i_k=i_k, window=window,
+                  diffusion=diffusion)
 
-  if causal:
+  if causal or diffusion is not None:
     # Skip blocks entirely above the causal diagonal (all scores -inf)
-    # and, with a window, those entirely left of the band.
-    @pl.when(_block_in_band(i_q, i_k, block_q, block_k, window))
+    # and, with a window, those entirely left of the band; under the
+    # block-diffusion mask, the tiles that hold none of its pairs.
+    @pl.when(_tile_needed(i_q, i_k, block_q, block_k, window, diffusion))
     def _update():
       _do_update()
   else:
@@ -232,7 +362,8 @@ def _kv_head(group: int):
 
 
 def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
-                block_k: int, interpret: bool, window: Optional[int] = None):
+                block_k: int, interpret: bool, window: Optional[int] = None,
+                diffusion=None):
   """[BH, L, D] flash attention via pallas_call.
 
   k/v may hold fewer heads than q ([BH/group, L, D], grouped-query
@@ -260,7 +391,7 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
   tile_rows = n_qi * block_q
   kernel = functools.partial(
       _flash_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k, window=window)
+      block_k=block_k, window=window, diffusion=diffusion)
   # Grid: per q TILE, k OUTER / q INNER (see _flash_kernel) — each k/v
   # block is fetched once per k step per tile; the tile's accumulators
   # live in VMEM scratch.
@@ -435,19 +566,17 @@ def _bwd_default_blocks(l_q: int, l_k: int):
 
 
 def _bwd_p_ds(q, k, v, do, lse, delta, *, scale, causal, q_base, k_base,
-              block_q, block_k, window=None):
+              block_q, block_k, window=None, diffusion=None):
   """Shared recompute for both backward kernels: (p, ds) for one block
   pair, from the saved log-sum-exp. All operands f32 2D blocks."""
   s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                           preferred_element_type=jnp.float32) * scale
-  if causal:
-    q_pos = q_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    s = jnp.where(_in_band(q_pos, k_pos, window), s, NEG_INF)
+  masked = causal or diffusion is not None
+  if masked:
+    s = jnp.where(_tile_mask(q_base, k_base, block_q, block_k, window,
+                             diffusion), s, NEG_INF)
   p = jnp.exp(s - lse)
-  if causal:
+  if masked:
     p = jnp.where(s <= NEG_INF / 2, 0.0, p)
   dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                            preferred_element_type=jnp.float32)
@@ -459,7 +588,7 @@ def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                          causal: bool, block_q: int, block_k: int,
                          n_q: int, group: int = 1,
-                         window: Optional[int] = None):
+                         window: Optional[int] = None, diffusion=None):
   """dk/dv: grid (bh of k/v, n_k, group * n_q) — k/v block resident
   (accumulators in scratch), q/do/lse/delta stream through. With
   grouped-query heads the last axis runs over the ``group`` query heads
@@ -486,7 +615,8 @@ def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       v_ref[0].astype(jnp.float32), do, lse, delta,
                       scale=scale, causal=causal,
                       q_base=i_q * block_q, k_base=i_k * block_k,
-                      block_q=block_q, block_k=block_k, window=window)
+                      block_q=block_q, block_k=block_k, window=window,
+                      diffusion=diffusion)
     dv_acc[...] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -494,10 +624,11 @@ def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds, q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-  if causal:
+  if causal or diffusion is not None:
     # Blocks fully above the diagonal (or, with a window, fully left of
-    # the band) contribute nothing to dk/dv.
-    @pl.when(_block_in_band(i_q, i_k, block_q, block_k, window))
+    # the band; or outside the block-diffusion mask) contribute nothing
+    # to dk/dv.
+    @pl.when(_tile_needed(i_q, i_k, block_q, block_k, window, diffusion))
     def _():
       _update()
   else:
@@ -512,7 +643,7 @@ def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dq_ref, dq_acc, *, scale: float, causal: bool,
                         block_q: int, block_k: int,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None, diffusion=None):
   """dq: grid (bh, n_q, n_k) — q block resident, k/v stream through."""
   i_q = pl.program_id(1)
   i_k = pl.program_id(2)
@@ -531,13 +662,14 @@ def _flash_bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _, ds = _bwd_p_ds(q, k, v_ref[0].astype(jnp.float32), do, lse, delta,
                       scale=scale, causal=causal,
                       q_base=i_q * block_q, k_base=i_k * block_k,
-                      block_q=block_q, block_k=block_k, window=window)
+                      block_q=block_q, block_k=block_k, window=window,
+                      diffusion=diffusion)
     dq_acc[...] += jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-  if causal:
-    @pl.when(_block_in_band(i_q, i_k, block_q, block_k, window))
+  if causal or diffusion is not None:
+    @pl.when(_tile_needed(i_q, i_k, block_q, block_k, window, diffusion))
     def _():
       _update()
   else:
@@ -549,7 +681,8 @@ def _flash_bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
-                      block_q, block_k, interpret, window=None):
+                      block_q, block_k, interpret, window=None,
+                      diffusion=None):
   """Full Pallas backward: dq over [BH, L, D], dk, dv over k/v's
   [BH/group, L, D].
 
@@ -583,7 +716,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
 
   kv_kernel = functools.partial(
       _flash_bwd_kv_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k, n_q=n_q, group=group, window=window)
+      block_k=block_k, n_q=n_q, group=group, window=window,
+      diffusion=diffusion)
   dk, dv = pl.pallas_call(
       kv_kernel,
       grid=(bh // group, n_k, group * n_q),
@@ -615,7 +749,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
 
   q_kernel = functools.partial(
       _flash_bwd_q_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k, window=window)
+      block_k=block_k, window=window, diffusion=diffusion)
   dq = pl.pallas_call(
       q_kernel,
       grid=(bh, n_q, n_k),
@@ -639,25 +773,27 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_diff(q, k, v, causal, scale, block_q, block_k, interpret,
-                block_q_bwd, block_k_bwd, window):
+                block_q_bwd, block_k_bwd, window, diffusion):
   """custom_vjp core over [BH, L, D] operands."""
   del block_q_bwd, block_k_bwd  # backward-only
   out, _ = _flash_bhld(q, k, v, scale=scale, causal=causal,
                        block_q=block_q, block_k=block_k,
-                       interpret=interpret, window=window)
+                       interpret=interpret, window=window,
+                       diffusion=diffusion)
   return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               block_q_bwd, block_k_bwd, window):
+               block_q_bwd, block_k_bwd, window, diffusion):
   del block_q_bwd, block_k_bwd
   q, k, v = (checkpoint_name(x, name)
              for x, name in zip((q, k, v), (FLASH_Q, FLASH_K, FLASH_V)))
   out, lse = _flash_bhld(q, k, v, scale=scale, causal=causal,
                          block_q=block_q, block_k=block_k,
-                         interpret=interpret, window=window)
+                         interpret=interpret, window=window,
+                         diffusion=diffusion)
   # The named ``out`` is both the result and the residual: one saved array
   # serves the caller's next layer and the backward kernels.
   out = checkpoint_name(out, FLASH_OUT)
@@ -666,7 +802,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, block_q_bwd,
-               block_k_bwd, window, residuals, d_out):
+               block_k_bwd, window, diffusion, residuals, d_out):
   """Pallas FlashAttention-2 backward (see _flash_bwd_pallas).
 
   Until round 4 this was an XLA lax.scan recompute; it is now the same
@@ -679,9 +815,13 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, block_q_bwd,
   default_bq, default_bk = _bwd_default_blocks(l_q, l_k)
   bq = _dividing_block_or_raise(min(block_q_bwd or default_bq, l_q), l_q)
   bk = _dividing_block_or_raise(min(block_k_bwd or default_bk, l_k), l_k)
+  if causal or diffusion is not None:
+    _set_pair_gauges('_bwd', q.shape[0], l_q, l_k, bq, bk, causal, window,
+                     diffusion)
   dq, dk, dv = _flash_bwd_pallas(
       q, k, v, out, lse, d_out, scale=scale, causal=causal,
-      block_q=bq, block_k=bk, interpret=interpret, window=window)
+      block_q=bq, block_k=bk, interpret=interpret, window=window,
+      diffusion=diffusion)
   return dq, dk, dv
 
 
@@ -696,7 +836,8 @@ def flash_attention(q, k, v,
                     interpret: Optional[bool] = None,
                     block_q_bwd: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[Tuple[int, int]] = None):
   """Exact attention over [B, L, H, D] inputs, O(L) memory, differentiable.
 
   Forward runs the Pallas kernel (k-outer/q-inner tiled sweep, see
@@ -714,6 +855,24 @@ def flash_attention(q, k, v,
   that lie wholly outside that band are skipped in all three kernels.
   ``window=None`` with equal head counts is the plain causal (or full)
   attention the kernels always computed.
+
+  ``block_diffusion=(length, block)`` (not causal, no window) is the mask
+  of block-diffusion training over 2 x ``length`` positions, the noised
+  copy first and the clean copy behind it, ``block`` dividing ``length``
+  (i, j positions, blk the block of a position within its own half):
+
+    noised i, noised j:  blk(i) == blk(j)     its own block, both ways
+    noised i, clean  j:  blk(j) <  blk(i)     clean tokens of earlier blocks
+    clean  i, clean  j:  blk(j) <= blk(i)     block-causal
+    clean  i, noised j:  never
+
+  It keeps length^2 + length x block of the 4 length^2 pairs; all three
+  kernels skip the tiles that hold none (``_block_in_block_diffusion``).
+
+  A masked call sets, on the host while it is traced, the gauges
+  ``attention/mask_pairs_needed`` and ``attention/mask_pairs_computed``
+  (the forward kernel's tiles; ``..._computed_bwd`` each backward
+  kernel's), all heads of the call.
 
   Default block sizes come from v5e sweeps (B=1, H=8, D=128, causal,
   chained on-device timing): (1024, 1024) — grid-step count (fixed
@@ -742,6 +901,15 @@ def flash_attention(q, k, v,
   if window is not None and (not causal or window < 1):
     raise ValueError('window={!r} needs causal=True and window >= 1.'.format(
         window))
+  if block_diffusion is not None:
+    length, block = block_diffusion
+    if causal or window is not None or l_q != l_k or l_q != 2 * length or \
+        block < 1 or length % block:
+      raise ValueError(
+          'block_diffusion={!r} needs causal=False, no window, q and k of '
+          '2 x length positions ({}, {}) and a block that divides the '
+          'length.'.format(block_diffusion, l_q, l_k))
+    block_diffusion = (int(length), int(block))
   if jnp.dtype(q.dtype).itemsize >= 4:
     # f32 operands double the VMEM block footprint; the bf16-tuned
     # (1024, 1024) defaults press past the 16 MB scoped-VMEM limit at
@@ -761,8 +929,11 @@ def flash_attention(q, k, v,
       x = jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
     return x
 
+  if causal or block_diffusion is not None:
+    _set_pair_gauges('', b * h, l_q, l_k, block_q, block_k, causal, window,
+                     block_diffusion)
   out = _flash_diff(_to_bhld(q), _to_bhld(k), _to_bhld(v), causal, scale,
                     block_q, block_k, interpret, block_q_bwd, block_k_bwd,
-                    window)
+                    window, block_diffusion)
   out = out[:, :, :d] if dp != d else out
   return out.reshape(b, h, l_q, d).transpose(0, 2, 1, 3)

@@ -54,7 +54,7 @@ MOE_STATS = ('moe/pairs_held', 'moe/expert_load_max_over_mean',
 # 4 heads of 128, bf16: 117.4 MB each for q and out, 16.8 each for k and v,
 # 1.8 for the log-sum-exp, a layer) and computes the rest of itself again.
 CheckpointedBlock = nn.remat(
-    transformer_lib.RouterFirstMoEBlock,
+    transformer_lib.MoEBlock,
     policy=jax.checkpoint_policies.save_only_these_names(
         *transformer_lib.flash_lib.BACKWARD_READS))
 
@@ -63,31 +63,13 @@ def next_token_loss(hidden, head, tokens, block_tokens: int, dtype):
   """Mean cross-entropy of position i's logits against token i + 1, in f32,
   over positions 0..L-2 of every sequence.
 
-  hidden [B, L, d], head [d, V], tokens [B, L]. The logits are formed
-  ``block_tokens`` tokens at a time under ``jax.checkpoint``: a block's
-  [block, V] f32 logits live only inside its own forward and backward."""
-  b, l, d = hidden.shape
-  n = b * l
-  block = max(c for c in range(1, min(block_tokens, n) + 1) if n % c == 0)
-  targets = jnp.roll(tokens, -1, axis=1).reshape(n // block, block)
-  counted = jnp.broadcast_to(jnp.arange(l) < l - 1, (b, l)).reshape(
-      n // block, block)
-  # Cast once, outside the loop: the loop's backward pass then stacks the
-  # rows' gradients at this width, not in float32.
-  head, hidden = head.astype(dtype), hidden.astype(dtype)
-
-  @jax.checkpoint
-  def block_loss(args):
-    rows, target, count = args
-    logits = jnp.dot(rows, head, preferred_element_type=jnp.float32)
-    picked = jnp.take_along_axis(logits, target[:, None], axis=-1)[:, 0]
-    return jnp.sum(jnp.where(
-        count, jax.nn.logsumexp(logits, axis=-1) - picked, 0.0))
-
-  with jax.named_scope('head_loss'):
-    sums = jax.lax.map(
-        block_loss, (hidden.reshape(n // block, block, d), targets, counted))
-  return jnp.sum(sums) / (b * (l - 1))
+  hidden [B, L, d], head [d, V], tokens [B, L]; the logits are formed
+  ``block_tokens`` tokens at a time (``blocked_cross_entropy``)."""
+  b, l, _ = hidden.shape
+  counted = jnp.broadcast_to(jnp.arange(l) < l - 1, (b, l))
+  return transformer_lib.blocked_cross_entropy(
+      hidden, head, jnp.roll(tokens, -1, axis=1), counted, block_tokens,
+      dtype) / (b * (l - 1))
 
 
 class SmallThinkerNet(nn.Module):

@@ -97,7 +97,10 @@ class _StepWatcher:
   (never the state: that is donated to the next step) as it dispatches;
   this daemon thread waits for one step at a time and appends the event
   ``train.step_done`` (``step``, ``steps_covered``) to the span ring, on
-  the clock of every other span. It waits for the OLDEST pending step —
+  the clock of every other span. The event also carries the values of the
+  step metrics the MODEL names (``traced_step_metrics``, none by default),
+  read here after the wait, so the training thread pays nothing and the
+  ring holds them for any driver. It waits for the OLDEST pending step —
   in a device-bound loop the host leads by several steps and each is seen
   as it ends — unless that one is already done while newer ones wait: then
   this thread has fallen behind a fast loop, and it skips to the first
@@ -110,7 +113,8 @@ class _StepWatcher:
   ``max(0, dispatched(n) - done(n-1))``.
   """
 
-  def __init__(self):
+  def __init__(self, traced_step_metrics: Sequence[str] = ()):
+    self._traced = tuple(traced_step_metrics)
     self._cond = threading.Condition()
     self._pending: collections.deque = collections.deque()
     self._stopped = False
@@ -122,8 +126,10 @@ class _StepWatcher:
     leaves = jax.tree_util.tree_leaves(metrics)
     if not leaves:
       return
+    traced = {name: metrics[name] for name in self._traced
+              if name in metrics}
     with self._cond:
-      self._pending.append((step, leaves[0]))
+      self._pending.append((step, leaves[0], traced))
       self._cond.notify()
 
   def stop(self) -> None:
@@ -146,17 +152,19 @@ class _StepWatcher:
           self._cond.wait()
         if self._stopped:
           return
-        step, leaf = self._pending.popleft()
+        step, leaf, traced = self._pending.popleft()
         while self._pending and leaf.is_ready():
-          step, leaf = self._pending.popleft()
+          step, leaf, traced = self._pending.popleft()
       try:
         jax.block_until_ready(leaf)
+        traced = {name: float(value) for name, value in traced.items()}
       except Exception:  # noqa: BLE001 — a failed step ends no other way
         continue
       # After a rollback the step counter runs backwards: such an event
       # stands for itself alone.
       covered = step - last_step if last_step is not None else 1
-      event('train.step_done', step=step, steps_covered=max(covered, 1))
+      event('train.step_done', step=step, steps_covered=max(covered, 1),
+            **traced)
       last_step = step
 
 
@@ -1070,7 +1078,7 @@ class Trainer:
                   total - data_s - ckpt_s - retry_s)
 
     with graceful_shutdown() as shutdown:
-      step_watcher = _StepWatcher()
+      step_watcher = _StepWatcher(self.model.traced_step_metrics)
       try:
         while step_i < max_train_steps:
           iter_start = time.perf_counter()

@@ -206,3 +206,91 @@ class TestNamedResiduals:
     for g, w in zip(jax.grad(kept, argnums=(0, 1, 2))(*args),
                     jax.grad(loss, argnums=(0, 1, 2))(*args)):
       np.testing.assert_array_equal(g, w)
+
+
+class TestBlockDiffusionMask:
+  """The third mask mode of the three kernels (tests/test_sdar.py holds it
+  against the dense mask at every tile shape): here, that it is the same
+  kernel family, that the other two modes are what they were, and what the
+  gauges count."""
+
+  def _qkv(self, length=32, kv_heads=2):
+    key = jax.random.PRNGKey(7)
+    q = jax.random.normal(key, (1, 2 * length, 4, 16))
+    k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                              (1, 2 * length, kv_heads, 16)) for i in (1, 2))
+    return q, k, v
+
+  def test_the_same_three_kernels_once_each(self, jaxpr_calls):
+    q, k, v = self._qkv()
+    loss = lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, block_diffusion=(32, 4), block_q=16, block_k=16) ** 2)
+    calls, tags = jaxpr_calls(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert [calls[name] for name in KERNELS] == [1, 1, 1]
+    assert set(tags) == set(flash_lib.BACKWARD_READS)
+
+  def test_a_policy_keeps_the_residuals_of_a_masked_call(self, jaxpr_calls):
+    q, k, v = self._qkv()
+    loss = lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, block_diffusion=(32, 4), block_q=16, block_k=16) ** 2)
+    kept = jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(
+            *flash_lib.BACKWARD_READS))
+    calls, _ = jaxpr_calls(jax.grad(kept, argnums=(0, 1, 2)), q, k, v)
+    assert [calls[name] for name in KERNELS] == [1, 1, 1]
+    for g, w in zip(jax.grad(kept, argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss, argnums=(0, 1, 2))(q, k, v)):
+      np.testing.assert_array_equal(g, w)
+
+  @pytest.mark.parametrize('window', [None, 8])
+  def test_causal_and_window_are_what_they_were(self, window):
+    """Causal and window calls still match the dense oracle; and a mask of
+    ONE block (B = L) is full attention within each half: block-causal
+    with one block among the clean tokens, the own block among the noised
+    ones, which see no clean token (none lies in an earlier block)."""
+    q, k, v = self._qkv()
+    out = flash_attention(q, k, v, causal=True, window=window, block_q=16,
+                          block_k=16)
+    want = transformer_lib.scaled_dot_attention(q, k, v, True, window)
+    np.testing.assert_allclose(out, want, atol=2e-6)
+    one_block = flash_attention(q, k, v, block_diffusion=(32, 32),
+                                block_q=16, block_k=16)
+    clean = flash_attention(q[:, 32:], k[:, 32:], v[:, 32:], block_q=16,
+                            block_k=16)
+    np.testing.assert_allclose(one_block[:, 32:], clean, atol=2e-6)
+    noised = flash_attention(q[:, :32], k[:, :32], v[:, :32], block_q=16,
+                             block_k=16)
+    np.testing.assert_allclose(one_block[:, :32], noised, atol=2e-6)
+
+  def test_blocks_of_one_make_the_clean_half_causal(self):
+    q, k, v = self._qkv()
+    masked = flash_attention(q, k, v, block_diffusion=(32, 1), block_q=16,
+                             block_k=16)
+    causal = flash_attention(q[:, 32:], k[:, 32:], v[:, 32:], causal=True,
+                             block_q=16, block_k=16)
+    np.testing.assert_allclose(masked[:, 32:], causal, atol=2e-6)
+
+  @pytest.mark.parametrize('causal, window, diffusion, pairs, tiles', [
+      (True, None, None, 64 * 65 // 2, 10),
+      (True, 8, None, 8 * 9 // 2 + 56 * 8, 7),
+      (False, None, (32, 4), 32 * 32 + 32 * 4, 2 + 3 + 3),
+  ])
+  def test_the_gauges_count_the_mask_and_the_tiles(self, causal, window,
+                                                   diffusion, pairs, tiles):
+    from tensor2robot_tpu.observability import get_registry
+
+    assert flash_lib.mask_pairs(64, 64, causal, window, diffusion) == pairs
+    assert flash_lib.tiles_computed(4, 4, 16, 16, causal, window,
+                                    diffusion) == tiles
+    q, k, v = self._qkv()
+    jax.make_jaxpr(lambda q: flash_attention(
+        q, k, v, causal=causal, window=window, block_diffusion=diffusion,
+        block_q=16, block_k=16))(q)
+    registry = get_registry()
+    assert registry.gauge('attention/mask_pairs_needed').value == 4 * pairs
+    assert registry.gauge('attention/mask_pairs_computed').value == \
+        4 * tiles * 256
+
+  def test_an_unmasked_call_sets_no_gauge(self):
+    assert flash_lib.mask_pairs(64, 48, False, None, None) == 64 * 48
+    assert flash_lib.tiles_computed(4, 3, 16, 16, False, None, None) == 12

@@ -247,3 +247,82 @@ class TestMoEDtypes:
     # Router runs in f32 in both; the only drift is the bf16-rounded
     # input it sees.
     np.testing.assert_allclose(float(aux16), float(aux32), rtol=1e-3)
+
+
+class TestDroplessGateAndShares:
+  """The dropless layer's gate activation is a field, and one chip's share
+  of an eight-chip deployment (16 of 128 experts, top 8) adds up with the
+  seven others to the uncut layer."""
+
+  def _inputs(self, experts, d=16, width=8, tokens=48):
+    from tensor2robot_tpu.layers import moe as moe_lib
+
+    u = jax.random.normal(jax.random.PRNGKey(4), (tokens, d))
+    logits = jax.random.normal(jax.random.PRNGKey(5), (tokens, experts))
+    layer = moe_lib.DroplessMoE(num_experts=experts,
+                                experts_held=(0, experts), expert_dim=width,
+                                top_k=8, gate_activation='silu',
+                                block_rows=8)
+    params = jax.tree.map(
+        lambda x: 20 * x,
+        layer.init(jax.random.PRNGKey(6), u, logits)['params'])
+    return moe_lib, u, logits, params
+
+  @staticmethod
+  def _dense(params, u, logits, first, held, top_k, activation):
+    values, index = jax.lax.top_k(logits, top_k)
+    weight = jax.nn.softmax(values, -1)
+    y = 0
+    for e in range(held):
+      gate = ((index == first + e) * weight).sum(-1)
+      y = y + gate[:, None] * (
+          (activation(u @ params['w_gate'][e]) * (u @ params['w_up'][e]))
+          @ params['w_down'][e])
+    return y
+
+  @pytest.mark.parametrize('name, activation', [('relu', jax.nn.relu),
+                                                ('silu', jax.nn.silu)])
+  def test_the_gate_is_activated_as_the_field_says(self, name, activation):
+    moe_lib, u, logits, params = self._inputs(8)
+    layer = moe_lib.DroplessMoE(num_experts=8, experts_held=(0, 8),
+                                expert_dim=8, top_k=8, gate_activation=name,
+                                block_rows=8)
+    y, _ = layer.apply({'params': params}, u, logits)
+    np.testing.assert_allclose(
+        y, self._dense(params, u, logits, 0, 8, 8, activation), atol=2e-5)
+    got = jax.grad(lambda p: jnp.sum(jnp.sin(
+        layer.apply({'params': p}, u, logits)[0])))(params)
+    want = jax.grad(lambda p: jnp.sum(jnp.sin(
+        self._dense(p, u, logits, 0, 8, 8, activation))))(params)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+      np.testing.assert_allclose(g, w, atol=5e-5)
+
+  def test_relu_is_the_default_and_the_two_differ(self):
+    moe_lib, u, logits, params = self._inputs(8)
+    kwargs = dict(num_experts=8, experts_held=(0, 8), expert_dim=8, top_k=8,
+                  block_rows=8)
+    assert moe_lib.DroplessMoE(**kwargs).gate_activation == 'relu'
+    relu, _ = moe_lib.DroplessMoE(**kwargs).apply({'params': params}, u,
+                                                  logits)
+    silu, _ = moe_lib.DroplessMoE(gate_activation='silu', **kwargs).apply(
+        {'params': params}, u, logits)
+    assert float(jnp.max(jnp.abs(relu - silu))) > 1e-3
+    with pytest.raises(ValueError, match='gate_activation'):
+      moe_lib.DroplessMoE(gate_activation='gelu', **kwargs).apply(
+          {'params': params}, u, logits)
+
+  def test_the_eight_shares_of_sixteen_add_up_to_the_uncut_layer(self):
+    moe_lib, u, logits, params = self._inputs(128)
+    whole = self._dense(params, u, logits, 0, 128, 8, jax.nn.silu)
+    total, pairs = 0, 0
+    for first in range(0, 128, 16):
+      share = jax.tree.map(lambda x: x[first:first + 16], params)
+      y, stats = moe_lib.DroplessMoE(
+          num_experts=128, experts_held=(first, 16), expert_dim=8, top_k=8,
+          gate_activation='silu', block_rows=8).apply(
+              {'params': share}, u, logits)
+      total = total + y
+      pairs += float(stats['pairs_held'])
+      assert float(stats['dropped_pairs']) == 0
+    np.testing.assert_allclose(total, whole, atol=2e-5)
+    assert pairs == 48 * 8

@@ -152,7 +152,7 @@ class TestTheBlockCheckpointKeepsWhatTheAttentionBackwardReads:
     results = {}
     for name, block_cls in [
         ('kept', smallthinker_model.CheckpointedBlock),
-        ('policy-less', nn.remat(transformer_lib.RouterFirstMoEBlock))]:
+        ('policy-less', nn.remat(transformer_lib.MoEBlock))]:
       loss, params, x = _block_stack(block_cls, blocks, windowed)
       grad = jax.value_and_grad(loss, argnums=(0, 1))
       calls, _ = jaxpr_calls(grad, params, x)
