@@ -10,27 +10,41 @@ stable online softmax (Milakov & Gimelshein 2018; Dao et al. 2022,
 FlashAttention) in VMEM scratch that persists across the innermost grid
 dimension:
 
-  grid = (batch*heads, n_q_tiles, L/block_k, tile/block_q)
-         # k OUTER within a q tile, q INNER: each k/v block is fetched
-         # once per k step and reused by the whole tile's q sweep (the
-         # FlashAttention-2 loop order); the tile's accumulators stay
-         # resident in VMEM scratch. Fully-masked causal blocks skip.
+  grid = (batch*heads, steps)
+         # one step a TILE THE MASK KEEPS, and no other: the mask is static
+         # (causal, causal with a window, block diffusion, or none), so the
+         # host lists the needed (q block, k block) pairs once a call
+         # (tile_table) and hands the list to the kernel as scalar-prefetch
+         # tables; the index maps and the body read their block indices
+         # from it. q OUTER, k ASCENDING within a q block: the q block, its
+         # output and its accumulators stay put over the q block's steps
+         # (fetched and written back once), k/v blocks stream past.
   s    = q_block @ k_block^T * scale           # MXU, f32 accumulation
   m'   = max(m, rowmax(s));  p = exp(s - m')   # VPU
   l    = l * exp(m - m') + rowsum(p)
   acc  = acc * exp(m - m') + p @ v_block       # MXU
-  at the last k block: out = acc / l
+  at the q block's last step (a flag of the table): out = acc / l
+
+A tile outside the mask is not a grid step at all: it is not launched,
+its blocks are not fetched and no output block is written back for it. (A
+rectangular grid with a branch on the tile's predicate inside pays all
+three for every tile it skips; what that cost the two token cells is in
+PERF.md, Findings PR 33.) An unmasked call lists the whole rectangle. A
+tile on the mask's edge is computed whole, with the mask applied to its
+elements.
 
 Memory: per-device O(L*D) activations only — no score tensor ever reaches
-HBM, forward OR backward: since round 4 the backward is the same kernel
-family (two Pallas kernels, FlashAttention-2 structure, causal block
-skip — _flash_bwd_pallas) instead of an XLA scan. Numerics match the XLA
-oracle to f32 rounding (tests/test_flash_attention.py); what the three
-kernels measure in a cell of the benchmark is in PERF.md (section 5:
+HBM, forward OR backward: the backward is the same kernel family (two
+Pallas kernels, FlashAttention-2 structure, each over its own list of
+needed tiles — _flash_bwd_pallas) instead of an XLA scan. Numerics match
+the XLA oracle to f32 rounding (tests/test_flash_attention.py); what the
+three kernels measure in a cell of the benchmark is in PERF.md (section 5:
 time a step by kernel, the work executed beside the work needed, and
 ``attention_roofline``). This is the single-device
 long-context path; ring_attention.py handles the cross-device dimension
-with its own shard-level blockwise accumulation.
+with its own shard-level blockwise accumulation (flash_attention_carry:
+its offsets are traced values, so its grid stays the rectangle with a
+branch inside).
 
 Named residuals. The forward rule of the custom VJP (_flash_fwd) names
 what the backward kernels read with ``jax.ad_checkpoint.checkpoint_name``:
@@ -110,7 +124,7 @@ def _block_in_band(i_q, i_k, block_q: int, block_k: int,
                    window: Optional[int]):
   """Whether block (i_q, i_k) holds any position of the causal band: not
   wholly above the diagonal and, with ``window``, not wholly left of the
-  band. Blocks outside are skipped on BOTH sides."""
+  band. Blocks outside, on either side, are not needed."""
   needed = i_q * block_q + block_q - 1 >= i_k * block_k
   if window is not None:
     needed = needed & (
@@ -120,7 +134,8 @@ def _block_in_band(i_q, i_k, block_q: int, block_k: int,
 
 def _block_index(position, block: int):
   """``position // block`` for non-negative positions (a shift where
-  ``block`` is a power of two: the kernels run it on every tile)."""
+  ``block`` is a power of two: the kernels run it on every tile's row and
+  column of positions)."""
   if block & (block - 1) == 0:
     return position >> (block.bit_length() - 1)
   return position // block
@@ -147,27 +162,27 @@ def _in_block_diffusion(q_pos, k_pos, length: int, block: int):
 
 
 def _block_in_block_diffusion(i_q, i_k, block_q: int, block_k: int,
-                              length: int, block: int, xp=jnp):
+                              length: int, block: int):
   """Whether tile (i_q, i_k) holds any pair of the block-diffusion mask:
   its noised rows against its noised columns (block ranges that meet), its
   noised rows against its clean columns (a clean block strictly below the
   last noised row's), its clean rows against its clean columns (a clean
   block at or below the last clean row's). A tile may straddle the border
-  between the halves. ``xp`` is ``jnp`` inside a kernel (scalars of the
-  grid) and ``numpy`` on the host (the whole grid at once)."""
+  between the halves. Evaluated on the host, numpy indices of the whole
+  rectangle at once."""
   first_row, last_row = i_q * block_q, i_q * block_q + block_q - 1
   first_col, last_col = i_k * block_k, i_k * block_k + block_k - 1
   index = lambda position: _block_index(position, block)
   noised_rows, clean_rows = first_row < length, last_row >= length
   noised_cols, clean_cols = first_col < length, last_col >= length
-  last_noised_row = index(xp.minimum(last_row, length - 1))
-  first_clean_col = index(xp.maximum(first_col, length) - length)
+  last_noised_row = index(np.minimum(last_row, length - 1))
+  first_clean_col = index(np.maximum(first_col, length) - length)
   own = (noised_rows & noised_cols &
-         (index(first_row) <= index(xp.minimum(last_col, length - 1))) &
+         (index(first_row) <= index(np.minimum(last_col, length - 1))) &
          (index(first_col) <= last_noised_row))
   earlier = noised_rows & clean_cols & (first_clean_col < last_noised_row)
   causal = clean_rows & clean_cols & (
-      first_clean_col <= index(xp.maximum(last_row, length) - length))
+      first_clean_col <= index(np.maximum(last_row, length) - length))
   return own | earlier | causal
 
 
@@ -188,11 +203,10 @@ def _tile_mask(q_base, k_base, block_q: int, block_k: int,
 
 
 def _tile_needed(i_q, i_k, block_q: int, block_k: int,
-                 window: Optional[int], diffusion, xp=jnp):
-  """The tile-level predicate of the mask ``_tile_mask`` applies."""
+                 window: Optional[int], diffusion):
+  """The tile-level predicate of the mask ``_tile_mask`` applies (host)."""
   if diffusion is not None:
-    return _block_in_block_diffusion(i_q, i_k, block_q, block_k, *diffusion,
-                                     xp=xp)
+    return _block_in_block_diffusion(i_q, i_k, block_q, block_k, *diffusion)
   return _block_in_band(i_q, i_k, block_q, block_k, window)
 
 
@@ -220,22 +234,73 @@ def mask_pairs(l_q: int, l_k: int, causal: bool, window: Optional[int],
   return int(np.sum(np.clip(np.minimum(rows, l_k - 1) - first + 1, 0, None)))
 
 
-def tiles_computed(n_q: int, n_k: int, block_q: int, block_k: int,
-                   causal: bool, window: Optional[int], diffusion) -> int:
-  """Tiles of the [n_q, n_k] grid that the kernels compute: the count of
-  the predicate they branch on, evaluated on the host."""
+def _tiles_needed(n_q: int, n_k: int, block_q: int, block_k: int,
+                  causal: bool, window: Optional[int], diffusion):
+  """[n_q, n_k] bool, on the host: the tiles that hold a pair of the mask
+  (every tile of an unmasked call)."""
   if not causal and diffusion is None:
-    return n_q * n_k
-  return int(np.sum(_tile_needed(
+    return np.ones((n_q, n_k), bool)
+  return np.broadcast_to(_tile_needed(
       np.arange(n_q, dtype=np.int32)[:, None],
       np.arange(n_k, dtype=np.int32)[None, :], block_q, block_k, window,
-      diffusion, xp=np)))
+      diffusion), (n_q, n_k))
 
 
-def _set_pair_gauges(suffix: str, bh: int, l_q: int, l_k: int, block_q: int,
-                     block_k: int, causal: bool, window, diffusion):
-  """Host side, when a masked call is traced: the pairs the mask keeps and
-  the pairs of the tiles the kernels compute, a call (all heads)."""
+def tiles_computed(n_q: int, n_k: int, block_q: int, block_k: int,
+                   causal: bool, window: Optional[int], diffusion) -> int:
+  """Tiles of the [n_q, n_k] rectangle that the mask keeps: the tiles the
+  kernels compute, and but for an empty block (``tile_table``) the grid
+  steps they launch."""
+  return int(np.sum(_tiles_needed(n_q, n_k, block_q, block_k, causal, window,
+                                  diffusion)))
+
+
+# Flags of a grid step (``tile_table``): its accumulator starts here, and
+# is written out here.
+FIRST, LAST = 1, 2
+
+
+def tile_table(n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
+               window: Optional[int], diffusion, *, k_resident: bool = False,
+               group: int = 1):
+  """The grid of a kernel: int32 [4, steps], the rows (head, q block,
+  k block, flags), one column a grid step, every step a tile the mask keeps.
+
+  An ACCUMULATOR is what stays in VMEM while blocks stream past it: a q
+  block in the forward and the dq kernel (its k blocks follow one another
+  in ASCENDING order; ``head`` is 0), a k/v block in the dk/dv kernel
+  (``k_resident``: the ``group`` query heads that read it follow one
+  another, each with its q blocks ascending, which is the order the
+  rectangular sweep had, so every sum is formed in that order). Steps of
+  one accumulator are consecutive: the resident blocks and the output are
+  named by every one of them, so they are fetched and written back once.
+  ``flags`` has FIRST on an accumulator's first step and LAST on its last.
+
+  An accumulator that the mask leaves NO tile (a k/v block past the last
+  query under ``causal`` with ``l_k > l_q``; never a self-attention row,
+  which sees itself) still gets one step, FIRST | LAST, on its first
+  candidate tile: the in-tile mask keeps nothing of it, so the step
+  computes the defined output (zeros for dk/dv and ``out``) at the price
+  of one tile, with no branch in the kernel."""
+  needed = _tiles_needed(n_q, n_k, block_q, block_k, causal, window,
+                         diffusion)
+  needed = np.tile(needed.T if k_resident else needed, (1, group))
+  needed[~needed.any(axis=1), 0] = True
+  resident, streamed = np.nonzero(needed)   # row-major: both ascending
+  first = np.append(True, resident[1:] != resident[:-1])
+  flags = FIRST * first + LAST * np.append(first[1:], True)
+  if k_resident:
+    rows = (streamed // n_q, streamed % n_q, resident, flags)
+  else:
+    rows = (np.zeros_like(resident), resident, streamed, flags)
+  return np.stack(rows).astype(np.int32)
+
+
+def _set_gauges(suffix: str, bh: int, l_q: int, l_k: int, block_q: int,
+                block_k: int, causal: bool, window, diffusion, steps: int):
+  """Host side, when a masked call is traced: the pairs the mask keeps, the
+  pairs of the tiles the kernels compute and the grid steps they launch
+  (``steps`` a query head), a call (all heads)."""
   from tensor2robot_tpu.observability import get_registry
 
   registry = get_registry()
@@ -245,6 +310,7 @@ def _set_pair_gauges(suffix: str, bh: int, l_q: int, l_k: int, block_q: int,
       bh * block_q * block_k * tiles_computed(
           l_q // block_q, l_k // block_k, block_q, block_k, causal, window,
           diffusion)))
+  registry.gauge('attention/grid_steps' + suffix).set(float(bh * steps))
 
 
 def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
@@ -292,62 +358,36 @@ def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
       p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                  l_ref, *, scale: float, causal: bool, block_q: int,
-                  block_k: int, window: Optional[int] = None,
-                  diffusion=None):
-  """One step of the k-outer / q-inner sweep within a q TILE.
+def _flash_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref, v_ref,
+                  o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale: float,
+                  causal: bool, block_q: int, block_k: int,
+                  window: Optional[int] = None, diffusion=None):
+  """One step of the grid (bh, steps of ``tile_table``): one tile the mask
+  keeps, folded into its q block's accumulators. The q block, its output
+  and its log-sum-exp are named by every step of the q block, so they move
+  once; k and v blocks stream past in ascending order."""
+  t = pl.program_id(1)
+  flags = flags_ref[t]
 
-  The grid is (bh, n_q_outer, n_k, n_q_inner): within one q tile
-  (n_q_inner * block_q rows, accumulators resident in VMEM scratch),
-  k is the outer loop — so Pallas fetches each k/v block ONCE per k
-  step and the inner q sweep reuses it from VMEM. With q fully outer
-  (the FlashAttention-1 order) every k/v block is re-fetched for every
-  q block; at long L the kernel was bound by those copies, not the MXU
-  (measured 12.7 ms at L=16k vs ~4 ms in this order). The q tile keeps
-  scratch under the 16 MB scoped-VMEM limit; k/v blocks are re-fetched
-  only once per TILE (L/tile times total).
-  """
-  i_qo = pl.program_id(1)
-  i_k = pl.program_id(2)
-  i_qi = pl.program_id(3)
-  n_k = pl.num_programs(2)
-  n_qi = pl.num_programs(3)
-  i_q = i_qo * n_qi + i_qi            # global q-block index
-  rows = pl.dslice(i_qi * block_q, block_q)
-
-  @pl.when(i_k == 0)
+  @pl.when(flags & FIRST != 0)
   def _init():
-    acc_ref[rows, :] = jnp.zeros((block_q, acc_ref.shape[-1]), jnp.float32)
-    m_ref[rows, :] = jnp.full((block_q, 128), NEG_INF, jnp.float32)
-    l_ref[rows, :] = jnp.zeros((block_q, 128), jnp.float32)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-  def _do_update():
-    # One shared numerics implementation (_block_update) for both this
-    # kernel and the ring-carry kernel; the tile's accumulator rows are
-    # exposed as sub-refs.
-    _block_update(q_ref, k_ref, v_ref, acc_ref.at[rows, :],
-                  m_ref.at[rows, :], l_ref.at[rows, :], scale=scale,
-                  causal=causal, block_q=block_q, block_k=block_k,
-                  q_offset=0, k_offset=0, i_q=i_q, i_k=i_k, window=window,
-                  diffusion=diffusion)
+  # One shared numerics implementation (_block_update) for both this
+  # kernel and the ring-carry kernel.
+  _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale=scale,
+                causal=causal, block_q=block_q, block_k=block_k, q_offset=0,
+                k_offset=0, i_q=q_blocks_ref[t], i_k=k_blocks_ref[t],
+                window=window, diffusion=diffusion)
 
-  if causal or diffusion is not None:
-    # Skip blocks entirely above the causal diagonal (all scores -inf)
-    # and, with a window, those entirely left of the band; under the
-    # block-diffusion mask, the tiles that hold none of its pairs.
-    @pl.when(_tile_needed(i_q, i_k, block_q, block_k, window, diffusion))
-    def _update():
-      _do_update()
-  else:
-    _do_update()
-
-  @pl.when(i_k == n_k - 1)
+  @pl.when(flags & LAST != 0)
   def _finalize():
-    l_col = jnp.max(l_ref[rows, :], axis=-1, keepdims=True)    # [bq, 1]
-    m_col = jnp.max(m_ref[rows, :], axis=-1, keepdims=True)
+    l_col = jnp.max(l_ref[...], axis=-1, keepdims=True)        # [bq, 1]
+    m_col = jnp.max(m_ref[...], axis=-1, keepdims=True)
     l_final = jnp.maximum(l_col, 1e-20)
-    o_ref[0] = (acc_ref[rows, :] / l_final).astype(o_ref.dtype)
+    o_ref[0] = (acc_ref[...] / l_final).astype(o_ref.dtype)
     # Log-sum-exp per row, saved for the backward pass (FlashAttention).
     # Broadcast over the 8 padding sublanes (see _flash_bhld's lse shape).
     row = (m_col + jnp.log(l_final))[:, 0]
@@ -379,50 +419,48 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
   bh, l_q, d = q.shape
   l_k = k.shape[1]
   kv = _kv_head(bh // k.shape[0])
-  n_q = pl.cdiv(l_q, block_q)
-  n_k = pl.cdiv(l_k, block_k)
-  # q rows per tile: as many q blocks as fit a few MB of f32 accumulator
-  # scratch AND divide n_q evenly (grid dims are rectangular).
-  max_qi = max(1, (4096 // block_q))
-  n_qi = max_qi
-  while n_q % n_qi:
-    n_qi -= 1
-  n_qo = n_q // n_qi
-  tile_rows = n_qi * block_q
+  table = tile_table(l_q // block_q, l_k // block_k, block_q, block_k,
+                     causal, window, diffusion)
+  if causal or diffusion is not None:
+    _set_gauges('', bh, l_q, l_k, block_q, block_k, causal, window,
+                diffusion, table.shape[1])
   kernel = functools.partial(
       _flash_kernel, scale=scale, causal=causal, block_q=block_q,
       block_k=block_k, window=window, diffusion=diffusion)
-  # Grid: per q TILE, k OUTER / q INNER (see _flash_kernel) — each k/v
-  # block is fetched once per k step per tile; the tile's accumulators
-  # live in VMEM scratch.
-  out, lse8 = pl.pallas_call(
-      kernel,
-      grid=(bh, n_qo, n_k, n_qi),
+  # Index maps receive the table's rows (but the head's, which is 0 for a
+  # resident q block) as trailing arguments.
+  q_block = lambda b, t, qs, ks, flags: (b, qs[t], 0)
+  kv_block = lambda b, t, qs, ks, flags: (kv(b), ks[t], 0)
+  grid_spec = pltpu.PrefetchScalarGridSpec(
+      num_scalar_prefetch=3,
+      grid=(bh, table.shape[1]),
       in_specs=[
-          pl.BlockSpec((1, block_q, d),
-                       lambda b, qo, j, qi, n=n_qi: (b, qo * n + qi, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, qo, j, qi: (kv(b), j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, qo, j, qi: (kv(b), j, 0)),
+          pl.BlockSpec((1, block_q, d), q_block),
+          pl.BlockSpec((1, block_k, d), kv_block),
+          pl.BlockSpec((1, block_k, d), kv_block),
       ],
       out_specs=[
-          pl.BlockSpec((1, block_q, d),
-                       lambda b, qo, j, qi, n=n_qi: (b, qo * n + qi, 0)),
+          pl.BlockSpec((1, block_q, d), q_block),
           pl.BlockSpec((1, 8, block_q),
-                       lambda b, qo, j, qi, n=n_qi: (b, 0, qo * n + qi)),
+                       lambda b, t, qs, ks, flags: (b, 0, qs[t])),
       ],
+      scratch_shapes=[
+          pltpu.VMEM((block_q, d), jnp.float32),
+          # 128 uniform lanes per scalar — see _block_update's m/l note.
+          pltpu.VMEM((block_q, 128), jnp.float32),
+          pltpu.VMEM((block_q, 128), jnp.float32),
+      ],
+  )
+  out, lse8 = pl.pallas_call(
+      kernel,
+      grid_spec=grid_spec,
       out_shape=[
           jax.ShapeDtypeStruct(q.shape, q.dtype),
           jax.ShapeDtypeStruct((bh, 8, l_q), jnp.float32),
       ],
-      scratch_shapes=[
-          pltpu.VMEM((tile_rows, d), jnp.float32),
-          # 128 uniform lanes per scalar — see _block_update's m/l note.
-          pltpu.VMEM((tile_rows, 128), jnp.float32),
-          pltpu.VMEM((tile_rows, 128), jnp.float32),
-      ],
       interpret=interpret,
       name='flash_attention_fwd',
-  )(q, k, v)
+  )(*table[1:], q, k, v)
   return out, lse8[:, 0, :]
 
 
@@ -584,98 +622,81 @@ def _bwd_p_ds(q, k, v, do, lse, delta, *, scale, causal, q_base, k_base,
   return p, ds
 
 
-def _flash_bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _flash_bwd_kv_kernel(head_ref, q_blocks_ref, k_blocks_ref, flags_ref,
+                         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                          causal: bool, block_q: int, block_k: int,
-                         n_q: int, group: int = 1,
                          window: Optional[int] = None, diffusion=None):
-  """dk/dv: grid (bh of k/v, n_k, group * n_q) — k/v block resident
-  (accumulators in scratch), q/do/lse/delta stream through. With
-  grouped-query heads the last axis runs over the ``group`` query heads
-  that read this k/v head, head-major, so dk/dv are summed over the group
-  in the scratch and written once."""
-  i_k = pl.program_id(1)
-  i_t = pl.program_id(2)
-  n_t = pl.num_programs(2)
-  i_q = i_t if group == 1 else i_t % n_q
+  """dk/dv: grid (bh of k/v, steps of ``tile_table(k_resident=True)``) —
+  k/v block resident (accumulators in scratch), q/do/lse/delta stream
+  through. With grouped-query heads a k/v block's steps run over the
+  ``group`` query heads that read this k/v head, head-major, so dk/dv are
+  summed over the group in the scratch and written once."""
+  del head_ref
+  t = pl.program_id(1)
+  flags = flags_ref[t]
 
-  @pl.when(i_t == 0)
+  @pl.when(flags & FIRST != 0)
   def _init():
     dk_acc[...] = jnp.zeros_like(dk_acc)
     dv_acc[...] = jnp.zeros_like(dv_acc)
 
-  def _update():
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    # Reduce over the uniform broadcast sublanes instead of slicing one
-    # (width-1 memref slices are rejected by jax 0.9 Mosaic).
-    lse = jnp.max(lse_ref[0].astype(jnp.float32), axis=0)[:, None]
-    delta = jnp.max(delta_ref[0].astype(jnp.float32), axis=0)[:, None]
-    p, ds = _bwd_p_ds(q, k_ref[0].astype(jnp.float32),
-                      v_ref[0].astype(jnp.float32), do, lse, delta,
-                      scale=scale, causal=causal,
-                      q_base=i_q * block_q, k_base=i_k * block_k,
-                      block_q=block_q, block_k=block_k, window=window,
-                      diffusion=diffusion)
-    dv_acc[...] += jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dk_acc[...] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+  q = q_ref[0].astype(jnp.float32)
+  do = do_ref[0].astype(jnp.float32)
+  # Reduce over the uniform broadcast sublanes instead of slicing one
+  # (width-1 memref slices are rejected by jax 0.9 Mosaic).
+  lse = jnp.max(lse_ref[0].astype(jnp.float32), axis=0)[:, None]
+  delta = jnp.max(delta_ref[0].astype(jnp.float32), axis=0)[:, None]
+  p, ds = _bwd_p_ds(q, k_ref[0].astype(jnp.float32),
+                    v_ref[0].astype(jnp.float32), do, lse, delta,
+                    scale=scale, causal=causal,
+                    q_base=q_blocks_ref[t] * block_q,
+                    k_base=k_blocks_ref[t] * block_k,
+                    block_q=block_q, block_k=block_k, window=window,
+                    diffusion=diffusion)
+  dv_acc[...] += jax.lax.dot_general(
+      p, do, (((0,), (0,)), ((), ())),
+      preferred_element_type=jnp.float32)
+  dk_acc[...] += jax.lax.dot_general(
+      ds, q, (((0,), (0,)), ((), ())),
+      preferred_element_type=jnp.float32)
 
-  if causal or diffusion is not None:
-    # Blocks fully above the diagonal (or, with a window, fully left of
-    # the band; or outside the block-diffusion mask) contribute nothing
-    # to dk/dv.
-    @pl.when(_tile_needed(i_q, i_k, block_q, block_k, window, diffusion))
-    def _():
-      _update()
-  else:
-    _update()
-
-  @pl.when(i_t == n_t - 1)
+  @pl.when(flags & LAST != 0)
   def _finalize():
     dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
     dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dq_ref, dq_acc, *, scale: float, causal: bool,
-                        block_q: int, block_k: int,
-                        window: Optional[int] = None, diffusion=None):
-  """dq: grid (bh, n_q, n_k) — q block resident, k/v stream through."""
-  i_q = pl.program_id(1)
-  i_k = pl.program_id(2)
-  n_k = pl.num_programs(2)
+def _flash_bwd_q_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref,
+                        v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc, *,
+                        scale: float, causal: bool, block_q: int,
+                        block_k: int, window: Optional[int] = None,
+                        diffusion=None):
+  """dq: grid (bh, steps of ``tile_table``) — q block resident, k/v stream
+  through."""
+  t = pl.program_id(1)
+  flags = flags_ref[t]
 
-  @pl.when(i_k == 0)
+  @pl.when(flags & FIRST != 0)
   def _init():
     dq_acc[...] = jnp.zeros_like(dq_acc)
 
-  def _update():
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = jnp.max(lse_ref[0].astype(jnp.float32), axis=0)[:, None]
-    delta = jnp.max(delta_ref[0].astype(jnp.float32), axis=0)[:, None]
-    k = k_ref[0].astype(jnp.float32)
-    _, ds = _bwd_p_ds(q, k, v_ref[0].astype(jnp.float32), do, lse, delta,
-                      scale=scale, causal=causal,
-                      q_base=i_q * block_q, k_base=i_k * block_k,
-                      block_q=block_q, block_k=block_k, window=window,
-                      diffusion=diffusion)
-    dq_acc[...] += jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+  q = q_ref[0].astype(jnp.float32)
+  do = do_ref[0].astype(jnp.float32)
+  lse = jnp.max(lse_ref[0].astype(jnp.float32), axis=0)[:, None]
+  delta = jnp.max(delta_ref[0].astype(jnp.float32), axis=0)[:, None]
+  k = k_ref[0].astype(jnp.float32)
+  _, ds = _bwd_p_ds(q, k, v_ref[0].astype(jnp.float32), do, lse, delta,
+                    scale=scale, causal=causal,
+                    q_base=q_blocks_ref[t] * block_q,
+                    k_base=k_blocks_ref[t] * block_k,
+                    block_q=block_q, block_k=block_k, window=window,
+                    diffusion=diffusion)
+  dq_acc[...] += jax.lax.dot_general(
+      ds, k, (((1,), (0,)), ((), ())),
+      preferred_element_type=jnp.float32)
 
-  if causal or diffusion is not None:
-    @pl.when(_tile_needed(i_q, i_k, block_q, block_k, window, diffusion))
-    def _():
-      _update()
-  else:
-    _update()
-
-  @pl.when(i_k == n_k - 1)
+  @pl.when(flags & LAST != 0)
   def _finalize():
     dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
@@ -688,19 +709,28 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
 
   Two kernels (FlashAttention-2 structure): dk/dv with the k/v block
   resident and q streaming, dq with the q block resident and k/v
-  streaming. P is recomputed from the forward's saved log-sum-exp; no
-  [L, L] tensor exists in either pass. delta = rowsum(do * out) is one
-  fused elementwise pass XLA handles before the kernels. Grouped-query
-  heads: both kernels read k/v head ``b // group`` through their index
-  maps; the dk/dv kernel sweeps the group's query heads after one another
-  over its resident k/v block (see _flash_bwd_kv_kernel).
+  streaming, each over the tiles the mask keeps (``tile_table``). P is
+  recomputed from the forward's saved log-sum-exp; no [L, L] tensor exists
+  in either pass. delta = rowsum(do * out) is one fused elementwise pass
+  XLA handles before the kernels. Grouped-query heads: both kernels read
+  k/v head ``b // group`` through their index maps; the dk/dv kernel
+  sweeps the group's query heads after one another over its resident k/v
+  block (see _flash_bwd_kv_kernel).
   """
   bh, l_q, d = q.shape
   l_k = k.shape[1]
   group = bh // k.shape[0]
   kv = _kv_head(group)
-  n_q = l_q // block_q
-  n_k = l_k // block_k
+  tiles = (l_q // block_q, l_k // block_k, block_q, block_k, causal, window,
+           diffusion)
+  kv_table = tile_table(*tiles, k_resident=True, group=group)
+  q_table = tile_table(*tiles)
+  if causal or diffusion is not None:
+    # The two kernels' steps a query head differ only where the mask
+    # leaves a q block or a k/v block empty: the larger count.
+    _set_gauges('_bwd', bh, l_q, l_k, block_q, block_k, causal, window,
+                diffusion,
+                max(q_table.shape[1], kv_table.shape[1] // group))
   do = d_out.astype(jnp.float32)
   delta = jnp.sum(do * out.astype(jnp.float32), axis=-1)      # [BH, Lq]
   # lse/delta ride as [BH, 8, L] broadcast-sublane blocks (Mosaic's
@@ -708,67 +738,70 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
   lse8 = jnp.broadcast_to(lse[:, None, :], (bh, 8, l_q))
   delta8 = jnp.broadcast_to(delta[:, None, :], (bh, 8, l_q))
 
-  # (query head of the flattened axis, q block) of step t of k/v head b.
-  if group == 1:
-    q_of = lambda b, t: (b, t)
-  else:
-    q_of = lambda b, t: (b * group + t // n_q, t % n_q)
-
+  # Index maps receive the table's rows as trailing arguments. dk/dv: b is
+  # a k/v head, its step's query head b * group + head[t].
+  q_of_kv = lambda b, t, head, qs, ks, flags: (b * group + head[t], qs[t], 0)
+  row_of_kv = lambda b, t, head, qs, ks, flags: (b * group + head[t], 0, qs[t])
+  kv_of_kv = lambda b, t, head, qs, ks, flags: (b, ks[t], 0)
   kv_kernel = functools.partial(
       _flash_bwd_kv_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k, n_q=n_q, group=group, window=window,
-      diffusion=diffusion)
+      block_k=block_k, window=window, diffusion=diffusion)
   dk, dv = pl.pallas_call(
       kv_kernel,
-      grid=(bh // group, n_k, group * n_q),
-      in_specs=[
-          pl.BlockSpec((1, block_q, d), lambda b, j, t: (*q_of(b, t), 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
-          pl.BlockSpec((1, block_q, d), lambda b, j, t: (*q_of(b, t), 0)),
-          pl.BlockSpec((1, 8, block_q),
-                       lambda b, j, t: (q_of(b, t)[0], 0, q_of(b, t)[1])),
-          pl.BlockSpec((1, 8, block_q),
-                       lambda b, j, t: (q_of(b, t)[0], 0, q_of(b, t)[1])),
-      ],
-      out_specs=[
-          pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
-      ],
+      grid_spec=pltpu.PrefetchScalarGridSpec(
+          num_scalar_prefetch=4,
+          grid=(bh // group, kv_table.shape[1]),
+          in_specs=[
+              pl.BlockSpec((1, block_q, d), q_of_kv),
+              pl.BlockSpec((1, block_k, d), kv_of_kv),
+              pl.BlockSpec((1, block_k, d), kv_of_kv),
+              pl.BlockSpec((1, block_q, d), q_of_kv),
+              pl.BlockSpec((1, 8, block_q), row_of_kv),
+              pl.BlockSpec((1, 8, block_q), row_of_kv),
+          ],
+          out_specs=[
+              pl.BlockSpec((1, block_k, d), kv_of_kv),
+              pl.BlockSpec((1, block_k, d), kv_of_kv),
+          ],
+          scratch_shapes=[
+              pltpu.VMEM((block_k, d), jnp.float32),
+              pltpu.VMEM((block_k, d), jnp.float32),
+          ],
+      ),
       out_shape=[
           jax.ShapeDtypeStruct(k.shape, k.dtype),
           jax.ShapeDtypeStruct(v.shape, v.dtype),
       ],
-      scratch_shapes=[
-          pltpu.VMEM((block_k, d), jnp.float32),
-          pltpu.VMEM((block_k, d), jnp.float32),
-      ],
       interpret=interpret,
       name='flash_attention_bwd_dkv',
-  )(q, k, v, d_out, lse8, delta8)
+  )(*kv_table, q, k, v, d_out, lse8, delta8)
 
+  q_of_q = lambda b, t, qs, ks, flags: (b, qs[t], 0)
+  row_of_q = lambda b, t, qs, ks, flags: (b, 0, qs[t])
+  kv_of_q = lambda b, t, qs, ks, flags: (kv(b), ks[t], 0)
   q_kernel = functools.partial(
       _flash_bwd_q_kernel, scale=scale, causal=causal, block_q=block_q,
       block_k=block_k, window=window, diffusion=diffusion)
   dq = pl.pallas_call(
       q_kernel,
-      grid=(bh, n_q, n_k),
-      in_specs=[
-          pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-          pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-          pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-          pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-      ],
-      out_specs=[
-          pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-      ],
+      grid_spec=pltpu.PrefetchScalarGridSpec(
+          num_scalar_prefetch=3,
+          grid=(bh, q_table.shape[1]),
+          in_specs=[
+              pl.BlockSpec((1, block_q, d), q_of_q),
+              pl.BlockSpec((1, block_k, d), kv_of_q),
+              pl.BlockSpec((1, block_k, d), kv_of_q),
+              pl.BlockSpec((1, block_q, d), q_of_q),
+              pl.BlockSpec((1, 8, block_q), row_of_q),
+              pl.BlockSpec((1, 8, block_q), row_of_q),
+          ],
+          out_specs=[pl.BlockSpec((1, block_q, d), q_of_q)],
+          scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+      ),
       out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-      scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
       interpret=interpret,
       name='flash_attention_bwd_dq',
-  )(q, k, v, d_out, lse8, delta8)[0]
+  )(*q_table[1:], q, k, v, d_out, lse8, delta8)[0]
   return dq, dk, dv
 
 
@@ -806,8 +839,8 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, block_q_bwd,
   """Pallas FlashAttention-2 backward (see _flash_bwd_pallas).
 
   Until round 4 this was an XLA lax.scan recompute; it is now the same
-  kernel family as the forward, with causal block skip and its own block
-  sizes (_bwd_default_blocks — the forward's 1024 would 4x the
+  kernel family as the forward, over the tiles the mask keeps and with its
+  own block sizes (_bwd_default_blocks — the forward's 1024 would 4x the
   backward's VMEM working set and OOM the L=32k case)."""
   q, k, v, out, lse = residuals
   l_q = q.shape[1]
@@ -815,9 +848,6 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, block_q_bwd,
   default_bq, default_bk = _bwd_default_blocks(l_q, l_k)
   bq = _dividing_block_or_raise(min(block_q_bwd or default_bq, l_q), l_q)
   bk = _dividing_block_or_raise(min(block_k_bwd or default_bk, l_k), l_k)
-  if causal or diffusion is not None:
-    _set_pair_gauges('_bwd', q.shape[0], l_q, l_k, bq, bk, causal, window,
-                     diffusion)
   dq, dk, dv = _flash_bwd_pallas(
       q, k, v, out, lse, d_out, scale=scale, causal=causal,
       block_q=bq, block_k=bk, interpret=interpret, window=window,
@@ -840,10 +870,12 @@ def flash_attention(q, k, v,
                     block_diffusion: Optional[Tuple[int, int]] = None):
   """Exact attention over [B, L, H, D] inputs, O(L) memory, differentiable.
 
-  Forward runs the Pallas kernel (k-outer/q-inner tiled sweep, see
-  _flash_kernel); the backward is the blockwise FlashAttention
-  recomputation (custom VJP) so training never sees an [L, L] tensor
-  either. Blocks step down automatically to sizes dividing L.
+  Forward runs the Pallas kernel (_flash_kernel); the backward is the
+  blockwise FlashAttention recomputation (custom VJP, two more kernels) so
+  training never sees an [L, L] tensor either. The grid of each kernel is
+  the list of the tiles the mask keeps (``tile_table``; the module
+  docstring has its order), every tile of the rectangle for an unmasked
+  call. Blocks step down automatically to sizes dividing L.
   ``interpret=None`` auto-selects the Pallas interpreter off-TPU so
   tests run on CPU.
 
@@ -851,8 +883,8 @@ def flash_attention(q, k, v,
   ([B, L, H/group, D]); query head n reads key/value head n // group.
   The kernels index the shared k/v blocks, nothing is repeated in HBM,
   and dk/dv come back summed over the group. ``window`` (causal only)
-  keeps, for row i, the columns j with ``0 <= i - j < window``; blocks
-  that lie wholly outside that band are skipped in all three kernels.
+  keeps, for row i, the columns j with ``0 <= i - j < window``; tiles
+  that lie wholly outside that band are in no kernel's grid.
   ``window=None`` with equal head counts is the plain causal (or full)
   attention the kernels always computed.
 
@@ -866,13 +898,15 @@ def flash_attention(q, k, v,
     clean  i, clean  j:  blk(j) <= blk(i)     block-causal
     clean  i, noised j:  never
 
-  It keeps length^2 + length x block of the 4 length^2 pairs; all three
-  kernels skip the tiles that hold none (``_block_in_block_diffusion``).
+  It keeps length^2 + length x block of the 4 length^2 pairs; the tiles
+  that hold none (``_block_in_block_diffusion``) are in no kernel's grid.
 
   A masked call sets, on the host while it is traced, the gauges
-  ``attention/mask_pairs_needed`` and ``attention/mask_pairs_computed``
-  (the forward kernel's tiles; ``..._computed_bwd`` each backward
-  kernel's), all heads of the call.
+  ``attention/mask_pairs_needed``, ``attention/mask_pairs_computed`` (the
+  forward kernel's tiles; ``..._computed_bwd`` each backward kernel's) and
+  ``attention/grid_steps`` (the steps the forward kernel launches;
+  ``..._steps_bwd`` each backward kernel's), all heads of the call: steps
+  x tile = pairs computed, every step computes.
 
   Default block sizes come from v5e sweeps (B=1, H=8, D=128, causal,
   chained on-device timing): (1024, 1024) — grid-step count (fixed
@@ -929,9 +963,6 @@ def flash_attention(q, k, v,
       x = jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
     return x
 
-  if causal or block_diffusion is not None:
-    _set_pair_gauges('', b * h, l_q, l_k, block_q, block_k, causal, window,
-                     block_diffusion)
   out = _flash_diff(_to_bhld(q), _to_bhld(k), _to_bhld(v), causal, scale,
                     block_q, block_k, interpret, block_q_bwd, block_k_bwd,
                     window, block_diffusion)

@@ -277,20 +277,164 @@ class TestBlockDiffusionMask:
   ])
   def test_the_gauges_count_the_mask_and_the_tiles(self, causal, window,
                                                    diffusion, pairs, tiles):
+    """... and the grid steps: every kernel launches the tiles it computes
+    and no other, so steps x tile = pairs computed, forward and backward."""
     from tensor2robot_tpu.observability import get_registry
 
     assert flash_lib.mask_pairs(64, 64, causal, window, diffusion) == pairs
     assert flash_lib.tiles_computed(4, 4, 16, 16, causal, window,
                                     diffusion) == tiles
+    tiles_bwd = flash_lib.tiles_computed(4, 2, 16, 32, causal, window,
+                                         diffusion)
     q, k, v = self._qkv()
-    jax.make_jaxpr(lambda q: flash_attention(
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q: jnp.sum(flash_attention(
         q, k, v, causal=causal, window=window, block_diffusion=diffusion,
-        block_q=16, block_k=16))(q)
-    registry = get_registry()
-    assert registry.gauge('attention/mask_pairs_needed').value == 4 * pairs
-    assert registry.gauge('attention/mask_pairs_computed').value == \
-        4 * tiles * 256
+        block_q=16, block_k=16, block_q_bwd=16, block_k_bwd=32))))(q)
+    value = lambda name: get_registry().gauge('attention/' + name).value
+    assert value('mask_pairs_needed') == 4 * pairs
+    assert value('mask_pairs_computed') == 4 * tiles * 256
+    assert value('grid_steps') == 4 * tiles
+    assert value('mask_pairs_computed_bwd') == 4 * tiles_bwd * 512
+    assert value('grid_steps_bwd') == 4 * tiles_bwd
+    # The grids themselves: (heads, steps); dk/dv's runs over the 2 k/v
+    # heads, each k/v block followed by both query heads of its group.
+    grids = {eqn.params['name']: eqn.params['grid_mapping'].grid
+             for eqn in jaxpr.eqns if eqn.primitive.name == 'pallas_call'}
+    assert grids == {'flash_attention_fwd': (4, tiles),
+                     'flash_attention_bwd_dkv': (2, 2 * tiles_bwd),
+                     'flash_attention_bwd_dq': (4, tiles_bwd)}
 
   def test_an_unmasked_call_sets_no_gauge(self):
     assert flash_lib.mask_pairs(64, 48, False, None, None) == 64 * 48
     assert flash_lib.tiles_computed(4, 3, 16, 16, False, None, None) == 12
+
+
+def _dense_mask(l_q, l_k, causal, window, diffusion):
+  """[l_q, l_k] bool: the mask as the kernels count it. Under ``causal``
+  both sides count positions from their first (row i sees columns j <= i),
+  also where the lengths differ."""
+  if diffusion is not None:
+    return np.asarray(flash_lib.block_diffusion_mask(*diffusion))
+  q_pos, k_pos = np.arange(l_q)[:, None], np.arange(l_k)[None, :]
+  mask = np.ones((l_q, l_k), bool)
+  if causal:
+    mask &= q_pos >= k_pos
+  if window is not None:
+    mask &= q_pos - k_pos < window
+  return mask
+
+
+# name: (l_q, l_k, causal, window, diffusion)
+MASKS = {
+    'causal': (256, 256, True, None, None),
+    'window': (256, 256, True, 40, None),
+    'block diffusion': (256, 256, False, None, (128, 4)),
+    'causal, more keys than queries': (64, 256, True, None, None),
+}
+# name: (k_resident, group)
+GRIDS = {'forward and dq': (False, 1), 'dk/dv': (True, 1),
+         'dk/dv of 4 query heads': (True, 4)}
+
+
+class TestTileTable:
+  """``tile_table``: the grid of each kernel is the list of tiles its mask
+  keeps, in the order that forms every sum as the rectangular sweep did."""
+
+  @pytest.mark.parametrize('block_q, block_k', [(32, 32), (16, 64)])
+  @pytest.mark.parametrize('grid', list(GRIDS))
+  @pytest.mark.parametrize('mask', list(MASKS))
+  def test_every_needed_tile_once_in_order_with_its_flags(self, mask, grid,
+                                                          block_q, block_k):
+    l_q, l_k, causal, window, diffusion = MASKS[mask]
+    k_resident, group = GRIDS[grid]
+    n_q, n_k = l_q // block_q, l_k // block_k
+    head, qs, ks, flags = flash_lib.tile_table(
+        n_q, n_k, block_q, block_k, causal, window, diffusion,
+        k_resident=k_resident, group=group)
+    # The oracle: a tile is needed when the dense mask keeps a pair of it.
+    needed = _dense_mask(l_q, l_k, causal, window, diffusion).reshape(
+        n_q, block_q, n_k, block_k).any(axis=(1, 3))
+    want = {(h, i, j) for h in range(group) for i, j in np.argwhere(needed).tolist()}
+    # An accumulator the mask leaves empty gets one step of its own.
+    empty = np.flatnonzero(~needed.any(axis=0 if k_resident else 1))
+    want |= {(0, 0, e) if k_resident else (0, e, 0) for e in empty.tolist()}
+    steps = list(zip(head.tolist(), qs.tolist(), ks.tolist()))
+    assert len(steps) == len(set(steps)) and set(steps) == want
+    assert len(steps) == group * flash_lib.tiles_computed(
+        n_q, n_k, block_q, block_k, causal, window, diffusion) + len(empty)
+    assert bool(len(empty)) == (mask == 'causal, more keys than queries'
+                                and k_resident)
+    # Order: the accumulator's steps together, the streamed side ascending
+    # (k within a q block; query head, then q block, within a k/v block).
+    order = [(j, h, i) if k_resident else (i, j) for h, i, j in steps]
+    assert order == sorted(order)
+    resident = [o[0] for o in order]
+    for t, flag in enumerate(flags.tolist()):
+      first = t == 0 or resident[t - 1] != resident[t]
+      last = t == len(steps) - 1 or resident[t + 1] != resident[t]
+      assert flag == flash_lib.FIRST * first + flash_lib.LAST * last
+
+  @pytest.mark.parametrize(
+      'n_q, n_k, block_q, block_k, causal, window, diffusion, steps', [
+          # The third cell: 16,384 positions, forward and backward blocks.
+          (16, 16, 1024, 1024, False, None, (8192, 4), 80),
+          (32, 16, 512, 1024, False, None, (8192, 4), 160),
+          # The second cell: a full layer and a window-4096 layer at 8,192.
+          (8, 8, 1024, 1024, True, None, None, 36),
+          (8, 8, 1024, 1024, True, 4096, None, 30),
+          (16, 8, 512, 1024, True, None, None, 72),
+          (16, 8, 512, 1024, True, 4096, None, 60),
+      ])
+  def test_the_cells_launch_what_they_compute(self, n_q, n_k, block_q,
+                                              block_k, causal, window,
+                                              diffusion, steps):
+    mask = (n_q, n_k, block_q, block_k, causal, window, diffusion)
+    assert flash_lib.tiles_computed(*mask) == steps
+    assert flash_lib.tile_table(*mask).shape == (4, steps)
+    assert flash_lib.tile_table(*mask, k_resident=True,
+                                group=7).shape == (4, 7 * steps)
+
+  def test_an_unmasked_call_launches_the_rectangle(self):
+    head, qs, ks, _ = flash_lib.tile_table(3, 2, 16, 16, False, None, None)
+    assert list(zip(qs.tolist(), ks.tolist())) == [
+        (i, j) for i in range(3) for j in range(2)] and not head.any()
+
+
+class TestMostlySkippedGrids:
+  """Outputs and all three gradients against a dense oracle under the
+  kernels' own mask, grouped heads, at sizes where the mask drops most of
+  the rectangle and a q block's k blocks are not neighbours."""
+
+  @pytest.mark.parametrize('l_q, l_k, causal, window, diffusion', [
+      (512, 512, False, None, (256, 4)),   # 24 of 64 tiles; q block 1: k 1, 4, 5
+      (512, 512, True, 70, None),          # 22 of 64
+      (256, 256, True, None, None),        # 10 of 16
+      (128, 256, True, None, None),        # k/v blocks 2 and 3: no tile at all
+  ], ids=['block diffusion', 'window', 'causal',
+          'causal, k/v blocks without a tile'])
+  def test_outputs_and_gradients_match_the_dense_mask(self, l_q, l_k, causal,
+                                                      window, diffusion):
+    key = jax.random.PRNGKey(l_q + l_k)
+    q = jax.random.normal(key, (1, l_q, 4, 16))
+    k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, l_k, 2, 16))
+            for i in (1, 2))
+    mask = jnp.asarray(_dense_mask(l_q, l_k, causal, window, diffusion))
+
+    def dense(q, k, v):
+      k, v = (jnp.repeat(x, 2, axis=2) for x in (k, v))
+      scores = jnp.einsum('bqhd,bkhd->bhqk', q, k) / 4.0
+      probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+      return jnp.einsum('bhqk,bkhd->bqhd', probs, v)
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, window=window, block_diffusion=diffusion,
+        block_q=64, block_k=64, block_q_bwd=64, block_k_bwd=64)
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-6)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, 'qkv'):
+      np.testing.assert_allclose(g, w, atol=1e-5, err_msg='d' + name)
+    if l_k > l_q:   # the keys no query sees get a gradient of exactly zero
+      assert not np.any(np.asarray(got[1])[:, l_q:]) and not np.any(
+          np.asarray(got[2])[:, l_q:])
