@@ -245,6 +245,36 @@ def route_top_k(router_logits: jnp.ndarray, top_k: int):
   return index.astype(jnp.int32), jax.nn.softmax(values, axis=-1)
 
 
+def route_sigmoid_bias(router_logits: jnp.ndarray, bias: jnp.ndarray,
+                       top_k: int):
+  """(expert index [T, k] int32, weight [T, k] f32) of a sigmoid router with
+  a selection bias (auxiliary-loss-free balancing: DeepSeek-V3, LFM2): every
+  expert scores s = sigmoid(logit) on its own; the ``top_k`` largest of
+  s + ``bias`` [E] are CHOSEN, and weigh s / (sum of the chosen s + 1e-6).
+  The bias chooses and never weighs; no gradient reaches it (it is state,
+  moved by ``balanced_bias``)."""
+  scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+  _, index = jax.lax.top_k(
+      scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+  chosen = jnp.take_along_axis(scores, index, axis=-1)
+  weight = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
+  return index.astype(jnp.int32), weight
+
+
+def expert_counts(expert_index: jnp.ndarray, num_experts: int):
+  """[E] f32: the pairs that chose each expert (no scatter: a comparison
+  with every expert's index, summed)."""
+  chose = expert_index.reshape(-1, 1) == jnp.arange(num_experts)[None, :]
+  return jnp.sum(chose, axis=0, dtype=jnp.float32)
+
+
+def balanced_bias(bias: jnp.ndarray, counts: jnp.ndarray, rate: float):
+  """The selection bias after one step of the auxiliary-loss-free rule
+  (DeepSeek-V3, arXiv:2412.19437 section 2.1.2): an expert that got fewer
+  pairs than the mean rises by ``rate``, one that got more falls by it."""
+  return bias + rate * jnp.sign(jnp.mean(counts) - counts)
+
+
 def buffer_rows(tokens: int, top_k: int, held: int, block_rows: int) -> int:
   """Rows that hold ANY routing of ``tokens`` tokens: every token's
   min(top_k, held) pairs, plus each expert's padding to whole tiles."""
@@ -412,12 +442,13 @@ class DroplessMoE(nn.Module):
 
   ``num_experts`` is the router's width (all the model's experts);
   ``experts_held`` = (first index, count) says which of them live here.
-  ``__call__(u, router_logits)`` takes the logits from the caller, because
-  where the router reads is the block's business (before attention in the
-  model this was written for). Experts are gated: ``(act(u Wg) * (u Wu))
-  Wd``, no bias, ``act`` the ``gate_activation``: ``'relu'`` (ReGLU) or
-  ``'silu'`` (SwiGLU). Weights are f32 parameters, products run in
-  ``dtype``.
+  ``__call__(u, routing)`` takes the ROUTING from the caller, a pair
+  (expert index [T, k] int32, weight [T, k] f32), because where the router
+  reads, how it scores, what it selects by and what it weighs by are the
+  block's business (``route_top_k``, ``route_sigmoid_bias``). Experts are
+  gated: ``(act(u Wg) * (u Wu)) Wd``, no bias, ``act`` the
+  ``gate_activation``: ``'relu'`` (ReGLU) or ``'silu'`` (SwiGLU). Weights
+  are f32 parameters, products run in ``dtype``.
 
   Returns ``(y, stats)``; ``stats`` are scalars of this call:
   ``pairs_held`` (pairs computed here), ``load_max_over_mean`` (largest
@@ -432,14 +463,13 @@ class DroplessMoE(nn.Module):
   num_experts: int
   experts_held: Tuple[int, int]
   expert_dim: int
-  top_k: int
   gate_activation: str = 'relu'
   block_rows: int = 256
   down_init_std: float = 0.02   # of w_down, which writes into the residual
   dtype: jnp.dtype = jnp.float32
 
   @nn.compact
-  def __call__(self, u: jnp.ndarray, router_logits: jnp.ndarray):
+  def __call__(self, u: jnp.ndarray, routing):
     d = u.shape[1]
     first, held = self.experts_held
     if not 0 <= first <= first + held <= self.num_experts or held < 1:
@@ -449,7 +479,6 @@ class DroplessMoE(nn.Module):
       raise ValueError('gate_activation {!r} is none of {}.'.format(
           self.gate_activation, sorted(GATE_ACTIVATIONS)))
     activation = GATE_ACTIVATIONS[self.gate_activation]
-    k = min(self.top_k, self.num_experts)
     init = nn.initializers.normal(0.02)
     w_gate = self.param('w_gate', init, (held, d, self.expert_dim),
                         jnp.float32)
@@ -457,8 +486,7 @@ class DroplessMoE(nn.Module):
     w_down = self.param('w_down', nn.initializers.normal(self.down_init_std),
                         (held, self.expert_dim, d), jnp.float32)
 
-    with jax.named_scope('moe_route'):
-      expert_index, weight = route_top_k(router_logits, k)
+    expert_index, weight = routing
     with jax.named_scope('moe_group'):
       layout = group_pairs(expert_index, first, held, self.block_rows)
       del layout['row_pair']   # the inverse map: no kernel reads it

@@ -29,6 +29,7 @@ causal mask the kernels implement.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -412,19 +413,94 @@ class GroupedQueryAttention(nn.Module):
                     name='out')(out.reshape(b, l, -1))
 
 
+class ShortConvolution(nn.Module):
+  """A gated short convolution, the token mixer that is not attention:
+
+    [B, C, X] = h W_in;  z = B * X;  c_t = sum_i w[:, i] z_{t-2+i};
+    y = (C * c) W_out
+
+  ``in_proj`` d -> 3 d (the three chunks in that order), a depthwise causal
+  filter of three taps a channel (``filter`` [d, 3], the last tap on the
+  current token, zeros before a sequence's first token, nothing across
+  batch rows), ``out_proj`` d -> d; no bias, no positions. The core between
+  the two projections is ``parallel/short_conv.py``: one Pallas kernel pair
+  on the TPU, the same mathematics in ``jax.numpy`` elsewhere."""
+
+  out_init_std: float = 0.02    # of `out_proj`, which writes into the stream
+  dtype: jnp.dtype = jnp.float32
+
+  @nn.compact
+  def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
+    from tensor2robot_tpu.parallel import short_conv as short_conv_lib
+
+    d = h.shape[-1]
+    taps = short_conv_lib.TAPS
+    bound = 1.0 / taps ** 0.5      # torch.nn.Conv1d's default for a fan-in of 3
+    taps_init = lambda key, shape, dtype: jax.random.uniform(
+        key, shape, dtype, -bound, bound)
+    weights = self.param('filter', taps_init, (d, taps), jnp.float32)
+    bcx = nn.Dense(3 * d, use_bias=False, dtype=self.dtype,
+                   kernel_init=nn.initializers.normal(0.02),
+                   name='in_proj')(h)
+    with jax.named_scope('short_conv'):
+      y = short_conv_lib.short_conv(bcx, weights)
+    return nn.Dense(d, use_bias=False, dtype=self.dtype,
+                    kernel_init=nn.initializers.normal(self.out_init_std),
+                    name='out_proj')(y)
+
+
+class GatedMLP(nn.Module):
+  """One dense SwiGLU: ``(silu(u W1) * (u W3)) W2``, no bias."""
+
+  width: int
+  down_init_std: float = 0.02   # of `w2`, which writes into the stream
+  dtype: jnp.dtype = jnp.float32
+
+  @nn.compact
+  def __call__(self, u: jnp.ndarray) -> jnp.ndarray:
+    dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+    init = nn.initializers.normal(0.02)
+    hidden = nn.silu(dense(self.width, kernel_init=init, name='w1')(u)) * \
+        dense(self.width, kernel_init=init, name='w3')(u)
+    return dense(u.shape[-1],
+                 kernel_init=nn.initializers.normal(self.down_init_std),
+                 name='w2')(hidden)
+
+
 class MoEBlock(nn.Module):
-  """Pre-norm block of grouped-query attention and routed experts:
+  """Pre-norm block of a token mixer and a feed-forward:
 
-    x1 = x + attn(rmsnorm(x));  u = rmsnorm(x1);  out = x1 + moe(u, r)
+    x1 = x + mixer(rmsnorm(x));  u = rmsnorm(x1);  out = x1 + ff(u)
 
-  whose router logits r (f32) read what ``router_reads`` says: ``'input'``,
-  the block's INPUT before attention, r = x W_r (the router-first block),
-  or ``'normed'``, the normed post-attention stream, r = u W_r (the usual
-  place). That is the one difference between the two; the other fields
-  are the attention's (window, rotary positions, q/k norm, the
-  block-diffusion mask) and the dropless expert layer's, which is told
-  which experts it holds (layers/moe.py::DroplessMoE) and how its gate is
-  activated. Returns (out, the expert layer's stats)."""
+  Both are FIELDS. ``mixer``: ``'attention'`` (grouped-query attention:
+  window, rotary positions, q/k norm, the block-diffusion mask) or
+  ``'short_conv'`` (``ShortConvolution``; it takes no positions).
+  ``feed_forward``: ``'experts'``, the dropless routed experts, which are
+  told which experts they hold (layers/moe.py::DroplessMoE) and how their
+  gate is activated, or ``'dense'``, one SwiGLU of width ``dense_dim``.
+
+  With experts, the router's logits r (f32) read what ``router_reads``
+  says: ``'input'``, the block's INPUT before the mixer, r = x W_r (the
+  router-first block), or ``'normed'``, the normed post-mixer stream,
+  r = u W_r (the usual place); and ``router`` says what is made of them:
+  ``'softmax'`` (the ``top_k`` largest logits, softmax over just those) or
+  ``'sigmoid_bias'`` (``route_sigmoid_bias``: the largest sigmoid + bias are
+  chosen, the sigmoids alone weigh). The bias [num_experts] f32 is no
+  parameter: it lives in the collection ``router_state`` and, where that
+  collection is mutable (training), leaves the call moved by
+  ``balanced_bias`` on this call's own counts at ``router_bias_rate``;
+  under ``nn.remat`` the backward pass computes the block again from the
+  bias it was GIVEN and its update is dropped, so a step applies the rule
+  once.
+
+  Returns (out, the feed-forward's stats): the expert layer's, with
+  ``chosen_load_max_over_mean`` (the most chosen of ALL experts over the
+  mean: what the bias balances) and ``router_bias_abs_mean`` (of the bias
+  as the call leaves it; 0 under a softmax router); {} for a dense one.
+
+  The norms keep the names of the first block of this class (``norm_attn``
+  before the mixer, ``norm_moe`` before the feed-forward) whatever the two
+  fields say, so that parameter trees written before the fields read on."""
 
   num_heads: int
   num_kv_heads: int
@@ -436,7 +512,12 @@ class MoEBlock(nn.Module):
   window: Optional[int] = None
   rope_theta: Optional[float] = None
   eps: float = 1e-6
+  mixer: str = 'attention'
+  feed_forward: str = 'experts'
+  dense_dim: Optional[int] = None
   router_reads: str = 'input'
+  router: str = 'softmax'
+  router_bias_rate: float = 1e-3
   qk_norm: bool = False
   block_diffusion: Optional[Tuple[int, int]] = None
   gate_activation: str = 'relu'
@@ -445,42 +526,79 @@ class MoEBlock(nn.Module):
   residual_init_std: float = 0.02  # of the two matrices that write into x
   dtype: jnp.dtype = jnp.float32
 
+  def _route(self, router_logits):
+    """((expert index, weight), the router's two stats)."""
+    from tensor2robot_tpu.layers import moe as moe_lib
+
+    k = min(self.top_k, self.num_experts)
+    with jax.named_scope('moe_route'):
+      if self.router == 'softmax':
+        bias = None
+        routing = moe_lib.route_top_k(router_logits, k)
+      else:
+        bias = self.variable('router_state', 'bias', jnp.zeros,
+                             (self.num_experts,), jnp.float32)
+        routing = moe_lib.route_sigmoid_bias(router_logits, bias.value, k)
+      counts = moe_lib.expert_counts(routing[0], self.num_experts)
+      if (bias is not None and self.is_mutable_collection('router_state')
+          and not self.is_initializing()):
+        bias.value = moe_lib.balanced_bias(bias.value, counts,
+                                           self.router_bias_rate)
+      return routing, {
+          'chosen_load_max_over_mean':
+              counts.max() / jnp.maximum(counts.mean(), 1.0),
+          'router_bias_abs_mean': jnp.float32(0) if bias is None else
+                                  jnp.mean(jnp.abs(bias.value))}
+
   @nn.compact
   def __call__(self, x: jnp.ndarray,
                positions: Optional[jnp.ndarray] = None):
     from tensor2robot_tpu.layers.moe import DroplessMoE
 
-    if self.router_reads not in ('input', 'normed'):
-      raise ValueError('router_reads {!r} is neither \'input\' nor '
-                       '\'normed\'.'.format(self.router_reads))
+    for field, allowed in (('router_reads', ('input', 'normed')),
+                           ('mixer', ('attention', 'short_conv')),
+                           ('feed_forward', ('experts', 'dense')),
+                           ('router', ('softmax', 'sigmoid_bias'))):
+      if getattr(self, field) not in allowed:
+        raise ValueError('{} {!r} is neither {!r} nor {!r}.'.format(
+            field, getattr(self, field), *allowed))
     b, l, d = x.shape
-    router = nn.Dense(
-        self.num_experts, use_bias=False, dtype=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-        kernel_init=nn.initializers.normal(0.02), name='router')
-    if self.router_reads == 'input':
-      router_logits = router(x.astype(jnp.float32))
+    experts = self.feed_forward == 'experts'
+    if experts:
+      router = nn.Dense(
+          self.num_experts, use_bias=False, dtype=jnp.float32,
+          precision=jax.lax.Precision.HIGHEST,
+          kernel_init=nn.initializers.normal(0.02), name='router')
+      if self.router_reads == 'input':
+        router_logits = router(x.astype(jnp.float32))
     h = RMSNorm(self.eps, name='norm_attn')(x).astype(self.dtype)
-    x = x + GroupedQueryAttention(
-        num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
-        head_dim=self.head_dim, window=self.window,
-        rope_theta=self.rope_theta, qk_norm=self.qk_norm, eps=self.eps,
-        block_diffusion=self.block_diffusion,
-        attention_mode=self.attention_mode,
-        out_init_std=self.residual_init_std, dtype=self.dtype,
-        name='attn')(h, positions)
+    if self.mixer == 'attention':
+      x = x + GroupedQueryAttention(
+          num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+          head_dim=self.head_dim, window=self.window,
+          rope_theta=self.rope_theta, qk_norm=self.qk_norm, eps=self.eps,
+          block_diffusion=self.block_diffusion,
+          attention_mode=self.attention_mode,
+          out_init_std=self.residual_init_std, dtype=self.dtype,
+          name='attn')(h, positions)
+    else:
+      x = x + ShortConvolution(out_init_std=self.residual_init_std,
+                               dtype=self.dtype, name='conv')(h)
     u = RMSNorm(self.eps, name='norm_moe')(x)
+    if not experts:
+      y = GatedMLP(self.dense_dim, down_init_std=self.residual_init_std,
+                   dtype=self.dtype, name='mlp')(u.astype(self.dtype))
+      return x + y.astype(x.dtype), {}
     if self.router_reads == 'normed':
       router_logits = router(u)
+    routing, router_stats = self._route(router_logits.reshape(b * l, -1))
     y, stats = DroplessMoE(
         num_experts=self.num_experts, experts_held=tuple(self.experts_held),
-        expert_dim=self.expert_dim, top_k=self.top_k,
-        gate_activation=self.gate_activation,
+        expert_dim=self.expert_dim, gate_activation=self.gate_activation,
         block_rows=self.moe_block_rows,
         down_init_std=self.residual_init_std, dtype=self.dtype, name='moe')(
-            u.astype(self.dtype).reshape(b * l, d),
-            router_logits.reshape(b * l, -1))
-    return x + y.reshape(b, l, d).astype(x.dtype), stats
+            u.astype(self.dtype).reshape(b * l, d), routing)
+    return x + y.reshape(b, l, d).astype(x.dtype), dict(stats, **router_stats)
 
 
 class TokenLearner(nn.Module):

@@ -261,11 +261,11 @@ class TestDroplessGateAndShares:
     logits = jax.random.normal(jax.random.PRNGKey(5), (tokens, experts))
     layer = moe_lib.DroplessMoE(num_experts=experts,
                                 experts_held=(0, experts), expert_dim=width,
-                                top_k=8, gate_activation='silu',
-                                block_rows=8)
+                                gate_activation='silu', block_rows=8)
     params = jax.tree.map(
         lambda x: 20 * x,
-        layer.init(jax.random.PRNGKey(6), u, logits)['params'])
+        layer.init(jax.random.PRNGKey(6), u,
+                   moe_lib.route_top_k(logits, 8))['params'])
     return moe_lib, u, logits, params
 
   @staticmethod
@@ -285,13 +285,14 @@ class TestDroplessGateAndShares:
   def test_the_gate_is_activated_as_the_field_says(self, name, activation):
     moe_lib, u, logits, params = self._inputs(8)
     layer = moe_lib.DroplessMoE(num_experts=8, experts_held=(0, 8),
-                                expert_dim=8, top_k=8, gate_activation=name,
+                                expert_dim=8, gate_activation=name,
                                 block_rows=8)
-    y, _ = layer.apply({'params': params}, u, logits)
+    route = lambda logits: moe_lib.route_top_k(logits, 8)
+    y, _ = layer.apply({'params': params}, u, route(logits))
     np.testing.assert_allclose(
         y, self._dense(params, u, logits, 0, 8, 8, activation), atol=2e-5)
     got = jax.grad(lambda p: jnp.sum(jnp.sin(
-        layer.apply({'params': p}, u, logits)[0])))(params)
+        layer.apply({'params': p}, u, route(logits))[0])))(params)
     want = jax.grad(lambda p: jnp.sum(jnp.sin(
         self._dense(p, u, logits, 0, 8, 8, activation))))(params)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
@@ -299,17 +300,18 @@ class TestDroplessGateAndShares:
 
   def test_relu_is_the_default_and_the_two_differ(self):
     moe_lib, u, logits, params = self._inputs(8)
-    kwargs = dict(num_experts=8, experts_held=(0, 8), expert_dim=8, top_k=8,
+    kwargs = dict(num_experts=8, experts_held=(0, 8), expert_dim=8,
                   block_rows=8)
+    routing = moe_lib.route_top_k(logits, 8)
     assert moe_lib.DroplessMoE(**kwargs).gate_activation == 'relu'
     relu, _ = moe_lib.DroplessMoE(**kwargs).apply({'params': params}, u,
-                                                  logits)
+                                                  routing)
     silu, _ = moe_lib.DroplessMoE(gate_activation='silu', **kwargs).apply(
-        {'params': params}, u, logits)
+        {'params': params}, u, routing)
     assert float(jnp.max(jnp.abs(relu - silu))) > 1e-3
     with pytest.raises(ValueError, match='gate_activation'):
       moe_lib.DroplessMoE(gate_activation='gelu', **kwargs).apply(
-          {'params': params}, u, logits)
+          {'params': params}, u, routing)
 
   def test_the_eight_shares_of_sixteen_add_up_to_the_uncut_layer(self):
     moe_lib, u, logits, params = self._inputs(128)
@@ -318,9 +320,9 @@ class TestDroplessGateAndShares:
     for first in range(0, 128, 16):
       share = jax.tree.map(lambda x: x[first:first + 16], params)
       y, stats = moe_lib.DroplessMoE(
-          num_experts=128, experts_held=(first, 16), expert_dim=8, top_k=8,
+          num_experts=128, experts_held=(first, 16), expert_dim=8,
           gate_activation='silu', block_rows=8).apply(
-              {'params': share}, u, logits)
+              {'params': share}, u, moe_lib.route_top_k(logits, 8))
       total = total + y
       pairs += float(stats['pairs_held'])
       assert float(stats['dropped_pairs']) == 0

@@ -235,11 +235,13 @@ class TestTheLayerReadsNothingItDidNotWrite:
     u = jax.random.normal(jax.random.PRNGKey(4), (40, 16))
     logits = jax.random.normal(jax.random.PRNGKey(5), (40, 8))
     layer = moe_lib.DroplessMoE(num_experts=8, experts_held=(first, held),
-                                expert_dim=12, top_k=3, block_rows=8)
+                                expert_dim=12, block_rows=8)
+    route = lambda logits: moe_lib.route_top_k(logits, 3)
     params = jax.tree.map(
-        lambda x: 20 * x, layer.init(jax.random.PRNGKey(6), u, logits)['params'])
+        lambda x: 20 * x,
+        layer.init(jax.random.PRNGKey(6), u, route(logits))['params'])
     del poisoned[:]
-    y, stats = layer.apply({'params': params}, u, logits)
+    y, stats = layer.apply({'params': params}, u, route(logits))
     assert poisoned.count('past') == 3 and poisoned.count('unowned') == 1
     np.testing.assert_allclose(
         y, _dense_experts(params, u, logits, first, held, 3), atol=1e-5)
@@ -250,7 +252,7 @@ class TestTheLayerReadsNothingItDidNotWrite:
     assert tiles * 8 < moe_lib.buffer_rows(40, 3, held, 8)
     del poisoned[:]
     got = jax.grad(lambda *a: jnp.sum(jnp.sin(
-        layer.apply({'params': a[0]}, a[1], a[2])[0])), (0, 1, 2))(
+        layer.apply({'params': a[0]}, a[1], route(a[2]))[0])), (0, 1, 2))(
             params, u, logits)
     # Forward: one take, two products, one sum. Backward: the combine's take
     # and row-dot, two products to the left (the two to the weights have no

@@ -319,6 +319,10 @@ def _dense_experts(params, u, router_logits, first, held, top_k):
   return y
 
 
+def _route(logits):
+  return moe_lib.route_top_k(logits, 3)
+
+
 class TestDroplessLayer:
 
   @pytest.fixture(scope='class')
@@ -326,9 +330,10 @@ class TestDroplessLayer:
     u = jax.random.normal(jax.random.PRNGKey(4), (40, 16))
     logits = jax.random.normal(jax.random.PRNGKey(5), (40, 8))
     layer = moe_lib.DroplessMoE(num_experts=8, experts_held=(0, 8),
-                                expert_dim=8, top_k=3, block_rows=8)
-    params = jax.tree.map(lambda x: 20 * x,
-                          layer.init(jax.random.PRNGKey(6), u, logits)['params'])
+                                expert_dim=8, block_rows=8)
+    params = jax.tree.map(
+        lambda x: 20 * x,
+        layer.init(jax.random.PRNGKey(6), u, _route(logits))['params'])
     return u, logits, params
 
   @pytest.mark.parametrize('first, held', [(0, 8), (2, 4), (6, 2), (3, 1)])
@@ -337,13 +342,13 @@ class TestDroplessLayer:
     u, logits, params = layer_inputs
     params = jax.tree.map(lambda x: x[first:first + held], params)
     layer = moe_lib.DroplessMoE(num_experts=8, experts_held=(first, held),
-                                expert_dim=8, top_k=3, block_rows=8)
-    y, stats = layer.apply({'params': params}, u, logits)
+                                expert_dim=8, block_rows=8)
+    y, stats = layer.apply({'params': params}, u, _route(logits))
     want = _dense_experts(params, u, logits, first, held, 3)
     np.testing.assert_allclose(y, want, atol=1e-5)
     assert float(stats['dropped_pairs']) == 0
     got = jax.grad(lambda *a: jnp.sum(jnp.sin(
-        layer.apply({'params': a[0]}, a[1], a[2])[0])), (0, 1, 2))(
+        layer.apply({'params': a[0]}, a[1], _route(a[2]))[0])), (0, 1, 2))(
             params, u, logits)
     wanted = jax.grad(lambda *a: jnp.sum(jnp.sin(
         _dense_experts(a[0], a[1], a[2], first, held, 3))), (0, 1, 2))(
@@ -360,8 +365,8 @@ class TestDroplessLayer:
     for first in (0, 2, 4, 6):
       share = jax.tree.map(lambda x: x[first:first + 2], params)
       y, stats = moe_lib.DroplessMoE(
-          num_experts=8, experts_held=(first, 2), expert_dim=8, top_k=3,
-          block_rows=8).apply({'params': share}, u, logits)
+          num_experts=8, experts_held=(first, 2), expert_dim=8,
+          block_rows=8).apply({'params': share}, u, _route(logits))
       total = total + y
       pairs += float(stats['pairs_held'])
     np.testing.assert_allclose(total, whole, atol=1e-5)
@@ -372,8 +377,8 @@ class TestDroplessLayer:
     u, _, params = layer_inputs
     logits = jnp.tile(jnp.array([[5., 4, 3, 0, 0, 0, 0, 0]]), (40, 1))
     y, stats = moe_lib.DroplessMoE(
-        num_experts=8, experts_held=(0, 8), expert_dim=8, top_k=3,
-        block_rows=8).apply({'params': params}, u, logits)
+        num_experts=8, experts_held=(0, 8), expert_dim=8,
+        block_rows=8).apply({'params': params}, u, _route(logits))
     np.testing.assert_allclose(
         y, _dense_experts(params, u, logits, 0, 8, 3), atol=1e-5)
     assert float(stats['pairs_held']) == 120
@@ -391,8 +396,9 @@ class TestDroplessLayer:
   def test_experts_held_must_be_a_range_of_the_experts(self, layer_inputs):
     u, logits, _ = layer_inputs
     with pytest.raises(ValueError, match='experts_held'):
-      moe_lib.DroplessMoE(num_experts=8, experts_held=(6, 4), expert_dim=8,
-                          top_k=3).init(jax.random.PRNGKey(0), u, logits)
+      moe_lib.DroplessMoE(num_experts=8, experts_held=(6, 4),
+                          expert_dim=8).init(jax.random.PRNGKey(0), u,
+                                             _route(logits))
 
 
 class TestGroupedMatmulKernels:
