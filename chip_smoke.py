@@ -16,7 +16,7 @@ out by the repo's own means:
            path) answers 512x640 requests: none failed, every action finite
            and of the declared shape, no compile at request time; a second
            server then starts from the PERSISTED executable.
-  flash    ``parallel/flash_attention.py`` forward and both backward kernels
+  flash    ``parallel/flash_attention.py`` forward and the backward kernel
            at the seq2act long-context shape (L=4096, 8 heads of 64),
            compiled by Mosaic — not interpreted — against the dense
            ``scaled_dot_attention``.
@@ -331,9 +331,9 @@ def flash_leg(seq_len=4096, batch=2, heads=8, head_dim=64, interpret=False,
            "attention_mode='auto' does not choose the flash kernel at L={} "
            'on this backend'.format(seq_len))
     kernels = flash_both.lower(q, k, v).as_text().count('tpu_custom_call')
-    _check(kernels >= 3,
+    _check(kernels >= 2,
            'lowered program holds {} Mosaic custom calls, wanted the '
-           'forward and both backward kernels'.format(kernels))
+           'forward and the backward kernel'.format(kernels))
 
   t0 = time.perf_counter()
   out, grads = jax.block_until_ready(flash_both(q, k, v))

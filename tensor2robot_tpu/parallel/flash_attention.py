@@ -34,11 +34,29 @@ tile on the mask's edge is computed whole, with the mask applied to its
 elements.
 
 Memory: per-device O(L*D) activations only — no score tensor ever reaches
-HBM, forward OR backward: the backward is the same kernel family (two
-Pallas kernels, FlashAttention-2 structure, each over its own list of
-needed tiles — _flash_bwd_pallas) instead of an XLA scan. Numerics match
-the XLA oracle to f32 rounding (tests/test_flash_attention.py); what the
-three kernels measure in a cell of the benchmark is in PERF.md (section 5:
+HBM, forward OR backward: the backward is the same kernel family instead of
+an XLA scan (_flash_bwd_pallas), over the same list of needed tiles. It is
+ONE Pallas kernel of five products a tile: on the forward's grid (q block
+resident, k/v blocks streaming) a step forms s = q k^T and dp = do v^T
+once, from them p and ds, and adds all three of dv += p^T do, dk += ds^T q
+and dq += ds k. dq accumulates in a [block_q, D] float32 scratch and leaves
+at its q block's last step; dk and dv accumulate in float32 over the WHOLE
+k/v head ([l_k, D] scratch each, a step adds at its k block's rows) and
+leave once a k/v head, after the last query head that reads it (grouped
+heads are consecutive on the head axis, which therefore runs in order).
+That working set grows with the length (16 bytes x l_k x D with bf16
+operands: 16 MB at 8,192 keys of 128, 64 MB at 32,768), so a call whose
+k/v head would take more than FUSED_BWD_RESIDENT_BYTES runs the
+FlashAttention-2 pair instead (dk/dv with the k/v block resident and q
+streaming, dq with the q block resident: s, dp, the exponential and the
+mask formed twice, seven products; its working set does not grow): one
+algorithm, its layout chosen from the call's shapes alone. The fused
+kernel is launched under the name ``flash_attention_bwd_dq`` (the kernel
+whose grid it runs): the benchmark's readers sum the attention time by the
+names of the pair, and a name of its own would drop out of them. Numerics
+match the XLA oracle to f32 rounding (tests/test_flash_attention.py), on
+both paths, which form every sum in the same order; what the
+kernels measure in a cell of the benchmark is in PERF.md (section 5:
 time a step by kernel, the work executed beside the work needed, and
 ``attention_roofline``). This is the single-device
 long-context path; ring_attention.py handles the cross-device dimension
@@ -80,7 +98,7 @@ from tensor2robot_tpu import runtime
 NEG_INF = -1e30
 
 # Names of the custom VJP's residuals (see the module docstring): what the
-# two backward kernels read. A checkpoint policy that saves them spares its
+# backward reads. A checkpoint policy that saves them spares its
 # backward pass the forward kernel (out, lse) and the caller's projections,
 # positions and layout change (q, k, v).
 FLASH_Q = 'flash_q'
@@ -297,13 +315,17 @@ def tile_table(n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
 
 
 def _set_gauges(suffix: str, bh: int, l_q: int, l_k: int, block_q: int,
-                block_k: int, causal: bool, window, diffusion, steps: int):
+                block_k: int, causal: bool, window, diffusion, steps: int,
+                backward_kernels: Optional[int] = None):
   """Host side, when a masked call is traced: the pairs the mask keeps, the
   pairs of the tiles the kernels compute and the grid steps they launch
-  (``steps`` a query head), a call (all heads)."""
+  (``steps`` a query head), a call (all heads); from the backward also the
+  kernels it launches (1 fused, 2 not)."""
   from tensor2robot_tpu.observability import get_registry
 
   registry = get_registry()
+  if backward_kernels is not None:
+    registry.gauge('attention/backward_kernels').set(float(backward_kernels))
   registry.gauge('attention/mask_pairs_needed').set(
       float(bh * mask_pairs(l_q, l_k, causal, window, diffusion)))
   registry.gauge('attention/mask_pairs_computed' + suffix).set(float(
@@ -603,35 +625,97 @@ def _bwd_default_blocks(l_q: int, l_k: int):
   return (256, 256) if max(l_q, l_k) <= 4096 else (512, 1024)
 
 
-def _bwd_p_ds(q, k, v, do, lse, delta, *, scale, causal, q_base, k_base,
-              block_q, block_k, window=None, diffusion=None):
-  """Shared recompute for both backward kernels: (p, ds) for one block
-  pair, from the saved log-sum-exp. All operands f32 2D blocks."""
+def _bwd_tile(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref, v_ref, do_ref,
+              lse_ref, delta_ref, *, scale, causal, block_q, block_k,
+              window=None, diffusion=None):
+  """What every backward kernel forms of step ``t``'s tile, from the saved
+  log-sum-exp: (q, k, do, p, ds), float32 2D blocks."""
+  q = q_ref[0].astype(jnp.float32)
+  k = k_ref[0].astype(jnp.float32)
+  do = do_ref[0].astype(jnp.float32)
+  # Reduce over the uniform broadcast sublanes instead of slicing one
+  # (width-1 memref slices are rejected by jax 0.9 Mosaic).
+  lse = jnp.max(lse_ref[0].astype(jnp.float32), axis=0)[:, None]
+  delta = jnp.max(delta_ref[0].astype(jnp.float32), axis=0)[:, None]
   s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                           preferred_element_type=jnp.float32) * scale
   masked = causal or diffusion is not None
   if masked:
-    s = jnp.where(_tile_mask(q_base, k_base, block_q, block_k, window,
-                             diffusion), s, NEG_INF)
+    s = jnp.where(_tile_mask(q_blocks_ref[t] * block_q,
+                             k_blocks_ref[t] * block_k, block_q, block_k,
+                             window, diffusion), s, NEG_INF)
   p = jnp.exp(s - lse)
   if masked:
     p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-  dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+  dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
+                           (((1,), (1,)), ((), ())),
                            preferred_element_type=jnp.float32)
   ds = p * (dp - delta) * scale
-  return p, ds
+  return q, k, do, p, ds
+
+
+def _rows_t(a, b):
+  """a^T b in float32: [rows, m], [rows, n] -> [m, n] (dv = p^T do, dk =
+  ds^T q)."""
+  return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+
+
+def _flash_bwd_fused_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref,
+                            k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                            dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                            group: int, block_k: int, **tile):
+  """dq, dk and dv in one: grid (bh, steps of ``tile_table``), the dq
+  kernel's, q block resident and k/v streaming; s and dp are formed once a
+  tile and all three gradients leave the step. dk and dv accumulate in
+  float32 over the WHOLE k/v head ([l_k, d] scratch each, a step adds its
+  tile at the k block's rows): zeroed at the first step of the group's
+  first query head, written at the last step of its last. The group's
+  query heads follow one another on the first grid axis and the dk/dv
+  output block is the whole k/v head, so it stays put over all of them and
+  is written back once; a k/v block that the mask leaves no tile keeps its
+  zeros."""
+  b, t = pl.program_id(0), pl.program_id(1)
+  flags = flags_ref[t]
+  last_step = t == pl.num_programs(1) - 1
+
+  @pl.when((t == 0) & (b % group == 0))
+  def _init_kv():
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
+
+  @pl.when(flags & FIRST != 0)
+  def _init_q():
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
+  q, k, do, p, ds = _bwd_tile(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref,
+                              v_ref, do_ref, lse_ref, delta_ref,
+                              block_k=block_k, **tile)
+  rows = pl.ds(pl.multiple_of(k_blocks_ref[t] * block_k, block_k), block_k)
+  dv_acc[rows, :] += _rows_t(p, do)
+  dk_acc[rows, :] += _rows_t(ds, q)
+  dq_acc[...] += jax.lax.dot_general(
+      ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+  @pl.when(flags & LAST != 0)
+  def _finalize_q():
+    dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+  @pl.when(last_step & (b % group == group - 1))
+  def _finalize_kv():
+    dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_kv_kernel(head_ref, q_blocks_ref, k_blocks_ref, flags_ref,
                          q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                         causal: bool, block_q: int, block_k: int,
-                         window: Optional[int] = None, diffusion=None):
-  """dk/dv: grid (bh of k/v, steps of ``tile_table(k_resident=True)``) —
-  k/v block resident (accumulators in scratch), q/do/lse/delta stream
-  through. With grouped-query heads a k/v block's steps run over the
-  ``group`` query heads that read this k/v head, head-major, so dk/dv are
-  summed over the group in the scratch and written once."""
+                         dk_ref, dv_ref, dk_acc, dv_acc, **tile):
+  """dk/dv of the two-kernel backward: grid (bh of k/v, steps of
+  ``tile_table(k_resident=True)``) — k/v block resident (accumulators in
+  scratch), q/do/lse/delta stream through. With grouped-query heads a k/v
+  block's steps run over the ``group`` query heads that read this k/v head,
+  head-major, so dk/dv are summed over the group in the scratch and written
+  once."""
   del head_ref
   t = pl.program_id(1)
   flags = flags_ref[t]
@@ -641,25 +725,10 @@ def _flash_bwd_kv_kernel(head_ref, q_blocks_ref, k_blocks_ref, flags_ref,
     dk_acc[...] = jnp.zeros_like(dk_acc)
     dv_acc[...] = jnp.zeros_like(dv_acc)
 
-  q = q_ref[0].astype(jnp.float32)
-  do = do_ref[0].astype(jnp.float32)
-  # Reduce over the uniform broadcast sublanes instead of slicing one
-  # (width-1 memref slices are rejected by jax 0.9 Mosaic).
-  lse = jnp.max(lse_ref[0].astype(jnp.float32), axis=0)[:, None]
-  delta = jnp.max(delta_ref[0].astype(jnp.float32), axis=0)[:, None]
-  p, ds = _bwd_p_ds(q, k_ref[0].astype(jnp.float32),
-                    v_ref[0].astype(jnp.float32), do, lse, delta,
-                    scale=scale, causal=causal,
-                    q_base=q_blocks_ref[t] * block_q,
-                    k_base=k_blocks_ref[t] * block_k,
-                    block_q=block_q, block_k=block_k, window=window,
-                    diffusion=diffusion)
-  dv_acc[...] += jax.lax.dot_general(
-      p, do, (((0,), (0,)), ((), ())),
-      preferred_element_type=jnp.float32)
-  dk_acc[...] += jax.lax.dot_general(
-      ds, q, (((0,), (0,)), ((), ())),
-      preferred_element_type=jnp.float32)
+  q, _, do, p, ds = _bwd_tile(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref,
+                              v_ref, do_ref, lse_ref, delta_ref, **tile)
+  dv_acc[...] += _rows_t(p, do)
+  dk_acc[...] += _rows_t(ds, q)
 
   @pl.when(flags & LAST != 0)
   def _finalize():
@@ -668,12 +737,10 @@ def _flash_bwd_kv_kernel(head_ref, q_blocks_ref, k_blocks_ref, flags_ref,
 
 
 def _flash_bwd_q_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref,
-                        v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc, *,
-                        scale: float, causal: bool, block_q: int,
-                        block_k: int, window: Optional[int] = None,
-                        diffusion=None):
-  """dq: grid (bh, steps of ``tile_table``) — q block resident, k/v stream
-  through."""
+                        v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
+                        **tile):
+  """dq of the two-kernel backward: grid (bh, steps of ``tile_table``) — q
+  block resident, k/v stream through; s and dp formed a second time."""
   t = pl.program_id(1)
   flags = flags_ref[t]
 
@@ -681,24 +748,32 @@ def _flash_bwd_q_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref,
   def _init():
     dq_acc[...] = jnp.zeros_like(dq_acc)
 
-  q = q_ref[0].astype(jnp.float32)
-  do = do_ref[0].astype(jnp.float32)
-  lse = jnp.max(lse_ref[0].astype(jnp.float32), axis=0)[:, None]
-  delta = jnp.max(delta_ref[0].astype(jnp.float32), axis=0)[:, None]
-  k = k_ref[0].astype(jnp.float32)
-  _, ds = _bwd_p_ds(q, k, v_ref[0].astype(jnp.float32), do, lse, delta,
-                    scale=scale, causal=causal,
-                    q_base=q_blocks_ref[t] * block_q,
-                    k_base=k_blocks_ref[t] * block_k,
-                    block_q=block_q, block_k=block_k, window=window,
-                    diffusion=diffusion)
+  _, k, _, _, ds = _bwd_tile(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref,
+                             v_ref, do_ref, lse_ref, delta_ref, **tile)
   dq_acc[...] += jax.lax.dot_general(
-      ds, k, (((1,), (0,)), ((), ())),
-      preferred_element_type=jnp.float32)
+      ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
   @pl.when(flags & LAST != 0)
   def _finalize():
     dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+
+# The fused backward holds, beside the blocks it streams, dk and dv of one
+# whole k/v head in VMEM: two float32 accumulators and the two output
+# blocks, which Pallas buffers twice. A call whose k/v head would take more
+# than this runs the two-kernel backward, whose working set does not grow
+# with the length. What it comes to by shape, and where the line was read
+# on the chip: CHANGES.md, PR 35.
+FUSED_BWD_RESIDENT_BYTES = 64 * 1024 * 1024
+# Scoped VMEM asked for beside the resident bytes: the streamed blocks,
+# twice, and the body's four [block_q, block_k] float32 tiles (the default
+# scoped limit, 16 MiB, is what the two-kernel backward runs under).
+_FUSED_BWD_STREAMED_BYTES = 32 * 1024 * 1024
+
+
+def _fused_bwd_resident_bytes(l_k: int, d: int, dtype) -> int:
+  """dk and dv of one k/v head: float32 accumulators, output blocks twice."""
+  return 2 * l_k * d * (4 + 2 * jnp.dtype(dtype).itemsize)
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
@@ -707,47 +782,91 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
   """Full Pallas backward: dq over [BH, L, D], dk, dv over k/v's
   [BH/group, L, D].
 
-  Two kernels (FlashAttention-2 structure): dk/dv with the k/v block
-  resident and q streaming, dq with the q block resident and k/v
-  streaming, each over the tiles the mask keeps (``tile_table``). P is
-  recomputed from the forward's saved log-sum-exp; no [L, L] tensor exists
-  in either pass. delta = rowsum(do * out) is one fused elementwise pass
-  XLA handles before the kernels. Grouped-query heads: both kernels read
-  k/v head ``b // group`` through their index maps; the dk/dv kernel
-  sweeps the group's query heads after one another over its resident k/v
-  block (see _flash_bwd_kv_kernel).
+  ONE kernel (``_flash_bwd_fused_kernel``, five products a tile) where dk
+  and dv of a whole k/v head fit in VMEM (FUSED_BWD_RESIDENT_BYTES, judged
+  from ``l_k``, ``d`` and the dtype alone); else two (FlashAttention-2
+  structure, seven products): dk/dv with the k/v block resident and q
+  streaming, dq with the q block resident and k/v streaming. Each runs over
+  the tiles the mask keeps (``tile_table``). P is recomputed from the
+  forward's saved log-sum-exp; no [L, L] tensor exists in either pass.
+  delta = rowsum(do * out) is one fused elementwise pass XLA handles before
+  the kernels. Grouped-query heads: every kernel reads k/v head
+  ``b // group`` through its index maps, and dk/dv are summed over the
+  group's query heads in VMEM (see the kernels). Why the fused kernel
+  carries the dq kernel's name: the module docstring.
   """
   bh, l_q, d = q.shape
   l_k = k.shape[1]
   group = bh // k.shape[0]
   kv = _kv_head(group)
+  resident = _fused_bwd_resident_bytes(l_k, d, k.dtype)
+  fused = resident <= FUSED_BWD_RESIDENT_BYTES
   tiles = (l_q // block_q, l_k // block_k, block_q, block_k, causal, window,
            diffusion)
-  kv_table = tile_table(*tiles, k_resident=True, group=group)
   q_table = tile_table(*tiles)
+  kv_table = None if fused else tile_table(*tiles, k_resident=True,
+                                           group=group)
   if causal or diffusion is not None:
-    # The two kernels' steps a query head differ only where the mask
-    # leaves a q block or a k/v block empty: the larger count.
+    # ONE backward kernel's steps a query head. The two kernels' differ
+    # only where the mask leaves a q block or a k/v block empty: the larger.
+    steps = q_table.shape[1] if fused else max(
+        q_table.shape[1], kv_table.shape[1] // group)
     _set_gauges('_bwd', bh, l_q, l_k, block_q, block_k, causal, window,
-                diffusion,
-                max(q_table.shape[1], kv_table.shape[1] // group))
+                diffusion, steps, backward_kernels=1 if fused else 2)
   do = d_out.astype(jnp.float32)
   delta = jnp.sum(do * out.astype(jnp.float32), axis=-1)      # [BH, Lq]
   # lse/delta ride as [BH, 8, L] broadcast-sublane blocks (Mosaic's
   # second-minor divisibility rule — same scheme as the forward's lse).
   lse8 = jnp.broadcast_to(lse[:, None, :], (bh, 8, l_q))
   delta8 = jnp.broadcast_to(delta[:, None, :], (bh, 8, l_q))
+  tile = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+              window=window, diffusion=diffusion)
 
-  # Index maps receive the table's rows as trailing arguments. dk/dv: b is
-  # a k/v head, its step's query head b * group + head[t].
+  # Index maps receive the table's rows as trailing arguments.
+  q_of_q = lambda b, t, qs, ks, flags: (b, qs[t], 0)
+  row_of_q = lambda b, t, qs, ks, flags: (b, 0, qs[t])
+  kv_of_q = lambda b, t, qs, ks, flags: (kv(b), ks[t], 0)
+  q_resident = dict(
+      num_scalar_prefetch=3,
+      grid=(bh, q_table.shape[1]),
+      in_specs=[
+          pl.BlockSpec((1, block_q, d), q_of_q),
+          pl.BlockSpec((1, block_k, d), kv_of_q),
+          pl.BlockSpec((1, block_k, d), kv_of_q),
+          pl.BlockSpec((1, block_q, d), q_of_q),
+          pl.BlockSpec((1, 8, block_q), row_of_q),
+          pl.BlockSpec((1, 8, block_q), row_of_q),
+      ])
+  dq_spec = pl.BlockSpec((1, block_q, d), q_of_q)
+  dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+  dkv_shapes = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype)]
+  if fused:
+    kv_head = pl.BlockSpec((1, l_k, d),
+                           lambda b, t, qs, ks, flags: (kv(b), 0, 0))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_fused_kernel, group=group, **tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            out_specs=[dq_spec, kv_head, kv_head],
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                            pltpu.VMEM((l_k, d), jnp.float32),
+                            pltpu.VMEM((l_k, d), jnp.float32)],
+            **q_resident),
+        out_shape=[dq_shape] + dkv_shapes,
+        # The query heads of a group share the dk/dv accumulators: in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary', 'arbitrary'),
+            vmem_limit_bytes=resident + _FUSED_BWD_STREAMED_BYTES),
+        interpret=interpret,
+        name='flash_attention_bwd_dq',
+    )(*q_table[1:], q, k, v, d_out, lse8, delta8)
+
+  # dk/dv: b is a k/v head, its step's query head b * group + head[t].
   q_of_kv = lambda b, t, head, qs, ks, flags: (b * group + head[t], qs[t], 0)
   row_of_kv = lambda b, t, head, qs, ks, flags: (b * group + head[t], 0, qs[t])
   kv_of_kv = lambda b, t, head, qs, ks, flags: (b, ks[t], 0)
-  kv_kernel = functools.partial(
-      _flash_bwd_kv_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k, window=window, diffusion=diffusion)
   dk, dv = pl.pallas_call(
-      kv_kernel,
+      functools.partial(_flash_bwd_kv_kernel, **tile),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=4,
           grid=(bh // group, kv_table.shape[1]),
@@ -768,37 +887,17 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
               pltpu.VMEM((block_k, d), jnp.float32),
           ],
       ),
-      out_shape=[
-          jax.ShapeDtypeStruct(k.shape, k.dtype),
-          jax.ShapeDtypeStruct(v.shape, v.dtype),
-      ],
+      out_shape=dkv_shapes,
       interpret=interpret,
       name='flash_attention_bwd_dkv',
   )(*kv_table, q, k, v, d_out, lse8, delta8)
-
-  q_of_q = lambda b, t, qs, ks, flags: (b, qs[t], 0)
-  row_of_q = lambda b, t, qs, ks, flags: (b, 0, qs[t])
-  kv_of_q = lambda b, t, qs, ks, flags: (kv(b), ks[t], 0)
-  q_kernel = functools.partial(
-      _flash_bwd_q_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k, window=window, diffusion=diffusion)
   dq = pl.pallas_call(
-      q_kernel,
+      functools.partial(_flash_bwd_q_kernel, **tile),
       grid_spec=pltpu.PrefetchScalarGridSpec(
-          num_scalar_prefetch=3,
-          grid=(bh, q_table.shape[1]),
-          in_specs=[
-              pl.BlockSpec((1, block_q, d), q_of_q),
-              pl.BlockSpec((1, block_k, d), kv_of_q),
-              pl.BlockSpec((1, block_k, d), kv_of_q),
-              pl.BlockSpec((1, block_q, d), q_of_q),
-              pl.BlockSpec((1, 8, block_q), row_of_q),
-              pl.BlockSpec((1, 8, block_q), row_of_q),
-          ],
-          out_specs=[pl.BlockSpec((1, block_q, d), q_of_q)],
+          out_specs=[dq_spec],
           scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-      ),
-      out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
+          **q_resident),
+      out_shape=[dq_shape],
       interpret=interpret,
       name='flash_attention_bwd_dq',
   )(*q_table[1:], q, k, v, d_out, lse8, delta8)[0]
@@ -836,7 +935,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, block_q_bwd,
                block_k_bwd, window, diffusion, residuals, d_out):
-  """Pallas FlashAttention-2 backward (see _flash_bwd_pallas).
+  """The Pallas backward (see _flash_bwd_pallas).
 
   Until round 4 this was an XLA lax.scan recompute; it is now the same
   kernel family as the forward, over the tiles the mask keeps and with its
@@ -871,7 +970,8 @@ def flash_attention(q, k, v,
   """Exact attention over [B, L, H, D] inputs, O(L) memory, differentiable.
 
   Forward runs the Pallas kernel (_flash_kernel); the backward is the
-  blockwise FlashAttention recomputation (custom VJP, two more kernels) so
+  blockwise FlashAttention recomputation (custom VJP; one more kernel, two
+  where dk and dv of a k/v head outgrow VMEM: _flash_bwd_pallas) so
   training never sees an [L, L] tensor either. The grid of each kernel is
   the list of the tiles the mask keeps (``tile_table``; the module
   docstring has its order), every tile of the rectangle for an unmasked
@@ -906,7 +1006,9 @@ def flash_attention(q, k, v,
   forward kernel's tiles; ``..._computed_bwd`` each backward kernel's) and
   ``attention/grid_steps`` (the steps the forward kernel launches;
   ``..._steps_bwd`` each backward kernel's), all heads of the call: steps
-  x tile = pairs computed, every step computes.
+  x tile = pairs computed, every step computes; and
+  ``attention/backward_kernels``, the kernels the backward launches (1
+  fused, 2 not).
 
   Default block sizes come from v5e sweeps (B=1, H=8, D=128, causal,
   chained on-device timing): (1024, 1024) — grid-step count (fixed

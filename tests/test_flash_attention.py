@@ -19,6 +19,17 @@ from tensor2robot_tpu.parallel.ring_attention import reference_attention
 flash_lib = transformer_lib.flash_lib  # the module; the package exports the function
 
 
+# The two backward paths, by the kernels each launches: ``_flash_bwd_pallas``
+# chooses from the bytes dk and dv of a k/v head take.
+BACKWARDS = {'fused': 1, 'two kernels': 2}
+
+
+def _take(backward, monkeypatch):
+  """Steers a call onto ``backward`` as a length past the budget would."""
+  if backward == 'two kernels':
+    monkeypatch.setattr(flash_lib, 'FUSED_BWD_RESIDENT_BYTES', 0)
+
+
 def _qkv(b=2, l=256, h=4, d=64, dtype=np.float32, seed=0):
   rng = np.random.RandomState(seed)
   return tuple(rng.randn(b, l, h, d).astype(dtype) for _ in range(3))
@@ -81,14 +92,16 @@ class TestFlashAttention:
     g_ref = jax.grad(ref_loss)(jnp.asarray(q))
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=1e-4)
 
+  @pytest.mark.parametrize('backward', list(BACKWARDS))
   @pytest.mark.parametrize('causal', [False, True])
-  def test_full_gradients_match_oracle(self, causal):
-    """dq, dk AND dv from the Pallas backward kernels (round 4 — two
-    kernels with causal block skip, parallel/flash_attention.py
-    _flash_bwd_pallas) against the XLA oracle. block_*_bwd=32 with L=128
-    makes the BACKWARD grids 4x4 blocks, so the cross-block accumulate /
-    init / finalize logic and the causal skip actually run (the backward
-    ignores the forward block sizes)."""
+  def test_full_gradients_match_oracle(self, causal, backward, monkeypatch):
+    """dq, dk AND dv from the Pallas backward (parallel/flash_attention.py
+    _flash_bwd_pallas: one fused kernel, or two where a k/v head's dk and
+    dv outgrow the budget) against the XLA oracle. block_*_bwd=32 with
+    L=128 makes the BACKWARD grids 4x4 blocks, so the cross-block
+    accumulate / init / finalize logic and the causal skip actually run
+    (the backward ignores the forward block sizes)."""
+    _take(backward, monkeypatch)
     q, k, v = _qkv(b=1, l=128, h=2, d=32)
 
     def loss(fn):
@@ -185,8 +198,9 @@ class TestNamedResiduals:
       return fn(*args), *jaxpr_calls(fn, *args)
 
     got, calls, _ = grad()
-    # A checkpoint with no policy runs the forward kernel again, as ever.
-    assert [calls[k] for k in KERNELS] == [2 if checkpointed else 1, 1, 1]
+    # A checkpoint with no policy runs the forward kernel again, as ever;
+    # the backward is one kernel, under the dq kernel's name.
+    assert [calls[k] for k in KERNELS] == [2 if checkpointed else 1, 0, 1]
     # With the tags made the identity the module is the one before them.
     monkeypatch.setattr(flash_lib, 'checkpoint_name', lambda x, name: x)
     want, untagged, tags = grad()
@@ -202,7 +216,7 @@ class TestNamedResiduals:
         loss, policy=jax.checkpoint_policies.save_only_these_names(
             flash_lib.FLASH_OUT, flash_lib.FLASH_LSE))
     calls, _ = jaxpr_calls(jax.grad(kept, argnums=(0, 1, 2)), *args)
-    assert [calls[k] for k in KERNELS] == [1, 1, 1]
+    assert [calls[k] for k in KERNELS] == [1, 0, 1]
     for g, w in zip(jax.grad(kept, argnums=(0, 1, 2))(*args),
                     jax.grad(loss, argnums=(0, 1, 2))(*args)):
       np.testing.assert_array_equal(g, w)
@@ -221,12 +235,17 @@ class TestBlockDiffusionMask:
                               (1, 2 * length, kv_heads, 16)) for i in (1, 2))
     return q, k, v
 
-  def test_the_same_three_kernels_once_each(self, jaxpr_calls):
+  @pytest.mark.parametrize('backward', list(BACKWARDS))
+  def test_the_same_kernels_once_each(self, backward, jaxpr_calls,
+                                      monkeypatch):
+    _take(backward, monkeypatch)
     q, k, v = self._qkv()
     loss = lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, block_diffusion=(32, 4), block_q=16, block_k=16) ** 2)
     calls, tags = jaxpr_calls(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
-    assert [calls[name] for name in KERNELS] == [1, 1, 1]
+    # The dk/dv kernel only of the two; the fused one carries dq's name.
+    assert [calls[name] for name in KERNELS] == [
+        1, BACKWARDS[backward] - 1, 1]
     assert set(tags) == set(flash_lib.BACKWARD_READS)
 
   def test_a_policy_keeps_the_residuals_of_a_masked_call(self, jaxpr_calls):
@@ -237,7 +256,7 @@ class TestBlockDiffusionMask:
         loss, policy=jax.checkpoint_policies.save_only_these_names(
             *flash_lib.BACKWARD_READS))
     calls, _ = jaxpr_calls(jax.grad(kept, argnums=(0, 1, 2)), q, k, v)
-    assert [calls[name] for name in KERNELS] == [1, 1, 1]
+    assert [calls[name] for name in KERNELS] == [1, 0, 1]
     for g, w in zip(jax.grad(kept, argnums=(0, 1, 2))(q, k, v),
                     jax.grad(loss, argnums=(0, 1, 2))(q, k, v)):
       np.testing.assert_array_equal(g, w)
@@ -270,17 +289,21 @@ class TestBlockDiffusionMask:
                              block_q=16, block_k=16)
     np.testing.assert_allclose(masked[:, 32:], causal, atol=2e-6)
 
+  @pytest.mark.parametrize('backward', list(BACKWARDS))
   @pytest.mark.parametrize('causal, window, diffusion, pairs, tiles', [
       (True, None, None, 64 * 65 // 2, 10),
       (True, 8, None, 8 * 9 // 2 + 56 * 8, 7),
       (False, None, (32, 4), 32 * 32 + 32 * 4, 2 + 3 + 3),
   ])
   def test_the_gauges_count_the_mask_and_the_tiles(self, causal, window,
-                                                   diffusion, pairs, tiles):
+                                                   diffusion, pairs, tiles,
+                                                   backward, monkeypatch):
     """... and the grid steps: every kernel launches the tiles it computes
-    and no other, so steps x tile = pairs computed, forward and backward."""
+    and no other, so steps x tile = pairs computed, forward and backward;
+    the backward gauges are ONE backward kernel's on either path."""
     from tensor2robot_tpu.observability import get_registry
 
+    _take(backward, monkeypatch)
     assert flash_lib.mask_pairs(64, 64, causal, window, diffusion) == pairs
     assert flash_lib.tiles_computed(4, 4, 16, 16, causal, window,
                                     diffusion) == tiles
@@ -296,13 +319,18 @@ class TestBlockDiffusionMask:
     assert value('grid_steps') == 4 * tiles
     assert value('mask_pairs_computed_bwd') == 4 * tiles_bwd * 512
     assert value('grid_steps_bwd') == 4 * tiles_bwd
-    # The grids themselves: (heads, steps); dk/dv's runs over the 2 k/v
-    # heads, each k/v block followed by both query heads of its group.
-    grids = {eqn.params['name']: eqn.params['grid_mapping'].grid
-             for eqn in jaxpr.eqns if eqn.primitive.name == 'pallas_call'}
-    assert grids == {'flash_attention_fwd': (4, tiles),
-                     'flash_attention_bwd_dkv': (2, 2 * tiles_bwd),
-                     'flash_attention_bwd_dq': (4, tiles_bwd)}
+    assert value('backward_kernels') == BACKWARDS[backward]
+    # The grids themselves: (heads, steps). The fused backward is ONE
+    # kernel under the dq kernel's name, on its grid; of the two, dk/dv's
+    # runs over the 2 k/v heads, each k/v block followed by both query
+    # heads of its group.
+    grids = [(eqn.params['name'], eqn.params['grid_mapping'].grid)
+             for eqn in jaxpr.eqns if eqn.primitive.name == 'pallas_call']
+    two = [('flash_attention_bwd_dkv', (2, 2 * tiles_bwd))] * (
+        backward == 'two kernels')
+    assert sorted(grids) == sorted(
+        [('flash_attention_fwd', (4, tiles)),
+         ('flash_attention_bwd_dq', (4, tiles_bwd))] + two)
 
   def test_an_unmasked_call_sets_no_gauge(self):
     assert flash_lib.mask_pairs(64, 48, False, None, None) == 64 * 48
@@ -402,26 +430,36 @@ class TestTileTable:
 
 class TestMostlySkippedGrids:
   """Outputs and all three gradients against a dense oracle under the
-  kernels' own mask, grouped heads, at sizes where the mask drops most of
-  the rectangle and a q block's k blocks are not neighbours."""
+  kernels' own mask, on both backward paths, at sizes where the mask drops
+  most of the rectangle and a q block's k blocks are not neighbours; query
+  heads in groups of 1, 2 and 3 (the fused backward sums dk and dv over a
+  group's heads in accumulators that outlive a head)."""
 
-  @pytest.mark.parametrize('l_q, l_k, causal, window, diffusion', [
-      (512, 512, False, None, (256, 4)),   # 24 of 64 tiles; q block 1: k 1, 4, 5
-      (512, 512, True, 70, None),          # 22 of 64
-      (256, 256, True, None, None),        # 10 of 16
-      (128, 256, True, None, None),        # k/v blocks 2 and 3: no tile at all
+  @pytest.mark.parametrize('backward', list(BACKWARDS))
+  @pytest.mark.parametrize('l_q, l_k, causal, window, diffusion, heads', [
+      (512, 512, False, None, (256, 4), (4, 2)),  # 24 of 64 tiles; q block 1: k 1, 4, 5
+      (512, 512, True, 70, None, (4, 2)),         # 22 of 64
+      (256, 256, True, None, None, (4, 2)),       # 10 of 16
+      (128, 256, True, None, None, (4, 2)),       # k/v blocks 2 and 3: no tile at all
+      (128, 192, False, None, None, (2, 2)),      # the whole rectangle, l_q != l_k
+      (256, 256, True, None, None, (6, 2)),
+      (256, 256, False, None, (128, 4), (3, 1)),
   ], ids=['block diffusion', 'window', 'causal',
-          'causal, k/v blocks without a tile'])
-  def test_outputs_and_gradients_match_the_dense_mask(self, l_q, l_k, causal,
-                                                      window, diffusion):
+          'causal, k/v blocks without a tile', 'unmasked, more keys',
+          'causal, groups of 3', 'block diffusion, one group of 3'])
+  def test_outputs_and_gradients_match_the_dense_mask(
+      self, l_q, l_k, causal, window, diffusion, heads, backward,
+      monkeypatch):
+    _take(backward, monkeypatch)
+    h, h_kv = heads
     key = jax.random.PRNGKey(l_q + l_k)
-    q = jax.random.normal(key, (1, l_q, 4, 16))
-    k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, l_k, 2, 16))
+    q = jax.random.normal(key, (1, l_q, h, 16))
+    k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, l_k, h_kv, 16))
             for i in (1, 2))
     mask = jnp.asarray(_dense_mask(l_q, l_k, causal, window, diffusion))
 
     def dense(q, k, v):
-      k, v = (jnp.repeat(x, 2, axis=2) for x in (k, v))
+      k, v = (jnp.repeat(x, h // h_kv, axis=2) for x in (k, v))
       scores = jnp.einsum('bqhd,bkhd->bhqk', q, k) / 4.0
       probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
       return jnp.einsum('bhqk,bkhd->bqhd', probs, v)
@@ -435,6 +473,7 @@ class TestMostlySkippedGrids:
     want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
     for g, w, name in zip(got, want, 'qkv'):
       np.testing.assert_allclose(g, w, atol=1e-5, err_msg='d' + name)
-    if l_k > l_q:   # the keys no query sees get a gradient of exactly zero
+    if l_k > l_q and causal:
+      # the keys no query sees get a gradient of exactly zero
       assert not np.any(np.asarray(got[1])[:, l_q:]) and not np.any(
           np.asarray(got[2])[:, l_q:])

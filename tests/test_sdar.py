@@ -317,6 +317,8 @@ class TestTheMaskInTheFlashKernels:
         8 * tiles(16, 16)
     assert registry.gauge('attention/mask_pairs_computed_bwd').value == \
         8 * tiles(16, 8)
+    # ... of ONE backward kernel, and one is all the backward launches.
+    assert registry.gauge('attention/backward_kernels').value == 1
 
 
 class TestLayersTakePositionsAndNorms:

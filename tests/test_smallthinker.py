@@ -160,8 +160,10 @@ class TestTheBlockCheckpointKeepsWhatTheAttentionBackwardReads:
                            products=calls['dot_general'],
                            grads=jax.tree.leaves(grad(params, x)))
     kept, before = results['kept'], results['policy-less']
-    assert kept['kernels'] == [blocks, blocks, blocks]
-    assert before['kernels'] == [2 * blocks, blocks, blocks]
+    # One forward kernel a block; the backward is one kernel, which
+    # carries the dq kernel's name (parallel/flash_attention.py).
+    assert kept['kernels'] == [blocks, 0, blocks]
+    assert before['kernels'] == [2 * blocks, 0, blocks]
     # q, k and v are kept too: their three projections are not run again.
     assert kept['products'] == before['products'] - 3 * blocks
     got, want = kept['grads'], before['grads']
@@ -194,7 +196,7 @@ class TestTheBlockCheckpointKeepsWhatTheAttentionBackwardReads:
     monkeypatch.setattr(transformer_lib, 'resolve_attention_mode',
                         lambda mode, length: 'flash')
     calls, tags = jaxpr_calls(jax.grad(program), params)
-    assert [calls[k] for k in FLASH_KERNELS] == [4, 4, 4]
+    assert [calls[k] for k in FLASH_KERNELS] == [4, 0, 4]
     assert set(tags) == set(transformer_lib.flash_lib.BACKWARD_READS)
 
 

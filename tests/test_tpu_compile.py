@@ -12,7 +12,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from tensor2robot_tpu.layers import transformer as transformer_lib
 from tensor2robot_tpu.parallel import short_conv
+
+flash_lib = transformer_lib.flash_lib  # the module; the package exports the function
 
 
 @pytest.fixture(scope='module')
@@ -64,3 +67,32 @@ def test_the_short_convolution_pair_compiles_for_the_v5e(one_chip, batch,
     assert 'tpu_custom_call' in text and name in text
     # One pass: nothing but the kernel's own operands and results.
     assert program.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize('heads, kv_heads, length, mask, resident_mb', [
+    (32, 4, 16384, dict(causal=False, diffusion=(8192, 4)), 32),
+    (28, 4, 8192, dict(causal=True, window=4096), 16),
+    (8, 8, 32768, dict(causal=True), 64),
+], ids=['third_cell', 'second_cell_window_layer', 'the_longest_fused'])
+def test_the_fused_attention_backward_compiles_for_the_v5e(
+    one_chip, heads, kv_heads, length, mask, resident_mb):
+  """ONE Mosaic kernel with dk and dv of a whole k/v head resident in VMEM:
+  the scoped limit it asks for has to hold at the cells' shapes and at the
+  longest length the budget keeps fused, which the interpreter cannot
+  show."""
+  assert flash_lib._fused_bwd_resident_bytes(
+      length, 128, jnp.bfloat16) == resident_mb << 20 <= \
+      flash_lib.FUSED_BWD_RESIDENT_BYTES
+  shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+      dims, dtype, sharding=one_chip)
+  q, kv = shape(heads, length, 128), shape(kv_heads, length, 128)
+  block_q, block_k = flash_lib._bwd_default_blocks(length, length)
+  program = _compiled(
+      lambda q, k, v, out, lse, d_out: flash_lib._flash_bwd_pallas(
+          q, k, v, out, lse, d_out, scale=0.088, block_q=block_q,
+          block_k=block_k, interpret=False, **mask),
+      q, kv, kv, q, shape(heads, length, dtype=jnp.float32), q)
+  text = program.as_text()
+  assert text.count('tpu_custom_call') == 1
+  assert 'flash_attention_bwd_dq' in text
+  assert 'flash_attention_bwd_dkv' not in text
