@@ -11,7 +11,10 @@ Two classes of signals the PR 3 registry could not see:
     recompile mid-run (a shape-unstable batch reaching a jitted step) was
     previously invisible until someone noticed the step-time graph; now
     it is ``jax/compiles`` + ``jax/compile_ms`` landing in TensorBoard
-    and telemetry.jsonl, and the watchdog's ``recompile`` trigger.
+    and telemetry.jsonl, and the watchdog's ``recompile`` trigger. The
+    same dispatcher writes each phase into the span ring (`spans.py`)
+    with the function's name, under the program span that caused it:
+    ``compile.trace``, ``compile.lower``, ``compile.backend`` (below).
   * **Memory watermarks.** ``device.memory_stats()`` per accelerator
     (None on CPU — skipped, not faked) and host RSS from /proc (fallback
     ``resource.getrusage``), sampled by the trainer at its log cadence.
@@ -28,9 +31,12 @@ from __future__ import annotations
 import os
 import resource
 import socket
+import threading
+import time
 from typing import Dict, Optional
 
 from tensor2robot_tpu.observability import registry as registry_lib
+from tensor2robot_tpu.observability import spans
 
 __all__ = [
     'COMPILE_COUNTER', 'COMPILE_MS_HISTOGRAM', 'TRACE_MS_HISTOGRAM',
@@ -56,14 +62,88 @@ DEVICE_PEAK_BYTES_GAUGE = 'memory/device_peak_bytes'
 # simply never matched, so a rename degrades to "no signal", not a crash).
 _BACKEND_COMPILE_EVENT = '/jax/core/compile/backend_compile_duration'
 _JAXPR_TRACE_EVENT = '/jax/core/compile/jaxpr_trace_duration'
+_LOWER_EVENT = '/jax/core/compile/jaxpr_to_mlir_module_duration'
 _CACHE_MISS_EVENT = '/jax/compilation_cache/cache_misses'
 _CACHE_HIT_EVENT = '/jax/compilation_cache/cache_hits'
+_CACHE_READ_EVENT = '/jax/compilation_cache/cache_retrieval_time_sec'
+
+# The three phases of a compile request, as ring records. jax announces a
+# phase at its start (a scalar) and times it at its end (a duration), both
+# on the compiling thread and both with ``fun_name`` (dispatch.py,
+# ``LogElapsedTimeContextManager``); ``backend_compile_duration`` wraps
+# ``compile_or_get_cached``, so on a cache hit it is the read and the load.
+_PHASE_RECORDS = {_JAXPR_TRACE_EVENT: 'compile.trace',
+                  _LOWER_EVENT: 'compile.lower',
+                  _BACKEND_COMPILE_EVENT: 'compile.backend'}
 
 _installed = False
 _enabled = False
 
 
+class _Phase:
+  """A phase jax has announced and not yet timed, and what fired inside."""
+
+  __slots__ = ('event', 'inner', 'from_cache', 'cache_read_ms')
+
+  def __init__(self, event: str):
+    self.event = event
+    self.inner = 0  # phase events folded into this one
+    self.from_cache = 0  # a persistent-cache hit fired inside it
+    self.cache_read_ms = 0.0
+
+
+class _OpenPhases(threading.local):
+  """Per thread: its open phases, outermost first. Kept whether or not the
+  dispatcher is enabled, so that enabling it inside a trace cannot leave a
+  phase open for ever."""
+
+  def __init__(self):
+    self.stack = []
+
+
+_PHASES = _OpenPhases()
+
+
+def _on_scalar(event: str, value, **kwargs) -> None:
+  if event in _PHASE_RECORDS:
+    _PHASES.stack.append(_Phase(event))
+
+
+def _close_phase(event: str, name: str, duration_secs: float, fun) -> None:
+  """One ring record per OUTERMOST phase of a thread. A jitted function
+  traced while another is traced or lowered (every inner ``jax.jit``, every
+  jitted ``jax.numpy`` function, what Mosaic traces while lowering a
+  kernel) fires events of its own: those are counted in the outer record's
+  ``inner`` and write nothing, so a set-up writes tens of records and their
+  seconds add up. A backend compile is ALWAYS a record (their count is
+  ``jax/compiles``); one inside a trace lies inside that trace's record on
+  the same thread, which is how a reader takes its seconds out."""
+  end_ns = time.perf_counter_ns()
+  stack = _PHASES.stack
+  # jax's phases nest, so the newest announcement is this phase's own; one
+  # announced before the listeners were registered has none.
+  phase = stack.pop() if stack else None
+  if phase is None or phase.event != event:
+    phase = _Phase(event)
+  if not _enabled:
+    return
+  start_ns = end_ns - max(0, int(duration_secs * 1e9))
+  if event == _BACKEND_COMPILE_EVENT:
+    spans.interval(name, start_ns, end_ns, fun=fun,
+                   from_cache=phase.from_cache,
+                   cache_read_ms=phase.cache_read_ms)
+  elif stack:
+    stack[-1].inner += 1 + phase.inner
+  else:
+    spans.interval(name, start_ns, end_ns, fun=fun, inner=phase.inner)
+
+
 def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+  name = _PHASE_RECORDS.get(event)
+  if name is not None:
+    _close_phase(event, name, duration_secs, kwargs.get('fun_name', ''))
+  elif event == _CACHE_READ_EVENT and _PHASES.stack:
+    _PHASES.stack[-1].cache_read_ms = duration_secs * 1e3
   if not _enabled:
     return
   registry = registry_lib.get_registry()
@@ -87,6 +167,8 @@ def _on_event(event: str, **kwargs) -> None:
     registry_lib.get_registry().counter(CACHE_MISS_COUNTER).inc()
   elif event == _CACHE_HIT_EVENT:
     registry_lib.get_registry().counter(CACHE_HIT_COUNTER).inc()
+    if _PHASES.stack:
+      _PHASES.stack[-1].from_cache = 1
 
 
 def install_jax_listeners() -> bool:
@@ -103,6 +185,7 @@ def install_jax_listeners() -> bool:
   if not _installed:
     monitoring.register_event_duration_secs_listener(_on_duration)
     monitoring.register_event_listener(_on_event)
+    monitoring.register_scalar_listener(_on_scalar)
     _installed = True
   _enabled = True
   return True

@@ -5,8 +5,11 @@ it records milliseconds into the registry histogram ``span/data.next``
 (the aggregate every dashboard reads), and it appends one record to a
 process-wide, bounded ring: ``id``, ``parent`` (the span open on the same
 thread when this one opened), ``name``, ``thread``, ``start_ns``,
-``end_ns`` and a small dict of numeric attributes. ``event(name, ...)``
-appends an instant record (start == end) at the same boundaries.
+``end_ns`` and a small dict of attributes (numbers; the ``compile.*``
+records also name their function). ``event(name, ...)``
+appends an instant record (start == end) at the same boundaries, and
+``interval(name, start_ns, end_ns, ...)`` one the caller measured itself
+(the compile path's listeners, `signals.py`, learn of a phase as it ends).
 
 Every record of every thread is on ONE clock, ``time.perf_counter_ns``,
 so the input producer, the trainer loop and the step-completion watcher
@@ -49,7 +52,7 @@ from typing import List, Optional
 from tensor2robot_tpu.observability import registry as registry_lib
 
 __all__ = ['RING_CAPACITY', 'SpanRecord', 'SpanRing', 'dropped', 'event',
-           'records', 'span']
+           'interval', 'records', 'span']
 
 # Span histograms hold milliseconds: sub-ms histogram bumps up to minutes
 # (a slow checkpoint commit, a cold data pipeline).
@@ -139,6 +142,17 @@ def event(name: str, **attrs) -> None:
   stack = local.stack
   _RING.append((next(_IDS), stack[-1] if stack else 0, name, local.thread,
                 now_ns, now_ns, attrs))
+
+
+def interval(name: str, start_ns: int, end_ns: int, **attrs) -> None:
+  """Appends a closed interval the caller measured on this module's clock
+  (``time.perf_counter_ns``), under the span open on the calling thread.
+  The ring only: the caller keeps whatever aggregate it has of its own
+  (the compile path's are ``jax/compile_ms`` and ``jax/trace_ms``)."""
+  local = _LOCAL
+  stack = local.stack
+  _RING.append((next(_IDS), stack[-1] if stack else 0, name, local.thread,
+                start_ns, end_ns, attrs))
 
 
 class span:  # noqa: N801 — reads as a keyword at call sites

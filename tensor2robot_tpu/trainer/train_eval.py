@@ -378,6 +378,8 @@ class Trainer:
     self._eval_writer = None
     self._telemetry = None
     self._last_goodput = None
+    # Seconds the last ``init_state`` took (its span's ``elapsed``).
+    self._init_state_s = 0.0
     self._device_feed = None
     self._device_feed_built = False
     self._tuned_config = tuned_config
@@ -589,6 +591,14 @@ class Trainer:
     they are run through the preprocessor so variable shapes match what the
     (preprocessed) train step feeds the network.
     """
+    # One span whoever calls (``train``, a driver ahead of it): the parent
+    # of the compile records of the init program and of ``ckpt.restore``.
+    with span('train.init_state', restored=0) as sp:
+      state = self._init_or_restore_state(features, labels, mode, sp)
+    self._init_state_s = sp.elapsed
+    return state
+
+  def _init_or_restore_state(self, features, labels, mode, sp) -> TrainState:
     rng = jax.random.PRNGKey(self.seed)
     features, labels = self.model.preprocessor.preprocess(
         features, labels, mode, rng=jax.random.PRNGKey(self.seed + 2))
@@ -616,7 +626,9 @@ class Trainer:
         _log('Restoring checkpoint at step %d from %s', candidate,
              self.model_dir)
         try:
-          return self.checkpoint_manager.restore(template, step=candidate)
+          state = self.checkpoint_manager.restore(template, step=candidate)
+          sp.note(restored=1)
+          return state
         except CHECKPOINT_SKIP_ERRORS as e:
           last_error = e
           _log('Checkpoint %d in %s failed to restore (%s); trying the '
@@ -967,105 +979,113 @@ class Trainer:
     training reads per-host shards with no extra wiring (the PER_HOST_V2
     contract, ref utils/tfdata.py:43-66).
     """
-    if shard_index is None:
-      shard_index = jax.process_index()
-    if num_shards is None:
-      num_shards = jax.process_count()
-    input_generator = provide_input_generator_with_model_information(
-        input_generator, self.model, ModeKeys.TRAIN)
-    iterator = input_generator.create_dataset_iterator(
-        mode=ModeKeys.TRAIN, shard_index=shard_index, num_shards=num_shards)
-    features, labels = next(iterator)
-    restore_s = 0.0
-    if state is None:
-      # Timed for the recovery timeline: after a preemption this is the
-      # mesh/state rebuild + checkpoint restore phase.
-      restore_t0 = time.perf_counter()
-      state = self.init_state(features, labels)
-      restore_s = time.perf_counter() - restore_t0
-    step_fn = self._compile_train_step()
-    base_rng = jax.device_put(jax.random.PRNGKey(self.seed + 1),
-                              NamedSharding(self.mesh, P()))
-    start_step = int(jax.device_get(state.step))
-    if start_step >= max_train_steps:
-      _log('Checkpoint already at step %d >= max_train_steps %d; skipping.',
-           start_step, max_train_steps)
-      return state
-    batch_size = int(jax.tree_util.tree_leaves(features.to_dict())[0].shape[0])
-    for hook in hooks:
-      hook.begin(self)
-    # perf_counter, not time.time(): steps/sec and goodput must survive
-    # wall-clock jumps (NTP step, DST) — the monotonic-deadline discipline
-    # the reliability layer already follows (docs/reliability.md).
-    t_last = time.perf_counter()
-    steps_since_log = 0
-    metrics = None
-    step_i = start_step
-    batch = (features, labels)
-    # feed_depth > 1: route the train channel through the N-deep
-    # pipelined feed — the producer thread decodes AND transfers batches
-    # ahead while the device computes, so the loop below only ever waits
-    # on an already-resident batch (the wait is the honest goodput
-    # 'data' cost). The first batch — already drawn for init_state — is
-    # chained back in so no data is skipped.
-    pipelined = None
-    if self._feed_depth > 1:
-      import itertools
+    # Everything between the call and the first iteration, under one
+    # name: the first batch, the state, and whatever jax traces, lowers
+    # or compiles for them (the compile.* records, signals.py).
+    with span('train.startup') as startup:
+      if shard_index is None:
+        shard_index = jax.process_index()
+      if num_shards is None:
+        num_shards = jax.process_count()
+      input_generator = provide_input_generator_with_model_information(
+          input_generator, self.model, ModeKeys.TRAIN)
+      # Not ``data.next``: the input metrics read that name over the window.
+      with span('train.first_batch'):
+        iterator = input_generator.create_dataset_iterator(
+            mode=ModeKeys.TRAIN, shard_index=shard_index,
+            num_shards=num_shards)
+        features, labels = next(iterator)
+      restore_s = 0.0
+      if state is None:
+        # For the recovery timeline: after a preemption the span's time is
+        # the mesh/state rebuild + checkpoint restore phase.
+        state = self.init_state(features, labels)
+        restore_s = self._init_state_s
+      step_fn = self._compile_train_step()
+      base_rng = jax.device_put(jax.random.PRNGKey(self.seed + 1),
+                                NamedSharding(self.mesh, P()))
+      start_step = int(jax.device_get(state.step))
+      startup.note(start_step=start_step)
+      if start_step >= max_train_steps:
+        _log('Checkpoint already at step %d >= max_train_steps %d; skipping.',
+             start_step, max_train_steps)
+        return state
+      batch_size = int(
+          jax.tree_util.tree_leaves(features.to_dict())[0].shape[0])
+      for hook in hooks:
+        hook.begin(self)
+      # perf_counter, not time.time(): steps/sec and goodput must survive
+      # wall-clock jumps (NTP step, DST) — the monotonic-deadline discipline
+      # the reliability layer already follows (docs/reliability.md).
+      t_last = time.perf_counter()
+      steps_since_log = 0
+      metrics = None
+      step_i = start_step
+      batch = (features, labels)
+      # feed_depth > 1: route the train channel through the N-deep
+      # pipelined feed — the producer thread decodes AND transfers batches
+      # ahead while the device computes, so the loop below only ever waits
+      # on an already-resident batch (the wait is the honest goodput
+      # 'data' cost). The first batch — already drawn for init_state — is
+      # chained back in so no data is skipped.
+      pipelined = None
+      if self._feed_depth > 1:
+        import itertools
 
-      from tensor2robot_tpu.data.device_feed import PipelinedFeed
+        from tensor2robot_tpu.data.device_feed import PipelinedFeed
 
-      def _host_batch(pair):
-        batch_features, batch_labels = pair
-        return {'features': batch_features.to_dict(),
-                'labels': (batch_labels.to_dict()
-                           if batch_labels is not None else None)}
+        def _host_batch(pair):
+          batch_features, batch_labels = pair
+          return {'features': batch_features.to_dict(),
+                  'labels': (batch_labels.to_dict()
+                             if batch_labels is not None else None)}
 
-      pipelined = PipelinedFeed(
-          map(_host_batch, itertools.chain([batch], iterator)),
-          self._put_batch, depth=self._feed_depth)
-    rollback_budget = self._nan_rollback_budget
-    host_nan_check = self._nan_policy in ('raise', 'rollback')
-    completed = False
-    # Goodput accounting: every loop second lands in exactly one of
-    # productive / data / checkpoint / retry (docs/observability.md).
-    tracker = GoodputTracker()
-    self._last_goodput = tracker
-    registry = get_registry()
-    # Pre-register the well-known reliability counters: a dashboard must
-    # see an explicit 0.0 on a clean run (an absent tag is
-    # indistinguishable from broken wiring — the guarantee the pre-registry
-    # quarantine export already gave).
-    registry.counter(quarantine_lib.RECORDS_SKIPPED_COUNTER)
-    registry.counter(quarantine_lib.FILES_ABANDONED_COUNTER)
-    registry.counter('reliability/nan_rollbacks')
-    registry.counter('reliability/preemptions')
-    registry.gauge(watchdog_lib.RECOMPILE_GAUGE)
-    # Forensics wiring: reports carry the live goodput split plus the
-    # active tuned-config id (attributable perf), and the collective
-    # stats come from relowering the step we just compiled.
-    self._auto_profiler.context_fn = \
-        lambda: {'goodput': tracker.fractions(),
-                 'tuned_config': self.active_config_id,
-                 'host': self.host_identity,
-                 'pipeline': (self._xray.last_record
-                              if self._xray is not None else None)}
-    self._auto_profiler.hlo_text_fn = self._train_step_hlo
-    telemetry = self.telemetry_logger
-    if telemetry is not None:
-      telemetry.log('run_start', step=start_step,
-                    max_train_steps=int(max_train_steps),
-                    batch_size=batch_size, nan_policy=self._nan_policy)
-      telemetry.flush()
-    # A pending recovery marker means the previous incarnation of this
-    # model_dir died in a preemption: the first completed step closes
-    # the recovery timeline (t2r.recovery.v1, fleet.py).
-    pending_recovery = None
-    if telemetry is not None:
-      marker = fleet_lib.consume_recovery_marker(
-          self.model_dir,
-          process_index=self.host_identity.get('process_index'))
-      if marker is not None:
-        pending_recovery = (marker, restore_s, time.perf_counter())
+        pipelined = PipelinedFeed(
+            map(_host_batch, itertools.chain([batch], iterator)),
+            self._put_batch, depth=self._feed_depth)
+      rollback_budget = self._nan_rollback_budget
+      host_nan_check = self._nan_policy in ('raise', 'rollback')
+      completed = False
+      # Goodput accounting: every loop second lands in exactly one of
+      # productive / data / checkpoint / retry (docs/observability.md).
+      tracker = GoodputTracker()
+      self._last_goodput = tracker
+      registry = get_registry()
+      # Pre-register the well-known reliability counters: a dashboard must
+      # see an explicit 0.0 on a clean run (an absent tag is
+      # indistinguishable from broken wiring — the guarantee the pre-registry
+      # quarantine export already gave).
+      registry.counter(quarantine_lib.RECORDS_SKIPPED_COUNTER)
+      registry.counter(quarantine_lib.FILES_ABANDONED_COUNTER)
+      registry.counter('reliability/nan_rollbacks')
+      registry.counter('reliability/preemptions')
+      registry.gauge(watchdog_lib.RECOMPILE_GAUGE)
+      # Forensics wiring: reports carry the live goodput split plus the
+      # active tuned-config id (attributable perf), and the collective
+      # stats come from relowering the step we just compiled.
+      self._auto_profiler.context_fn = \
+          lambda: {'goodput': tracker.fractions(),
+                   'tuned_config': self.active_config_id,
+                   'host': self.host_identity,
+                   'pipeline': (self._xray.last_record
+                                if self._xray is not None else None)}
+      self._auto_profiler.hlo_text_fn = self._train_step_hlo
+      telemetry = self.telemetry_logger
+      if telemetry is not None:
+        telemetry.log('run_start', step=start_step,
+                      max_train_steps=int(max_train_steps),
+                      batch_size=batch_size, nan_policy=self._nan_policy)
+        telemetry.flush()
+      # A pending recovery marker means the previous incarnation of this
+      # model_dir died in a preemption: the first completed step closes
+      # the recovery timeline (t2r.recovery.v1, fleet.py).
+      pending_recovery = None
+      if telemetry is not None:
+        marker = fleet_lib.consume_recovery_marker(
+            self.model_dir,
+            process_index=self.host_identity.get('process_index'))
+        if marker is not None:
+          pending_recovery = (marker, restore_s, time.perf_counter())
 
     def commit_goodput(iter_start, data_s, ckpt_s, retry_s):
       # ``productive`` is the remainder, so the categories partition the

@@ -561,6 +561,22 @@ class TestTelemetryCLI:
     assert 'span/train.step' in result.stdout or 'examples/sec' \
         in result.stdout
 
+  def test_summarize_lists_a_histogram_once(self, trained_run):
+    # A histogram takes ONE line of the listing, not one a statistic: the
+    # once-a-run start-up spans (five equal statistics each, in thousands
+    # of ms) would else crowd out what recurs. Which instruments make the
+    # 15 follows what else the worker's process registry held.
+    result = self._run('summarize', trained_run)
+    assert result.returncode == 0, result.stderr
+    listing = result.stdout.split('events @ step')[1].splitlines()[1:]
+    tags = [line.split()[0] for line in listing]
+    assert len(tags) == len(set(tags)) == 15
+    assert not [tag for tag in tags if tag.rpartition('/')[2] in (
+        'count', 'mean', 'p50', 'p95', 'p99', 'max')]
+    assert not [tag for tag in tags if tag.startswith('span/compile.')]
+    histograms = [line for line in listing if 'count=' in line]
+    assert histograms and all('p99=' in line for line in histograms)
+
   def test_summarize_stage_table_reports_bytes(self, trained_run):
     # ISSUE 10 satellite: per-stage BYTES alongside examples in the
     # pipeline stage table — wire-compression wins must be visible in

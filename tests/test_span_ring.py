@@ -202,3 +202,221 @@ def test_many_threads_lose_no_record_and_share_no_id(mark):
     assert r.parent == 0
   assert obs.get_registry().scalars()['span/stress/count'] == \
       threads * per_thread
+
+
+# -- a closed interval, and the compile path that writes them ------------------
+
+
+def test_an_interval_the_caller_measured_lies_under_the_open_span(
+    mark, fresh_registry):
+  with obs.span('holder'):
+    spans.interval('measured', 1_000, 4_000_000, fun='f', inner=2)
+  spans.interval('loose', 5, 5)
+  got = _by_name(mark)
+  assert got['measured'].parent == got['holder'].id
+  assert (got['measured'].start_ns, got['measured'].end_ns) == (
+      1_000, 4_000_000)
+  assert got['measured'].attrs == {'fun': 'f', 'inner': 2}
+  assert got['measured'].thread == threading.current_thread().name
+  assert got['loose'].parent == 0
+  # The ring only: whoever measured the interval keeps its own aggregate.
+  assert 'span/measured/count' not in fresh_registry.scalars()
+
+
+PHASES = ('compile.trace', 'compile.lower', 'compile.backend')
+
+
+def _compile_outer():
+  """A jitted function, new each call, that calls an inner jitted one."""
+  import jax
+  import jax.numpy as jnp
+
+  @jax.jit
+  def inner(x):
+    return jnp.where(x > 0, x, 0.0) * 2
+
+  @jax.jit
+  def outer(x):
+    return inner(x).sum() + 1
+
+  return outer
+
+
+@pytest.fixture
+def listeners():
+  from tensor2robot_tpu.observability import signals
+
+  import jax.numpy as jnp
+
+  was_enabled = signals._enabled
+  # The tests' operand is a compile request of its own the first time a
+  # process builds it (whichever case a worker runs first): not theirs.
+  obs.uninstall_jax_listeners()
+  jnp.ones((4,))
+  obs.install_jax_listeners()
+  yield signals
+  if was_enabled:
+    obs.install_jax_listeners()
+  else:
+    obs.uninstall_jax_listeners()
+
+
+@pytest.mark.parametrize('case', [
+    'one_record_a_phase_naming_the_outer_function',
+    'disjoint_and_in_order_on_the_thread',
+    'a_second_call_writes_none',
+    'the_parent_is_the_open_span',
+    'another_thread_has_its_own_phases',
+    'uninstall_stops_the_records',
+    'the_counters_count_what_they_counted',
+    'an_eager_op_is_a_request_of_its_own',
+])
+def test_the_compile_path_writes_its_outermost_phases_into_the_ring(
+    case, listeners, fresh_registry):
+  import jax.numpy as jnp
+
+  operand = jnp.ones((4,))
+  outer = _compile_outer()
+  spans.event('test.mark')
+  mark = max(r.id for r in spans.records())
+
+  def phases():
+    return [r for r in spans.records(since_id=mark) if r.name in PHASES]
+
+  if case == 'uninstall_stops_the_records':
+    obs.uninstall_jax_listeners()
+    outer(operand)
+    assert phases() == []
+    assert 'jax/compiles' not in fresh_registry.scalars()
+    # Phases that opened and closed while it was off left nothing open:
+    # the next outermost phase is a record again.
+    obs.install_jax_listeners()
+    _compile_outer()(operand)
+    assert [r.name for r in phases()] == list(PHASES)
+    return
+  if case == 'an_eager_op_is_a_request_of_its_own':
+    jnp.ones((3, 5, 7)) @ jnp.ones((7, 2))
+    backends = [r for r in phases() if r.name == 'compile.backend']
+    assert len(backends) >= 1
+    assert fresh_registry.scalars()['jax/compiles'] == len(backends)
+    return
+  if case == 'another_thread_has_its_own_phases':
+    with obs.span('main.open'):
+      thread = threading.Thread(target=outer, args=(operand,),
+                                name='test-compiler')
+      thread.start()
+      thread.join(timeout=60)
+    got = phases()
+    assert [r.name for r in got] == list(PHASES)
+    assert {r.thread for r in got} == {'test-compiler'}
+    assert {r.parent for r in got} == {0}
+    return
+
+  with obs.span('holder'):
+    outer(operand)
+  got = phases()
+  assert [r.name for r in got] == list(PHASES)
+  trace, lower, backend = got
+  if case == 'one_record_a_phase_naming_the_outer_function':
+    # jax's own words: the function for the trace, jit(function) after it.
+    assert trace.attrs['fun'] == 'outer'
+    assert lower.attrs['fun'] == backend.attrs['fun'] == 'jit(outer)'
+    # inner, where, multiply, sum, add ... were traced inside outer's trace
+    # and folded into it.
+    assert trace.attrs['inner'] >= 1
+    assert set(trace.attrs) == set(lower.attrs) == {'fun', 'inner'}
+    assert backend.attrs == {'fun': 'jit(outer)', 'from_cache': 0,
+                             'cache_read_ms': 0.0}
+  elif case == 'disjoint_and_in_order_on_the_thread':
+    assert {r.thread for r in got} == {threading.current_thread().name}
+    edges = [t for r in got for t in (r.start_ns, r.end_ns)]
+    assert edges == sorted(edges)
+    assert all(r.start_ns < r.end_ns for r in got)
+    holder = _by_name(mark)['holder']
+    assert holder.start_ns <= trace.start_ns
+    assert backend.end_ns <= holder.end_ns
+  elif case == 'a_second_call_writes_none':
+    outer(operand)
+    assert len(phases()) == 3
+  elif case == 'the_parent_is_the_open_span':
+    holder = _by_name(mark)['holder']
+    assert {r.parent for r in got} == {holder.id}
+    # The compile path's aggregates stay jax/compile_ms and jax/trace_ms.
+    assert not [tag for tag in fresh_registry.scalars()
+                if tag.startswith('span/compile.')]
+  elif case == 'the_counters_count_what_they_counted':
+    scalars = fresh_registry.scalars()
+    # One compile request, one histogram entry for it; every trace event,
+    # folded or not, in jax/trace_ms as before.
+    assert scalars['jax/compiles'] == 1.0
+    assert scalars['jax/compile_ms/count'] == 1.0
+    assert scalars['jax/compile_ms/max'] == pytest.approx(
+        (backend.end_ns - backend.start_ns) / 1e6, rel=0.05, abs=0.5)
+    traced = scalars['jax/trace_ms/count']
+    assert 1 + trace.attrs['inner'] <= traced <= (
+        1 + trace.attrs['inner'] + lower.attrs['inner'])
+
+
+def test_a_backend_compile_inside_a_trace_is_still_a_record(
+    listeners, fresh_registry):
+  """An operation run eagerly while a function is traced compiles inside
+  that trace: its trace and lowering fold into the outer record, its
+  backend compile is a record of its own, inside the outer one."""
+  import jax
+  import jax.numpy as jnp
+
+  @jax.jit
+  def outer(x):
+    with jax.ensure_compile_time_eval():
+      table = jnp.cumsum(jnp.arange(11.0))  # eager, compiled here
+    return x * table[3]
+
+  spans.event('test.mark')
+  mark = max(r.id for r in spans.records())
+  outer(jnp.ones((4,)))
+  got = [r for r in spans.records(since_id=mark) if r.name in PHASES]
+  traces = [r for r in got if r.name == 'compile.trace']
+  backends = [r for r in got if r.name == 'compile.backend']
+  assert [r.attrs['fun'] for r in traces] == ['outer']
+  assert len(backends) >= 2 and backends[-1].attrs['fun'] == 'jit(outer)'
+  nested = [b for b in backends if traces[0].start_ns <= b.start_ns
+            and b.end_ns <= traces[0].end_ns]
+  assert nested and nested == backends[:-1]
+  assert fresh_registry.scalars()['jax/compiles'] == len(backends)
+
+
+_CACHED_COMPILE = '''
+import json, sys
+import jax, jax.numpy as jnp
+jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
+from tensor2robot_tpu import observability as obs
+from tensor2robot_tpu.observability import spans
+obs.install_jax_listeners()
+operand = jnp.ones((16, 16))
+mark = max([r.id for r in spans.records()] or [0])
+jax.jit(lambda x: (x @ x).sum(), keep_unused=True)(operand)
+print(json.dumps([r.attrs for r in spans.records(since_id=mark)
+                  if r.name == 'compile.backend']))
+'''
+
+
+def test_a_persistent_cache_hit_is_noted_with_the_time_the_read_took(
+    tmp_path):
+  import json
+  import os
+  import subprocess
+
+  root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  env = dict(os.environ, JAX_PLATFORMS='cpu', PYTHONPATH=root,
+             JAX_COMPILATION_CACHE_DIR=str(tmp_path / 'cache'))
+  runs = []
+  for _ in range(2):  # two fresh processes, one cache directory
+    done = subprocess.run([sys.executable, '-c', _CACHED_COMPILE], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    runs.append(json.loads(done.stdout.splitlines()[-1]))
+  (cold,), (warm,) = runs
+  assert cold['from_cache'] == 0 and cold['cache_read_ms'] == 0.0
+  assert warm['from_cache'] == 1 and warm['cache_read_ms'] > 0
+  assert cold['fun'] == warm['fun']

@@ -2,6 +2,7 @@
 under a name, the step-completion watcher, the profiler's clock marker, and
 idle gaps named from ring records."""
 
+import shutil
 import threading
 import time
 
@@ -352,3 +353,162 @@ def test_forensics_names_a_hand_made_gap_from_hand_made_records():
                   'train.iteration': pytest.approx(0.003)}
   assert forensics_lib.name_idle_gaps(busy, []) == {
       forensics_lib.NO_HOST_EVENT: pytest.approx(0.006)}
+
+
+# -- the start-up -------------------------------------------------------------
+
+COMPILE = ('compile.trace', 'compile.lower', 'compile.backend')
+
+
+@pytest.fixture(scope='module')
+def started(tmp_path_factory):
+  """One fresh ten-step run; the ring's records of it, in start order."""
+  spans.event('test.mark')
+  mark = max(r.id for r in spans.records())
+  trainer = _trainer(tmp_path_factory.mktemp('startup'))
+  trainer.train(MockInputGenerator(batch_size=8), max_train_steps=10)
+  trainer.close()
+  return sorted(spans.records(since_id=mark), key=lambda r: r.start_ns)
+
+
+@pytest.fixture(scope='module')
+def trained_two_steps(tmp_path_factory):
+  """A model directory with a checkpoint at step 2; copy it, do not use it."""
+  root = tmp_path_factory.mktemp('trained')
+  trainer = _trainer(root)
+  trainer.train(MockInputGenerator(batch_size=8), max_train_steps=2)
+  trainer.close()
+  return root / 'run'
+
+
+def _one(records, name, **attrs):
+  found = [r for r in records if r.name == name and
+           all(r.attrs.get(k) == v for k, v in attrs.items())]
+  assert len(found) == 1, (name, attrs, len(found))
+  return found[0]
+
+
+@pytest.mark.parametrize('case', [
+    'startup_holds_the_first_batch_and_the_state',
+    'startup_ends_where_the_first_iteration_starts',
+    'the_first_step_has_compile_children_the_tenth_none',
+    'the_init_program_compiles_under_init_state',
+    'nothing_compiles_under_no_span',
+])
+def test_the_start_up_of_a_fresh_run_is_in_the_ring(started, case):
+  startup = _one(started, 'train.startup')
+  first_batch = _one(started, 'train.first_batch')
+  init_state = _one(started, 'train.init_state')
+  compiles = [r for r in started if r.name in COMPILE]
+  if case == 'startup_holds_the_first_batch_and_the_state':
+    assert startup.parent == 0 and startup.attrs == {'start_step': 0}
+    assert first_batch.parent == init_state.parent == startup.id
+    assert init_state.attrs == {'restored': 0}
+    assert startup.start_ns <= first_batch.start_ns
+    assert first_batch.end_ns <= init_state.start_ns
+    assert init_state.end_ns <= startup.end_ns
+    assert startup.thread == threading.current_thread().name
+    # The first batch is NOT a data.next: the input metrics read that name.
+    assert not [r for r in started if r.name == 'data.next'
+                and r.start_ns < startup.end_ns]
+  elif case == 'startup_ends_where_the_first_iteration_starts':
+    first = _one(started, 'train.iteration', step=1)
+    # Between the two: the signal handlers and the step watcher's thread.
+    assert 0 <= first.start_ns - startup.end_ns < 50e6
+    assert first.parent == 0
+    assert not [r for r in started if r.name != 'test.mark' and
+                r.thread == startup.thread and r.start_ns < startup.start_ns]
+  elif case == 'the_first_step_has_compile_children_the_tenth_none':
+    first, tenth = (_one(started, 'train.step', step=n) for n in (1, 10))
+    children = [r for r in compiles if r.parent == first.id]
+    assert [r.name for r in children] == list(COMPILE)
+    assert children[0].attrs['fun'] == 'step'
+    assert children[2].attrs['fun'] == 'jit(step)'
+    for r in children:
+      assert first.start_ns <= r.start_ns and r.end_ns <= first.end_ns
+    # They say what the first step's seconds were: most of the record.
+    covered = sum(r.end_ns - r.start_ns for r in children)
+    assert covered > 0.5 * (first.end_ns - first.start_ns)
+    assert not [r for r in compiles if r.parent == tenth.id]
+    later = [r for r in started if r.name == 'train.step'
+             and r.attrs['step'] > 1]
+    assert not [r for r in compiles if r.parent in {s.id for s in later}]
+  elif case == 'the_init_program_compiles_under_init_state':
+    under = [r for r in compiles if r.parent == init_state.id]
+    assert {r.name for r in under} == set(COMPILE)
+    assert all(init_state.start_ns <= r.start_ns and
+               r.end_ns <= init_state.end_ns for r in under)
+  elif case == 'nothing_compiles_under_no_span':
+    # Every compile of Trainer.train has a program span over it.
+    assert not [r for r in compiles if r.parent == 0 and
+                startup.start_ns <= r.start_ns]
+
+
+@pytest.mark.parametrize('case', [
+    'a_state_handed_in_leaves_init_state_outside_the_startup',
+    'a_restore_is_noted_and_timed_once',
+    'a_start_up_that_raises_leaves_the_stack_clean',
+    'nothing_left_to_train_still_closes_the_startup',
+])
+def test_the_start_up_spans_on_the_other_paths(tmp_path, mark, case,
+                                               trained_two_steps):
+  generator = MockInputGenerator(batch_size=8)
+  if case == 'a_state_handed_in_leaves_init_state_outside_the_startup':
+    # As the benchmark's drivers do: the state first, then train().
+    trainer = _trainer(tmp_path)
+    generator = train_eval.provide_input_generator_with_model_information(
+        generator, trainer.model, train_eval.ModeKeys.TRAIN)
+    features, labels = next(generator.create_dataset_iterator(
+        mode=train_eval.ModeKeys.TRAIN))
+    state = trainer.init_state(features, labels)
+    trainer.train(generator, max_train_steps=2, state=state)
+    trainer.close()
+    records = spans.records(since_id=mark)
+    init_state = _one(records, 'train.init_state')
+    startup = _one(records, 'train.startup')
+    assert init_state.parent == 0
+    assert init_state.end_ns <= startup.start_ns
+    assert trainer._init_state_s == pytest.approx(
+        (init_state.end_ns - init_state.start_ns) * 1e-9)
+  elif case == 'a_restore_is_noted_and_timed_once':
+    shutil.copytree(trained_two_steps, tmp_path / 'run')
+    trainer = _trainer(tmp_path)
+    trainer.train(generator, max_train_steps=3)
+    trainer.close()
+    records = spans.records(since_id=mark)
+    init_state = _one(records, 'train.init_state')
+    assert init_state.attrs == {'restored': 1}
+    assert _one(records, 'train.startup').attrs == {'start_step': 2}
+    restore = _one(records, 'ckpt.restore')
+    assert restore.parent == init_state.id
+    # What the recovery timeline calls restore_s is this span's time.
+    assert trainer._init_state_s == pytest.approx(
+        (init_state.end_ns - init_state.start_ns) * 1e-9)
+  elif case == 'a_start_up_that_raises_leaves_the_stack_clean':
+    class Broken(MockInputGenerator):
+
+      def create_dataset_iterator(self, **kwargs):
+        raise RuntimeError('no data')
+
+    trainer = _trainer(tmp_path)
+    with pytest.raises(RuntimeError):
+      trainer.train(Broken(batch_size=8), max_train_steps=2)
+    trainer.close()
+    with obs.span('after'):
+      pass
+    records = spans.records(since_id=mark)
+    startup = _one(records, 'train.startup')
+    assert _one(records, 'train.first_batch').parent == startup.id
+    assert _one(records, 'after').parent == 0
+    assert not [r for r in records if r.name == 'train.iteration']
+  elif case == 'nothing_left_to_train_still_closes_the_startup':
+    shutil.copytree(trained_two_steps, tmp_path / 'run')
+    trainer = _trainer(tmp_path)
+    trainer.train(generator, max_train_steps=2)
+    trainer.close()
+    records = spans.records(since_id=mark)
+    assert _one(records, 'train.startup').attrs == {'start_step': 2}
+    assert not [r for r in records if r.name == 'train.iteration']
+    with obs.span('after'):
+      pass
+    assert _one(spans.records(since_id=mark), 'after').parent == 0
