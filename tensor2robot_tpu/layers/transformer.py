@@ -333,29 +333,75 @@ def blocked_cross_entropy(hidden, head, targets, weights, block_tokens: int,
   logits (``hidden`` @ ``head``) against its target, in f32.
 
   hidden [B, L, d], head [d, V], targets and weights [B, L]. The logits are
-  formed ``block_tokens`` tokens at a time under ``jax.checkpoint``: a
-  block's [block, V] f32 logits live only inside its own forward and
-  backward."""
+  formed ``block_tokens`` tokens at a time: a block's [block, V] f32 logits
+  live only inside its own step of the loop. Under differentiation that
+  step forms them ONCE and gives the loss and both gradients
+  (``_head_loss``); ``targets`` and ``weights`` get no cotangent."""
   b, l, d = hidden.shape
   n = b * l
   block = max(c for c in range(1, min(block_tokens, n) + 1) if n % c == 0)
-  # Cast once, outside the loop: the loop's backward pass then stacks the
-  # rows' gradients at this width, not in float32.
+  # Cast once, outside the loop: the rows' gradients are then stacked at
+  # this width, not in float32.
   head, hidden = head.astype(dtype), hidden.astype(dtype)
-
-  @jax.checkpoint
-  def block_loss(args):
-    rows, target, weight = args
-    logits = jnp.dot(rows, head, preferred_element_type=jnp.float32)
-    picked = jnp.take_along_axis(logits, target[:, None], axis=-1)[:, 0]
-    return jnp.sum(weight * (jax.nn.logsumexp(logits, axis=-1) - picked))
-
   with jax.named_scope('head_loss'):
-    sums = jax.lax.map(
-        block_loss, (hidden.reshape(n // block, block, d),
-                     targets.reshape(n // block, block),
-                     weights.astype(jnp.float32).reshape(n // block, block)))
-  return jnp.sum(sums)
+    return _head_loss(hidden.reshape(n // block, block, d), head,
+                      targets.reshape(n // block, block),
+                      weights.astype(jnp.float32).reshape(n // block, block))
+
+
+def _block_logits(rows, head, target, weight):
+  """A block's f32 logits and its weighted cross-entropy sum."""
+  logits = jnp.dot(rows, head, preferred_element_type=jnp.float32)
+  picked = jnp.take_along_axis(logits, target[:, None], axis=-1)[:, 0]
+  lse = jax.nn.logsumexp(logits, axis=-1)
+  return logits, lse, jnp.sum(weight * (lse - picked))
+
+
+@jax.custom_vjp
+def _head_loss(rows, head, targets, weights):
+  """Sum of the blocks' losses; rows [blocks, block, d], head [d, V].
+  Undifferentiated (eval, predict): one product over the vocabulary a
+  block."""
+  return jnp.sum(jax.lax.map(
+      lambda args: _block_logits(args[0], head, *args[1:])[2],
+      (rows, targets, weights)))
+
+
+def _head_loss_fwd(rows, head, targets, weights):
+  """One pass over the blocks that forms each block's logits once and
+  gives its loss, its rows' gradient dh (stacked) and its share of the
+  head's gradient dW (carried at the head's width): three products over
+  the vocabulary a block, where the loss's forward and its transpose took
+  four."""
+  from tensor2robot_tpu.observability import get_registry
+
+  get_registry().gauge('head_loss/vocab_products').set(3.0)
+  vocab = jax.lax.broadcasted_iota(jnp.int32, (1, head.shape[1]), 1)
+
+  def step(d_head, args):
+    block_rows, target, weight = args
+    logits, lse, loss = _block_logits(block_rows, head, target, weight)
+    # w (softmax - onehot), the onehot a comparison, in the compute dtype.
+    p = jnp.exp(logits - lse[:, None])
+    dlogits = (weight[:, None] * jnp.where(vocab == target[:, None], p - 1.0,
+                                           p)).astype(head.dtype)
+    d_rows = jnp.dot(dlogits, head.T, preferred_element_type=jnp.float32)
+    d_head = d_head + jnp.dot(block_rows.T, dlogits,
+                              preferred_element_type=jnp.float32)
+    return d_head.astype(head.dtype), (loss, d_rows.astype(rows.dtype))
+
+  d_head, (losses, d_rows) = jax.lax.scan(
+      step, jnp.zeros_like(head), (rows, targets, weights))
+  return jnp.sum(losses), (d_rows, d_head)
+
+
+def _head_loss_bwd(residuals, g):
+  d_rows, d_head = residuals
+  return ((d_rows * g).astype(d_rows.dtype), (d_head * g).astype(d_head.dtype),
+          None, None)
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
 class GroupedQueryAttention(nn.Module):
