@@ -246,18 +246,21 @@ def route_top_k(router_logits: jnp.ndarray, top_k: int):
 
 
 def route_sigmoid_bias(router_logits: jnp.ndarray, bias: jnp.ndarray,
-                       top_k: int):
+                       top_k: int, scale: float = 1.0):
   """(expert index [T, k] int32, weight [T, k] f32) of a sigmoid router with
   a selection bias (auxiliary-loss-free balancing: DeepSeek-V3, LFM2): every
   expert scores s = sigmoid(logit) on its own; the ``top_k`` largest of
-  s + ``bias`` [E] are CHOSEN, and weigh s / (sum of the chosen s + 1e-6).
-  The bias chooses and never weighs; no gradient reaches it (it is state,
-  moved by ``balanced_bias``)."""
+  s + ``bias`` [E] are CHOSEN, and weigh ``scale`` x s / (sum of the chosen
+  s + 1e-6) (``scale`` is a config's ``routed_scaling_factor``). The bias
+  chooses and never weighs; no gradient reaches it (it is state, moved by
+  ``balanced_bias``)."""
   scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
   _, index = jax.lax.top_k(
       scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
   chosen = jnp.take_along_axis(scores, index, axis=-1)
   weight = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
+  if scale != 1.0:
+    weight = weight * scale
   return index.astype(jnp.int32), weight
 
 

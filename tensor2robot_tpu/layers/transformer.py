@@ -54,15 +54,18 @@ _FLASH_MIN_LENGTH = 2048
 
 def scaled_dot_attention(q, k, v, causal: bool,
                          window: Optional[int] = None,
-                         block_diffusion: Optional[Tuple[int, int]] = None
-                         ) -> jnp.ndarray:
+                         block_diffusion: Optional[Tuple[int, int]] = None,
+                         scale: Optional[float] = None) -> jnp.ndarray:
   """Dense [B, L, H, D] attention in f32 accumulation (the oracle path).
 
   k/v with fewer heads than q are grouped-query heads (query head n reads
   head n // group); ``window`` keeps columns j with 0 <= i - j < window;
   ``block_diffusion`` = (length, block), not causal, is the mask of
-  ``flash_attention``'s argument of that name over 2 x length positions."""
-  scale = 1.0 / np.sqrt(q.shape[-1])
+  ``flash_attention``'s argument of that name over 2 x length positions.
+  v may be narrower or wider than q and k; ``scale`` defaults to 1 /
+  sqrt of their width."""
+  if scale is None:
+    scale = 1.0 / np.sqrt(q.shape[-1])
   group = q.shape[2] // k.shape[2]
   if group > 1:
     k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
@@ -100,26 +103,31 @@ def resolve_attention_mode(mode: str, seq_length: int) -> str:
 def run_attention(q, k, v, *, mode: str, causal: bool,
                   mesh=None, seq_axis: str = 'data',
                   window: Optional[int] = None,
-                  block_diffusion: Optional[Tuple[int, int]] = None
-                  ) -> jnp.ndarray:
+                  block_diffusion: Optional[Tuple[int, int]] = None,
+                  scale: Optional[float] = None) -> jnp.ndarray:
   """Dispatches [B, L, H, D] self-attention to the selected backend.
 
-  Grouped-query heads (k/v with fewer heads), ``window`` and the
-  ``block_diffusion`` mask are the dense and flash backends'; the ring
+  Grouped-query heads (k/v with fewer heads), ``window``, the
+  ``block_diffusion`` mask, a value width unlike the key width and a
+  ``scale`` of the caller's are the dense and flash backends'; the ring
   backend has none of them."""
   mode = resolve_attention_mode(mode, q.shape[1])
   if mode == 'xla':
-    return scaled_dot_attention(q, k, v, causal, window, block_diffusion)
+    return scaled_dot_attention(q, k, v, causal, window, block_diffusion,
+                                scale)
   if mode == 'flash':
-    return flash_lib.flash_attention(q, k, v, causal=causal, window=window,
+    return flash_lib.flash_attention(q, k, v, causal=causal, scale=scale,
+                                     window=window,
                                      block_diffusion=block_diffusion)
   if mode == 'ring':
     if mesh is None:
       raise ValueError("attention_mode='ring' requires a mesh.")
     if window is not None or k.shape[2] != q.shape[2] or \
-        block_diffusion is not None:
+        block_diffusion is not None or scale is not None or \
+        v.shape[-1] != q.shape[-1]:
       raise ValueError("attention_mode='ring' has no window, no "
-                       'grouped-query heads and no block-diffusion mask.')
+                       'grouped-query heads, no block-diffusion mask, no '
+                       'scale of its own and one head width.')
     return ring_lib.ring_self_attention(q, k, v, mesh, seq_axis=seq_axis,
                                         causal=causal)
   raise ValueError('Unknown attention mode: {!r}'.format(mode))
@@ -311,12 +319,17 @@ class RMSNorm(nn.Module):
 
 
 def rotary_positions(x: jnp.ndarray, theta: float,
-                     positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                     positions: Optional[jnp.ndarray] = None,
+                     inverse: Optional[np.ndarray] = None) -> jnp.ndarray:
   """Rotary position embedding of [B, L, H, D], rotate-half pairing
   (dimension i with i + D/2), in f32. ``positions`` [L] are the position
-  ids of the rows (None: the index in the sequence)."""
+  ids of the rows (None: the index in the sequence). ``inverse`` [D/2]: the
+  frequencies (None: theta^(-2i/D); ``yarn_frequencies`` gives YaRN's)."""
   d = x.shape[-1]
-  inverse = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+  if inverse is None:
+    inverse = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+  else:
+    inverse = jnp.asarray(inverse, jnp.float32)
   if positions is None:
     positions = jnp.arange(x.shape[1])
   angle = positions.astype(jnp.float32)[:, None] * inverse[None]
@@ -325,6 +338,30 @@ def rotary_positions(x: jnp.ndarray, theta: float,
   x = x.astype(jnp.float32)
   first, second = x[..., :d // 2], x[..., d // 2:]
   return x * cos + jnp.concatenate([-second, first], axis=-1) * sin
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
+                     beta_fast: float, beta_slow: float) -> np.ndarray:
+  """YaRN's rotary frequencies [dim/2] (Peng et al. 2023, arXiv:2309.00071,
+  as DeepSeek-V2/V3 compute them): f_i = theta^(-2i/dim) kept where a
+  dimension turns more than ``beta_fast`` times over the ``original``
+  context, divided by ``factor`` where it turns fewer than ``beta_slow``
+  times, and a linear ramp between over the dimensions
+  low = floor(c(beta_fast)) .. high = ceil(c(beta_slow)), c(r) = dim
+  ln(original / (2 pi r)) / (2 ln theta)."""
+  frequency = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+  turns = lambda rotations: dim * np.log(original / (rotations * 2 * np.pi)) / (
+      2 * np.log(theta))
+  low = max(int(np.floor(turns(beta_fast))), 0)
+  high = min(int(np.ceil(turns(beta_slow))), dim - 1)
+  ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+  return (frequency / factor * ramp + frequency * (1 - ramp)).astype(
+      np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+  """YaRN's attention temperature term: 0.1 mscale ln(factor) + 1."""
+  return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
 
 
 def blocked_cross_entropy(hidden, head, targets, weights, block_tokens: int,
@@ -459,6 +496,89 @@ class GroupedQueryAttention(nn.Module):
                     name='out')(out.reshape(b, l, -1))
 
 
+class LatentAttention(nn.Module):
+  """Multi-head latent attention (DeepSeek-V2/V3): queries and keys/values
+  through low-rank bottlenecks, a rotary key SHARED by all heads, and values
+  narrower or wider than the keys. On the input h [B, L, d]:
+
+    c_q = rmsnorm(h W_qa);  q = c_q W_qb = [q_nope ; q_pe] a head
+    [c_kv ; k_pe] = h W_kva;  [k_nope ; v] = rmsnorm(c_kv) W_kvb a head
+    q_h = [q_nope ; R(q_pe)],  k_h = [k_nope ; R(k_pe)]  (k_pe one for all)
+    o_h = softmax(scale q_h k_h^T + causal) v_h;  out = concat_h(o_h) W_o
+
+  R is the rotary embedding (rotate-half pairing) at YaRN's frequencies
+  when ``rope_scaling`` = (factor, original context, beta_fast, beta_slow,
+  mscale, mscale_all_dim) is given, plain ones at ``rope_theta``
+  otherwise; scale is yarn_mscale(factor, mscale_all_dim)^2 / sqrt(nope +
+  rope width), and cos and sin are multiplied by yarn_mscale(factor,
+  mscale) / yarn_mscale(factor, mscale_all_dim). No bias anywhere; the
+  parameters keep DeepSeek's names (q_a, q_a_norm, q_b, kv_a, kv_a_norm,
+  kv_b, out). The attention is ``run_attention``'s, the flash kernels at
+  key width nope + rope and value width ``v_head_dim``."""
+
+  num_heads: int
+  q_lora_rank: int
+  kv_lora_rank: int
+  qk_nope_head_dim: int
+  qk_rope_head_dim: int
+  v_head_dim: int
+  rope_theta: float
+  rope_scaling: Optional[Tuple[float, ...]] = None
+  eps: float = 1e-6
+  attention_mode: str = 'auto'
+  out_init_std: float = 0.02    # of `out`, which writes into the residual
+  dtype: jnp.dtype = jnp.float32
+
+  def rotary(self):
+    """(frequencies [rope width / 2] or None, the factor on cos and sin,
+    the attention's scale)."""
+    width = self.qk_nope_head_dim + self.qk_rope_head_dim
+    if self.rope_scaling is None:
+      return None, 1.0, 1.0 / float(np.sqrt(width))
+    factor, original, fast, slow, mscale, mscale_all = self.rope_scaling
+    inverse = yarn_frequencies(self.qk_rope_head_dim, self.rope_theta,
+                               factor, int(original), fast, slow)
+    temperature = yarn_mscale(factor, mscale_all)
+    return (inverse, yarn_mscale(factor, mscale) / temperature,
+            temperature ** 2 / float(np.sqrt(width)))
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray,
+               positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    b, l, d = x.shape
+    heads, nope, rope = (self.num_heads, self.qk_nope_head_dim,
+                         self.qk_rope_head_dim)
+    dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                              kernel_init=nn.initializers.normal(0.02))
+    c_q = RMSNorm(self.eps, name='q_a_norm')(
+        dense(self.q_lora_rank, name='q_a')(x)).astype(self.dtype)
+    q = dense(heads * (nope + rope), name='q_b')(c_q).reshape(
+        b, l, heads, nope + rope)
+    compressed = dense(self.kv_lora_rank + rope, name='kv_a')(x)
+    c_kv = RMSNorm(self.eps, name='kv_a_norm')(
+        compressed[..., :self.kv_lora_rank]).astype(self.dtype)
+    kv = dense(heads * (nope + self.v_head_dim), name='kv_b')(c_kv).reshape(
+        b, l, heads, nope + self.v_head_dim)
+    inverse, on_cos_sin, scale = self.rotary()
+
+    def rotated(t):
+      t = rotary_positions(t, self.rope_theta, positions, inverse)
+      return (t * on_cos_sin if on_cos_sin != 1.0 else t).astype(self.dtype)
+
+    q_pe = rotated(q[..., nope:])
+    k_pe = rotated(compressed[..., self.kv_lora_rank:].reshape(b, l, 1, rope))
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (b, l, heads, rope))],
+        axis=-1)
+    with jax.named_scope('attention'):
+      out = run_attention(q, k, kv[..., nope:], mode=self.attention_mode,
+                          causal=True, scale=scale)
+    return nn.Dense(d, use_bias=False, dtype=self.dtype,
+                    kernel_init=nn.initializers.normal(self.out_init_std),
+                    name='out')(out.reshape(b, l, -1))
+
+
 class ShortConvolution(nn.Module):
   """A gated short convolution, the token mixer that is not attention:
 
@@ -513,17 +633,58 @@ class GatedMLP(nn.Module):
                  name='w2')(hidden)
 
 
+class StreamMaps(nn.Module):
+  """The maps of one sublayer over ``n`` residual streams
+  (``parallel/hyper_connections.py`` has the equations): phi_pre, phi_post
+  [n C, n] and phi_res [n C, n^2] (normal(0.02)), the gains alpha_pre,
+  alpha_post, alpha_res (ones) and the biases b_pre, b_post [n], b_res
+  [n^2] (normal(1): seeded draws that make the maps differ by stream, so
+  that a fault in a map shows). ``__call__(state [rows, n C] f32)`` -> (h [rows, C] f32, maps,
+  the state to hand to ``hc_post``); the projection runs at ``dtype``."""
+
+  n: int
+  iters: int = 20
+  eps: float = 1e-6
+  clamp: float = 30.0
+  dtype: jnp.dtype = jnp.float32
+
+  @nn.compact
+  def __call__(self, state: jnp.ndarray):
+    from tensor2robot_tpu.parallel import hyper_connections as hc_lib
+
+    n, width = self.n, state.shape[-1]
+    init = nn.initializers.normal(0.02)
+    bias_init = nn.initializers.normal(1.0)
+    phi = jnp.concatenate([
+        self.param('phi_pre', init, (width, n), jnp.float32),
+        self.param('phi_post', init, (width, n), jnp.float32),
+        self.param('phi_res', init, (width, n * n), jnp.float32)], axis=1)
+    alpha = jnp.stack([self.param(name, nn.initializers.ones, (), jnp.float32)
+                       for name in ('alpha_pre', 'alpha_post', 'alpha_res')])
+    bias = jnp.concatenate([
+        self.param('b_pre', bias_init, (n,), jnp.float32),
+        self.param('b_post', bias_init, (n,), jnp.float32),
+        self.param('b_res', bias_init, (n * n,), jnp.float32)])
+    with jax.named_scope('hc_pre'):
+      return hc_lib.hc_pre(state, phi.astype(self.dtype), alpha, bias, n=n,
+                           iters=self.iters, eps=self.eps, clamp=self.clamp)
+
+
 class MoEBlock(nn.Module):
   """Pre-norm block of a token mixer and a feed-forward:
 
     x1 = x + mixer(rmsnorm(x));  u = rmsnorm(x1);  out = x1 + ff(u)
 
   Both are FIELDS. ``mixer``: ``'attention'`` (grouped-query attention:
-  window, rotary positions, q/k norm, the block-diffusion mask) or
-  ``'short_conv'`` (``ShortConvolution``; it takes no positions).
+  window, rotary positions, q/k norm, the block-diffusion mask),
+  ``'short_conv'`` (``ShortConvolution``; it takes no positions) or
+  ``'latent_attention'`` (``LatentAttention``: the ``q_lora_rank`` ..
+  ``v_head_dim`` fields and ``rope_scaling``; ``num_heads`` its heads).
   ``feed_forward``: ``'experts'``, the dropless routed experts, which are
   told which experts they hold (layers/moe.py::DroplessMoE) and how their
   gate is activated, or ``'dense'``, one SwiGLU of width ``dense_dim``.
+  ``shared_expert_dim``: with experts, one more SwiGLU of that width that
+  every token goes through, unweighted, added to the routed experts' sum.
 
   With experts, the router's logits r (f32) read what ``router_reads``
   says: ``'input'``, the block's INPUT before the mixer, r = x W_r (the
@@ -531,21 +692,30 @@ class MoEBlock(nn.Module):
   r = u W_r (the usual place); and ``router`` says what is made of them:
   ``'softmax'`` (the ``top_k`` largest logits, softmax over just those) or
   ``'sigmoid_bias'`` (``route_sigmoid_bias``: the largest sigmoid + bias are
-  chosen, the sigmoids alone weigh). The bias [num_experts] f32 is no
-  parameter: it lives in the collection ``router_state`` and, where that
-  collection is mutable (training), leaves the call moved by
-  ``balanced_bias`` on this call's own counts at ``router_bias_rate``;
-  under ``nn.remat`` the backward pass computes the block again from the
-  bias it was GIVEN and its update is dropped, so a step applies the rule
-  once.
+  chosen, the sigmoids alone weigh, times ``routed_scaling``). The bias
+  [num_experts] f32 is no parameter: it lives in the collection
+  ``router_state`` and, where that collection is mutable (training), leaves
+  the call moved by ``balanced_bias`` on this call's own counts at
+  ``router_bias_rate``; under ``nn.remat`` the backward pass computes the
+  block again from the bias it was GIVEN and its update is dropped, so a
+  step applies the rule once.
+
+  ``hc_streams`` n > 0: the block carries n residual streams, x [B, L, n
+  d] float32 in and out (manifold-constrained hyper-connections,
+  ``parallel/hyper_connections.py``): each of the two sublayers reads h from
+  its own ``StreamMaps`` (``hc_attn``, ``hc_ff``), computes f from
+  rmsnorm(h) as above, and the streams become res X + post f; the router
+  reads ``'normed'``.
 
   Returns (out, the feed-forward's stats): the expert layer's, with
   ``chosen_load_max_over_mean`` (the most chosen of ALL experts over the
   mean: what the bias balances) and ``router_bias_abs_mean`` (of the bias
-  as the call leaves it; 0 under a softmax router); {} for a dense one.
+  as the call leaves it; 0 under a softmax router); {} for a dense one;
+  with streams also ``res_stochastic_error``, the largest |row or column
+  sum - 1| of either sublayer's res over the tokens.
 
   The norms keep the names of the first block of this class (``norm_attn``
-  before the mixer, ``norm_moe`` before the feed-forward) whatever the two
+  before the mixer, ``norm_moe`` before the feed-forward) whatever the
   fields say, so that parameter trees written before the fields read on."""
 
   num_heads: int
@@ -564,8 +734,20 @@ class MoEBlock(nn.Module):
   router_reads: str = 'input'
   router: str = 'softmax'
   router_bias_rate: float = 1e-3
+  routed_scaling: float = 1.0
+  shared_expert_dim: Optional[int] = None
   qk_norm: bool = False
   block_diffusion: Optional[Tuple[int, int]] = None
+  q_lora_rank: Optional[int] = None
+  kv_lora_rank: Optional[int] = None
+  qk_nope_head_dim: Optional[int] = None
+  qk_rope_head_dim: Optional[int] = None
+  v_head_dim: Optional[int] = None
+  rope_scaling: Optional[Tuple[float, ...]] = None
+  hc_streams: int = 0
+  hc_iters: int = 20
+  hc_eps: float = 1e-6
+  hc_clamp: float = 30.0
   gate_activation: str = 'relu'
   attention_mode: str = 'auto'
   moe_block_rows: int = 256
@@ -584,7 +766,8 @@ class MoEBlock(nn.Module):
       else:
         bias = self.variable('router_state', 'bias', jnp.zeros,
                              (self.num_experts,), jnp.float32)
-        routing = moe_lib.route_sigmoid_bias(router_logits, bias.value, k)
+        routing = moe_lib.route_sigmoid_bias(router_logits, bias.value, k,
+                                             self.routed_scaling)
       counts = moe_lib.expert_counts(routing[0], self.num_experts)
       if (bias is not None and self.is_mutable_collection('router_state')
           and not self.is_initializing()):
@@ -596,30 +779,10 @@ class MoEBlock(nn.Module):
           'router_bias_abs_mean': jnp.float32(0) if bias is None else
                                   jnp.mean(jnp.abs(bias.value))}
 
-  @nn.compact
-  def __call__(self, x: jnp.ndarray,
-               positions: Optional[jnp.ndarray] = None):
-    from tensor2robot_tpu.layers.moe import DroplessMoE
-
-    for field, allowed in (('router_reads', ('input', 'normed')),
-                           ('mixer', ('attention', 'short_conv')),
-                           ('feed_forward', ('experts', 'dense')),
-                           ('router', ('softmax', 'sigmoid_bias'))):
-      if getattr(self, field) not in allowed:
-        raise ValueError('{} {!r} is neither {!r} nor {!r}.'.format(
-            field, getattr(self, field), *allowed))
-    b, l, d = x.shape
-    experts = self.feed_forward == 'experts'
-    if experts:
-      router = nn.Dense(
-          self.num_experts, use_bias=False, dtype=jnp.float32,
-          precision=jax.lax.Precision.HIGHEST,
-          kernel_init=nn.initializers.normal(0.02), name='router')
-      if self.router_reads == 'input':
-        router_logits = router(x.astype(jnp.float32))
-    h = RMSNorm(self.eps, name='norm_attn')(x).astype(self.dtype)
+  def _mix(self, h, positions):
+    """The mixer's output on the normed h [B, L, d]."""
     if self.mixer == 'attention':
-      x = x + GroupedQueryAttention(
+      return GroupedQueryAttention(
           num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
           head_dim=self.head_dim, window=self.window,
           rope_theta=self.rope_theta, qk_norm=self.qk_norm, eps=self.eps,
@@ -627,16 +790,31 @@ class MoEBlock(nn.Module):
           attention_mode=self.attention_mode,
           out_init_std=self.residual_init_std, dtype=self.dtype,
           name='attn')(h, positions)
-    else:
-      x = x + ShortConvolution(out_init_std=self.residual_init_std,
-                               dtype=self.dtype, name='conv')(h)
-    u = RMSNorm(self.eps, name='norm_moe')(x)
-    if not experts:
-      y = GatedMLP(self.dense_dim, down_init_std=self.residual_init_std,
-                   dtype=self.dtype, name='mlp')(u.astype(self.dtype))
-      return x + y.astype(x.dtype), {}
-    if self.router_reads == 'normed':
-      router_logits = router(u)
+    if self.mixer == 'latent_attention':
+      return LatentAttention(
+          num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+          kv_lora_rank=self.kv_lora_rank,
+          qk_nope_head_dim=self.qk_nope_head_dim,
+          qk_rope_head_dim=self.qk_rope_head_dim,
+          v_head_dim=self.v_head_dim, rope_theta=self.rope_theta,
+          rope_scaling=self.rope_scaling, eps=self.eps,
+          attention_mode=self.attention_mode,
+          out_init_std=self.residual_init_std, dtype=self.dtype,
+          name='attn')(h, positions)
+    return ShortConvolution(out_init_std=self.residual_init_std,
+                            dtype=self.dtype, name='conv')(h)
+
+  def _feed(self, u, router_logits):
+    """(the feed-forward's output [B, L, d], its stats) on the normed u
+    (f32); ``router_logits`` [B, L, E] where the router read the input."""
+    from tensor2robot_tpu.layers.moe import DroplessMoE
+
+    b, l, d = u.shape
+    if self.feed_forward == 'dense':
+      return GatedMLP(self.dense_dim, down_init_std=self.residual_init_std,
+                      dtype=self.dtype, name='mlp')(u.astype(self.dtype)), {}
+    if router_logits is None:
+      router_logits = self._router()(u)
     routing, router_stats = self._route(router_logits.reshape(b * l, -1))
     y, stats = DroplessMoE(
         num_experts=self.num_experts, experts_held=tuple(self.experts_held),
@@ -644,7 +822,71 @@ class MoEBlock(nn.Module):
         block_rows=self.moe_block_rows,
         down_init_std=self.residual_init_std, dtype=self.dtype, name='moe')(
             u.astype(self.dtype).reshape(b * l, d), routing)
-    return x + y.reshape(b, l, d).astype(x.dtype), dict(stats, **router_stats)
+    y = y.reshape(b, l, d)
+    if self.shared_expert_dim:
+      y = y + GatedMLP(self.shared_expert_dim,
+                       down_init_std=self.residual_init_std, dtype=self.dtype,
+                       name='shared_expert')(u.astype(self.dtype))
+    return y, dict(stats, **router_stats)
+
+  def _router(self):
+    return nn.Dense(
+        self.num_experts, use_bias=False, dtype=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+        kernel_init=nn.initializers.normal(0.02), name='router')
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray,
+               positions: Optional[jnp.ndarray] = None):
+    for field, allowed in (
+        ('router_reads', ('input', 'normed')),
+        ('mixer', ('attention', 'short_conv', 'latent_attention')),
+        ('feed_forward', ('experts', 'dense')),
+        ('router', ('softmax', 'sigmoid_bias'))):
+      if getattr(self, field) not in allowed:
+        raise ValueError('{} {!r} is none of {}.'.format(
+            field, getattr(self, field), ', '.join(map(repr, allowed))))
+    if self.hc_streams:
+      return self._streams(x, positions)
+    router_logits = None
+    if self.feed_forward == 'experts' and self.router_reads == 'input':
+      router_logits = self._router()(x.astype(jnp.float32))
+    h = RMSNorm(self.eps, name='norm_attn')(x).astype(self.dtype)
+    x = x + self._mix(h, positions)
+    u = RMSNorm(self.eps, name='norm_moe')(x)
+    y, stats = self._feed(u, router_logits)
+    return x + y.astype(x.dtype), stats
+
+  def _streams(self, x, positions):
+    """The block over ``hc_streams`` residual streams (class docstring)."""
+    from tensor2robot_tpu.parallel import hyper_connections as hc_lib
+
+    if self.router_reads != 'normed':
+      raise ValueError('a block of several streams routes on the normed '
+                       "input of its feed-forward: router_reads 'normed'.")
+    n = self.hc_streams
+    b, l, width = x.shape
+    d = width // n
+    maps_of = lambda name: StreamMaps(
+        n, self.hc_iters, self.hc_eps, self.hc_clamp, dtype=self.dtype,
+        name=name)
+    state = x.astype(jnp.float32).reshape(b * l, width)
+    h, maps_attn, state = maps_of('hc_attn')(state)
+    f = self._mix(RMSNorm(self.eps, name='norm_attn')(
+        h.reshape(b, l, d)).astype(self.dtype), positions)
+    with jax.named_scope('hc_post'):
+      state = hc_lib.hc_post(state, f.reshape(b * l, d).astype(self.dtype),
+                             maps_attn, n=n)
+    h, maps_ff, state = maps_of('hc_ff')(state)
+    y, stats = self._feed(RMSNorm(self.eps, name='norm_moe')(
+        h.reshape(b, l, d)), None)
+    with jax.named_scope('hc_post'):
+      state = hc_lib.hc_post(state, y.reshape(b * l, d).astype(self.dtype),
+                             maps_ff, n=n)
+    error = jax.lax.stop_gradient(jnp.maximum(
+        hc_lib.res_stochastic_error(maps_attn, n),
+        hc_lib.res_stochastic_error(maps_ff, n)))
+    return state.reshape(b, l, width), dict(stats, res_stochastic_error=error)
 
 
 class TokenLearner(nn.Module):

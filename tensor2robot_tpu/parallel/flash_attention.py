@@ -439,7 +439,7 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
   (bh, L): ~3.5 MB at bh=8, L=16k — noise next to the k/v tensors.
   """
   bh, l_q, d = q.shape
-  l_k = k.shape[1]
+  l_k, d_v = k.shape[1], v.shape[2]
   kv = _kv_head(bh // k.shape[0])
   table = tile_table(l_q // block_q, l_k // block_k, block_q, block_k,
                      causal, window, diffusion)
@@ -459,15 +459,15 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
       in_specs=[
           pl.BlockSpec((1, block_q, d), q_block),
           pl.BlockSpec((1, block_k, d), kv_block),
-          pl.BlockSpec((1, block_k, d), kv_block),
+          pl.BlockSpec((1, block_k, d_v), kv_block),
       ],
       out_specs=[
-          pl.BlockSpec((1, block_q, d), q_block),
+          pl.BlockSpec((1, block_q, d_v), q_block),
           pl.BlockSpec((1, 8, block_q),
                        lambda b, t, qs, ks, flags: (b, 0, qs[t])),
       ],
       scratch_shapes=[
-          pltpu.VMEM((block_q, d), jnp.float32),
+          pltpu.VMEM((block_q, d_v), jnp.float32),
           # 128 uniform lanes per scalar — see _block_update's m/l note.
           pltpu.VMEM((block_q, 128), jnp.float32),
           pltpu.VMEM((block_q, 128), jnp.float32),
@@ -477,7 +477,7 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
       kernel,
       grid_spec=grid_spec,
       out_shape=[
-          jax.ShapeDtypeStruct(q.shape, q.dtype),
+          jax.ShapeDtypeStruct((bh, l_q, d_v), q.dtype),
           jax.ShapeDtypeStruct((bh, 8, l_q), jnp.float32),
       ],
       interpret=interpret,
@@ -771,9 +771,12 @@ FUSED_BWD_RESIDENT_BYTES = 64 * 1024 * 1024
 _FUSED_BWD_STREAMED_BYTES = 32 * 1024 * 1024
 
 
-def _fused_bwd_resident_bytes(l_k: int, d: int, dtype) -> int:
-  """dk and dv of one k/v head: float32 accumulators, output blocks twice."""
-  return 2 * l_k * d * (4 + 2 * jnp.dtype(dtype).itemsize)
+def _fused_bwd_resident_bytes(l_k: int, d: int, dtype,
+                              d_v: Optional[int] = None) -> int:
+  """dk [l_k, d] and dv [l_k, d_v] (d_v None: d) of one k/v head: float32
+  accumulators, output blocks twice."""
+  return l_k * (d + (d if d_v is None else d_v)) * (
+      4 + 2 * jnp.dtype(dtype).itemsize)
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
@@ -796,10 +799,10 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
   carries the dq kernel's name: the module docstring.
   """
   bh, l_q, d = q.shape
-  l_k = k.shape[1]
+  l_k, d_v = k.shape[1], v.shape[2]
   group = bh // k.shape[0]
   kv = _kv_head(group)
-  resident = _fused_bwd_resident_bytes(l_k, d, k.dtype)
+  resident = _fused_bwd_resident_bytes(l_k, d, k.dtype, d_v)
   fused = resident <= FUSED_BWD_RESIDENT_BYTES
   tiles = (l_q // block_q, l_k // block_k, block_q, block_k, causal, window,
            diffusion)
@@ -832,8 +835,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
       in_specs=[
           pl.BlockSpec((1, block_q, d), q_of_q),
           pl.BlockSpec((1, block_k, d), kv_of_q),
-          pl.BlockSpec((1, block_k, d), kv_of_q),
-          pl.BlockSpec((1, block_q, d), q_of_q),
+          pl.BlockSpec((1, block_k, d_v), kv_of_q),
+          pl.BlockSpec((1, block_q, d_v), q_of_q),
           pl.BlockSpec((1, 8, block_q), row_of_q),
           pl.BlockSpec((1, 8, block_q), row_of_q),
       ])
@@ -842,15 +845,15 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
   dkv_shapes = [jax.ShapeDtypeStruct(k.shape, k.dtype),
                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
   if fused:
-    kv_head = pl.BlockSpec((1, l_k, d),
-                           lambda b, t, qs, ks, flags: (kv(b), 0, 0))
+    kv_head = lambda width: pl.BlockSpec(
+        (1, l_k, width), lambda b, t, qs, ks, flags: (kv(b), 0, 0))
     return pl.pallas_call(
         functools.partial(_flash_bwd_fused_kernel, group=group, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            out_specs=[dq_spec, kv_head, kv_head],
+            out_specs=[dq_spec, kv_head(d), kv_head(d_v)],
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                             pltpu.VMEM((l_k, d), jnp.float32),
-                            pltpu.VMEM((l_k, d), jnp.float32)],
+                            pltpu.VMEM((l_k, d_v), jnp.float32)],
             **q_resident),
         out_shape=[dq_shape] + dkv_shapes,
         # The query heads of a group share the dk/dv accumulators: in order.
@@ -873,18 +876,18 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
           in_specs=[
               pl.BlockSpec((1, block_q, d), q_of_kv),
               pl.BlockSpec((1, block_k, d), kv_of_kv),
-              pl.BlockSpec((1, block_k, d), kv_of_kv),
-              pl.BlockSpec((1, block_q, d), q_of_kv),
+              pl.BlockSpec((1, block_k, d_v), kv_of_kv),
+              pl.BlockSpec((1, block_q, d_v), q_of_kv),
               pl.BlockSpec((1, 8, block_q), row_of_kv),
               pl.BlockSpec((1, 8, block_q), row_of_kv),
           ],
           out_specs=[
               pl.BlockSpec((1, block_k, d), kv_of_kv),
-              pl.BlockSpec((1, block_k, d), kv_of_kv),
+              pl.BlockSpec((1, block_k, d_v), kv_of_kv),
           ],
           scratch_shapes=[
               pltpu.VMEM((block_k, d), jnp.float32),
-              pltpu.VMEM((block_k, d), jnp.float32),
+              pltpu.VMEM((block_k, d_v), jnp.float32),
           ],
       ),
       out_shape=dkv_shapes,
@@ -1016,24 +1019,31 @@ def flash_attention(q, k, v,
   blocks win until the f32 score matrix presses the 16 MB scoped-VMEM
   limit.
 
-  Head dims below 128 are zero-padded up to 128 for the kernels: jax
-  0.9's Mosaic rejects memref slices whose lane extent is not 128-aligned,
-  which the accumulator sub-refs need. Exact — zero k/v columns change
-  neither scores nor outputs; padding/slicing happens outside the
-  custom_vjp so the backward sees the padded problem and autodiff of the
-  pad/slice restores [.., d] gradients.
+  The value width may differ from the key width (latent attention: q and
+  k of 192, v of 128): ``v`` [B, L, H_kv, D_v] gives an output of D_v; the
+  default ``scale`` is 1 / sqrt of the KEY width.
+
+  Head dims below 128 are zero-padded up to 128 for the kernels
+  (``kernel_width``): jax 0.9's Mosaic rejects memref slices whose lane
+  extent is not 128-aligned, which the accumulator sub-refs need. Exact —
+  zero columns change neither scores nor outputs; padding/slicing happens
+  outside the custom_vjp so the backward sees the padded problem and
+  autodiff of the pad/slice restores [.., d] gradients.
   """
   if scale is None:
     scale = 1.0 / float(np.sqrt(q.shape[-1]))
   if interpret is None:
     interpret = not runtime.on_tpu()
   b, l_q, h, d = q.shape
-  l_k, h_kv = k.shape[1], k.shape[2]
+  l_k, h_kv, d_v = k.shape[1], k.shape[2], v.shape[3]
   if h % h_kv or v.shape[2] != h_kv:
     raise ValueError(
         'grouped-query attention needs the query heads ({}) to be a '
         'multiple of the key/value heads ({}, {}).'.format(
             h, h_kv, v.shape[2]))
+  if k.shape[3] != d:
+    raise ValueError('q and k need one head width; got {} and {}.'.format(
+        d, k.shape[3]))
   if window is not None and (not causal or window < 1):
     raise ValueError('window={!r} needs causal=True and window >= 1.'.format(
         window))
@@ -1057,16 +1067,26 @@ def flash_attention(q, k, v,
   block_q = _dividing_block_or_raise(min(block_q, l_q), l_q)
   block_k = _dividing_block_or_raise(min(block_k, l_k), l_k)
 
-  dp = -(-d // 128) * 128 if not interpret else d
-
   def _to_bhld(x):
-    x = x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)
-    if dp != d:
-      x = jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
+    width = x.shape[3]
+    x = x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], width)
+    padded = kernel_width(width) if not interpret else width
+    if padded != width:
+      x = jnp.pad(x, ((0, 0), (0, 0), (0, padded - width)))
     return x
 
   out = _flash_diff(_to_bhld(q), _to_bhld(k), _to_bhld(v), causal, scale,
                     block_q, block_k, interpret, block_q_bwd, block_k_bwd,
                     window, block_diffusion)
-  out = out[:, :, :d] if dp != d else out
-  return out.reshape(b, h, l_q, d).transpose(0, 2, 1, 3)
+  if out.shape[2] != d_v:
+    out = out[:, :, :d_v]
+  return out.reshape(b, h, l_q, d_v).transpose(0, 2, 1, 3)
+
+
+def kernel_width(width: int) -> int:
+  """The head width the kernels run at on the TPU: a width under 128 is
+  zero-padded to 128 lanes (Mosaic slices the accumulators only at whole
+  128-lane tiles), a wider one runs whole, one block of the array's full
+  width (192 for latent attention's q and k: not padded to 256, which
+  would cost a third more on the MXU)."""
+  return 128 if width < 128 else width

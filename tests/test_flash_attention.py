@@ -477,3 +477,78 @@ class TestMostlySkippedGrids:
       # the keys no query sees get a gradient of exactly zero
       assert not np.any(np.asarray(got[1])[:, l_q:]) and not np.any(
           np.asarray(got[2])[:, l_q:])
+
+
+class TestAValueWidthUnlikeTheKeyWidth:
+  """Latent attention's q and k of one width against v of another (192 and
+  128 in the Xing4.0 cell), through the forward kernel and both backward
+  paths, against the dense oracle; and the path of equal widths unchanged."""
+
+  @pytest.mark.parametrize('backward', list(BACKWARDS))
+  @pytest.mark.parametrize('kv_heads', [4, 2], ids=['mha', 'gqa'])
+  def test_outputs_and_gradients_match_the_oracle(self, kv_heads, backward,
+                                                  monkeypatch):
+    _take(backward, monkeypatch)
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(1, 128, 4, 48), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 128, kv_heads, 48), jnp.float32)
+    v = jnp.asarray(rng.randn(1, 128, kv_heads, 16), jnp.float32)
+    cotangent = jnp.asarray(rng.randn(1, 128, 4, 16), jnp.float32)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, scale=0.2, block_q=32, block_k=32,
+        block_q_bwd=32, block_k_bwd=32)
+    dense = lambda q, k, v: transformer_lib.scaled_dot_attention(
+        q, k, v, True, scale=0.2)
+    out, vjp = jax.vjp(flash, q, k, v)
+    want, want_vjp = jax.vjp(dense, q, k, v)
+    assert out.shape == (1, 128, 4, 16)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-6)
+    for got, ref, name in zip(vjp(cotangent), want_vjp(cotangent), 'qkv'):
+      assert got.shape == ref.shape
+      np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5,
+                                 err_msg='d' + name)
+
+  def test_the_equal_width_path_is_the_parents_to_the_bit(self):
+    """Outputs and the three gradients of calls whose value width is the key
+    width, digested: what the kernels gave before they took two widths (the
+    digests were read from the parent commit's code, same inputs)."""
+    import hashlib
+
+    digests = {
+        'f32_d32_causal': ((1, 128, 2, 2, 32), np.float32, True, None,
+                           'af8554191128032a'),
+        'bf16_d128_gqa_window': ((1, 128, 4, 2, 128), jnp.bfloat16, True, 64,
+                                 'a3efc06aaf745c6c'),
+        'f32_d64_full': ((1, 64, 2, 2, 64), np.float32, False, None,
+                         '6c50dbd87d820857'),
+    }
+    rng = np.random.RandomState(11)
+    for name, (shape, dtype, causal, window, want) in digests.items():
+      b, l, h, h_kv, d = shape
+      q, k, v = (jnp.asarray(rng.randn(b, l, heads, d), dtype)
+                 for heads in (h, h_kv, h_kv))
+      cotangent = jnp.asarray(rng.randn(b, l, h, d), dtype)
+      out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+          q, k, v, causal=causal, window=window, block_q=32, block_k=32,
+          block_q_bwd=32, block_k_bwd=32), q, k, v)
+      digest = hashlib.sha256()
+      for array in (out,) + vjp(cotangent):
+        digest.update(np.asarray(array).tobytes())
+      assert digest.hexdigest()[:16] == want, name
+
+  def test_the_widths_the_kernels_run_at(self):
+    assert flash_lib.kernel_width(64) == 128      # padded, as before
+    assert flash_lib.kernel_width(128) == 128
+    assert flash_lib.kernel_width(192) == 192     # whole, not 256
+    # dk [l_k, 192] and dv [l_k, 128] of a head: f32 accumulators and bf16
+    # output blocks twice, 8 bytes an element; equal widths count as before.
+    assert flash_lib._fused_bwd_resident_bytes(4096, 192, jnp.bfloat16,
+                                               128) == 4096 * (192 + 128) * 8
+    assert flash_lib._fused_bwd_resident_bytes(8192, 128, jnp.bfloat16) == \
+        flash_lib._fused_bwd_resident_bytes(8192, 128, jnp.bfloat16, 128) == \
+        2 * 8192 * 128 * 8
+
+  def test_q_and_k_of_two_widths_are_refused(self):
+    q, k, v = _qkv(l=64, d=32)
+    with pytest.raises(ValueError, match='one head width'):
+      flash_attention(q, k[..., :16], v)

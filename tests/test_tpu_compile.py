@@ -96,3 +96,52 @@ def test_the_fused_attention_backward_compiles_for_the_v5e(
   assert text.count('tpu_custom_call') == 1
   assert 'flash_attention_bwd_dq' in text
   assert 'flash_attention_bwd_dkv' not in text
+
+
+def test_the_latent_attention_widths_compile_for_the_v5e(one_chip):
+  """q and k of 192 against v of 128 at the Xing4.0 cell's shape (32 heads,
+  4,096 tokens), forward and the fused backward: 192 runs whole, one block
+  of the array's full width, and nothing is padded."""
+  shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16,
+                                             sharding=one_chip)
+  attend = lambda q, k, v: flash_lib.flash_attention(
+      q, k, v, causal=True, scale=0.14468, interpret=False)
+  program = _compiled(lambda q, k, v, g: jax.vjp(attend, q, k, v)[1](g),
+                      shape(1, 4096, 32, 192), shape(1, 4096, 32, 192),
+                      shape(1, 4096, 32, 128), shape(1, 4096, 32, 128))
+  text = program.as_text()
+  assert 'flash_attention_fwd' in text and 'flash_attention_bwd_dq' in text
+  assert 'flash_attention_bwd_dkv' not in text
+  assert ' pad(' not in text
+
+
+@pytest.mark.parametrize('kernel', ['hc_pre_fwd', 'hc_post_fwd',
+                                    'hc_post_bwd', 'hc_pre_bwd'])
+def test_the_stream_kernels_compile_for_the_v5e(one_chip, kernel):
+  """The four hyper-connection kernels at the Xing4.0 cell's shape (4,096
+  tokens, four streams of 3,584): tiles of whole rows at the full width of
+  14,336 float32 lanes, the blocks and temporaries under the scoped VMEM
+  limit they ask for."""
+  from tensor2robot_tpu.parallel import hyper_connections as hc
+
+  rows, c = 4096, 3584
+  shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+      dims, dtype, sharding=one_chip)
+  x, f = shape(rows, 4 * c), shape(rows, c, dtype=jnp.bfloat16)
+  maps, dh = shape(rows, 32), shape(rows, c)
+  phi = shape(4 * c, 24, dtype=jnp.bfloat16)
+  alpha, bias = shape(3), shape(24)
+  kw = dict(n=4, iters=20, eps=1e-6, clamp=30.0, interpret=False)
+  calls = {
+      'hc_pre_fwd': (lambda *a: hc.hc_pre_fwd(*a, **kw),
+                     (x, phi, alpha, bias)),
+      'hc_post_fwd': (lambda *a: hc.hc_post_fwd(*a, n=4, interpret=False),
+                      (x, f, maps)),
+      'hc_post_bwd': (lambda *a: hc.hc_post_bwd(*a, n=4, interpret=False),
+                      (x, f, maps, x)),
+      'hc_pre_bwd': (lambda *a: hc.hc_pre_bwd(*a, **kw),
+                     (x, phi, alpha, bias, dh, x, maps)),
+  }
+  call, operands = calls[kernel]
+  text = _compiled(call, *operands).as_text()
+  assert 'tpu_custom_call' in text and kernel in text
