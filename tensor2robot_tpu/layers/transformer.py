@@ -670,6 +670,12 @@ class StreamMaps(nn.Module):
                            iters=self.iters, eps=self.eps, clamp=self.clamp)
 
 
+def _gauge_kept_stream_bytes(value: int):
+  from tensor2robot_tpu.observability import get_registry
+
+  get_registry().gauge('hc/kept_bytes_per_token').set(float(value))
+
+
 class MoEBlock(nn.Module):
   """Pre-norm block of a token mixer and a feed-forward:
 
@@ -705,7 +711,11 @@ class MoEBlock(nn.Module):
   ``parallel/hyper_connections.py``): each of the two sublayers reads h from
   its own ``StreamMaps`` (``hc_attn``, ``hc_ff``), computes f from
   rmsnorm(h) as above, and the streams become res X + post f; the router
-  reads ``'normed'``.
+  reads ``'normed'``. Where the stream kernels run, they name h, the maps, f
+  and the state between the sublayers (``hc_lib.BACKWARD_READS``) for a
+  checkpoint policy to keep. When the block is traced it sets the gauge
+  ``hc/kept_bytes_per_token``: the bytes a token of those arrays
+  (``hc_lib.kept_bytes_per_token``), 0 for a block that names none.
 
   Returns (out, the feed-forward's stats): the expert layer's, with
   ``chosen_load_max_over_mean`` (the most chosen of ALL experts over the
@@ -848,6 +858,7 @@ class MoEBlock(nn.Module):
             field, getattr(self, field), ', '.join(map(repr, allowed))))
     if self.hc_streams:
       return self._streams(x, positions)
+    _gauge_kept_stream_bytes(0)
     router_logits = None
     if self.feed_forward == 'experts' and self.router_reads == 'input':
       router_logits = self._router()(x.astype(jnp.float32))
@@ -867,6 +878,8 @@ class MoEBlock(nn.Module):
     n = self.hc_streams
     b, l, width = x.shape
     d = width // n
+    _gauge_kept_stream_bytes(hc_lib.kept_bytes_per_token(
+        b * l, n, d, jnp.dtype(self.dtype).itemsize))
     maps_of = lambda name: StreamMaps(
         n, self.hc_iters, self.hc_eps, self.hc_clamp, dtype=self.dtype,
         name=name)

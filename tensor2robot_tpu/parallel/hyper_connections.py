@@ -61,6 +61,18 @@ four kernels are ``jax.jit`` functions (one copy a program) carrying their
 names into the instruction, which is how a trace finds them. When a kernel
 is traced it sets the gauge ``hc/bytes_per_token/<kernel>``: the bytes it
 moves a token (operands read once, results written once).
+
+Named arrays. The forward rules of the two custom VJPs name, with
+``jax.ad_checkpoint.checkpoint_name``, what a sublayer's backward reads
+beside the state it was handed: h and the maps (HC_H, HC_MAPS: the norm and
+projections of the sublayer take their gradients from h, the post kernel's
+backward reads the maps), the sublayer's output f as the post kernel reads
+it (HC_F) and the post kernel's result X' (HC_STATE: the next sublayer's
+state). BACKWARD_READS holds the four. As for the flash kernels' names, a
+name is the identity unless a ``jax.checkpoint`` policy asks for it; one
+that does keeps the arrays, and its backward pass runs neither forward
+kernel again nor the products that made f. The plain formulation names
+nothing.
 """
 
 from __future__ import annotations
@@ -70,6 +82,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -80,6 +93,13 @@ _TILE_ROWS = (256, 128, 64, 32, 16, 8)
 _TILE_BYTES = 24 * 1024 * 1024
 _VMEM_LIMIT = 96 * 1024 * 1024
 KERNELS = ('hc_pre_fwd', 'hc_post_fwd', 'hc_post_bwd', 'hc_pre_bwd')
+
+# Names of what a sublayer's backward reads (module docstring).
+HC_H = 'hc_h'
+HC_MAPS = 'hc_maps'
+HC_F = 'hc_f'
+HC_STATE = 'hc_state'
+BACKWARD_READS = (HC_H, HC_MAPS, HC_F, HC_STATE)
 
 
 def map_count(n: int) -> int:
@@ -362,6 +382,19 @@ def call_bytes(kernel: str, n: int, c: int, f_itemsize: int = 2) -> int:
   }[kernel]
 
 
+def kept_bytes_per_token(rows: int, n: int, c: int, f_itemsize: int = 2,
+                         mode: str = 'auto') -> int:
+  """Bytes a token that a checkpoint keeping BACKWARD_READS holds for a
+  block of two sublayers over ``rows`` tokens, beside the block's input: h,
+  the maps and f of each sublayer and the state between them (the second
+  sublayer's X' is the block's output, which the next block keeps as its
+  input anyway). 0 where ``mode`` picks the plain formulation, which names
+  nothing."""
+  if not _use_kernels(mode, rows, c):
+    return 0
+  return 2 * (4 * c + 4 * MAP_ROWS + f_itemsize * c) + 4 * n * c
+
+
 def _gauge(kernel: str, n: int, c: int, f_itemsize: int = 2):
   from tensor2robot_tpu.observability import get_registry
 
@@ -502,8 +535,12 @@ def _pre_kernels(x, phi, alpha, bias, n, iters, eps, clamp, interpret):
 
 
 def _pre_kernels_fwd(x, phi, alpha, bias, n, iters, eps, clamp, interpret):
-  out = _pre_kernels(x, phi, alpha, bias, n, iters, eps, clamp, interpret)
-  return out, (x, phi, alpha, bias)
+  h, maps = hc_pre_fwd(x, phi, alpha, bias, n=n, iters=iters, eps=eps,
+                       clamp=clamp, interpret=interpret)
+  # The state leaves as the array that came in, so a checkpoint that keeps
+  # h and the maps has nothing of this call left to run again.
+  return ((checkpoint_name(h, HC_H), checkpoint_name(maps, HC_MAPS), x),
+          (x, phi, alpha, bias))
 
 
 def _pre_kernels_bwd(n, iters, eps, clamp, interpret, residuals, cotangents):
@@ -526,7 +563,10 @@ def _post_kernels(x, f, maps, n, interpret):
 
 
 def _post_kernels_fwd(x, f, maps, n, interpret):
-  return _post_kernels(x, f, maps, n, interpret), (x, f, maps)
+  f = checkpoint_name(f, HC_F)
+  out = checkpoint_name(hc_post_fwd(x, f, maps, n=n, interpret=interpret),
+                        HC_STATE)
+  return out, (x, f, maps)
 
 
 def _post_kernels_bwd(n, interpret, residuals, g):

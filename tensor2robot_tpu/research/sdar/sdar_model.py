@@ -29,9 +29,9 @@ flax module.
 The model can hold one chip's SHARE of an expert-parallel, vocabulary-split
 deployment, as ``research/smallthinker`` does: ``experts_held`` (first
 index, count) of the ``num_experts`` the router scores and the first
-``vocab_rows`` rows of embedding and head. A block is under
-``jax.checkpoint`` with that model's policy: the residual stream and what
-the attention backward kernels read are kept, the rest is computed again.
+``vocab_rows`` rows of embedding and head. A block is that model's
+``CheckpointedBlock``: the residual stream and what the attention backward
+kernels read are kept, the rest is computed again.
 """
 
 from __future__ import annotations
@@ -48,18 +48,15 @@ from tensor2robot_tpu.layers import transformer as transformer_lib
 from tensor2robot_tpu.models import optimizers as opt_lib
 from tensor2robot_tpu.models.abstract_model import AbstractT2RModel
 from tensor2robot_tpu.modes import ModeKeys
+from tensor2robot_tpu.research.smallthinker.smallthinker_model import (
+    CheckpointedBlock,
+)
 from tensor2robot_tpu.specs.struct import SpecStruct
 from tensor2robot_tpu.specs.tensor_spec import TensorSpec
 
 STEP_METRICS = ('moe/pairs_held', 'moe/expert_load_max_over_mean',
                 'moe/dropped_pairs', 'moe/rows_in_use',
                 'diffusion/masked_positions', 'diffusion/mean_noise_level')
-
-CheckpointedBlock = nn.remat(
-    transformer_lib.MoEBlock,
-    policy=jax.checkpoint_policies.save_only_these_names(
-        *transformer_lib.flash_lib.BACKWARD_READS))
-
 
 def sequence_key(rng, tokens_row):
   """The key of one sequence's noise: ``rng`` with a checksum of the

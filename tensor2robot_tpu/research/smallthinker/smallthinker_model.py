@@ -26,7 +26,10 @@ parallel/flash_attention.py names, BACKWARD_READS), so the backward pass
 runs neither the flash forward kernel nor the q, k, v projections a second
 time; everything else in the block (norms, router, the expert layer) is
 computed again. A block whose attention is not the flash kernel carries no
-such names and keeps the residual stream alone.
+such names and keeps the residual stream alone. The same checkpoint keeps,
+in a block of several residual streams (research/xing), what the stream
+kernels name (parallel/hyper_connections.py, BACKWARD_READS); this model's
+blocks have one stream and give none of those names.
 """
 
 from __future__ import annotations
@@ -43,6 +46,7 @@ from tensor2robot_tpu.layers import transformer as transformer_lib
 from tensor2robot_tpu.models import optimizers as opt_lib
 from tensor2robot_tpu.models.abstract_model import AbstractT2RModel
 from tensor2robot_tpu.modes import ModeKeys
+from tensor2robot_tpu.parallel import hyper_connections as hc_lib
 from tensor2robot_tpu.specs.struct import SpecStruct
 from tensor2robot_tpu.specs.tensor_spec import TensorSpec
 
@@ -52,11 +56,17 @@ MOE_STATS = ('moe/pairs_held', 'moe/expert_load_max_over_mean',
 # A block under ``jax.checkpoint`` that keeps, for its backward pass, the
 # residuals the flash kernels' forward rule names (at 2 x 8192 tokens, 28 and
 # 4 heads of 128, bf16: 117.4 MB each for q and out, 16.8 each for k and v,
-# 1.8 for the log-sum-exp, a layer) and computes the rest of itself again.
+# 1.8 for the log-sum-exp, a layer) and, in a block of several residual
+# streams, what the stream kernels' forward rules name (h, the maps and f of
+# each sublayer and the state between the two: 100,608 bytes a token at
+# four streams of 3,584, 412 MB a layer at 4,096 tokens), so that its
+# backward runs neither stream forward kernel nor the products that made f
+# again; it computes the rest of itself again. A block that gives none of
+# these names (no flash kernel, one stream) keeps its input alone.
 CheckpointedBlock = nn.remat(
     transformer_lib.MoEBlock,
     policy=jax.checkpoint_policies.save_only_these_names(
-        *transformer_lib.flash_lib.BACKWARD_READS))
+        *transformer_lib.flash_lib.BACKWARD_READS, *hc_lib.BACKWARD_READS))
 
 
 def next_token_loss(hidden, head, tokens, block_tokens: int, dtype):

@@ -5,17 +5,29 @@ the parameters after one step; every fault planted in the reference refused
 by the comparison the chip makes (the gradient by group); YaRN's frequencies
 against a hand table; the latent attention's shapes and its value width; the
 eight chips' shares of an expert layer adding up to the uncut layer with the
-shared expert, attention and streams counted once; the published form only."""
+shared expert, attention and streams counted once; the published form only;
+the block's checkpoint keeping what the stream kernels' backward reads, and
+the other token models' steps untouched by it."""
 
+import re
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
 
+from tensor2robot_tpu import runtime
 from tensor2robot_tpu.layers import moe as moe_lib
 from tensor2robot_tpu.layers import transformer as transformer_lib
 from tensor2robot_tpu.modes import ModeKeys
+from tensor2robot_tpu.observability import get_registry
+from tensor2robot_tpu.parallel import hyper_connections as hc_lib
+from tensor2robot_tpu.research.lfm2 import LFM2Model, lfm2_model
+from tensor2robot_tpu.research.sdar import SDARModel, sdar_model
+from tensor2robot_tpu.research.smallthinker import SmallThinkerModel
+from tensor2robot_tpu.research.smallthinker import smallthinker_model
 from tensor2robot_tpu.research.xing import XingModel, xing_model
 from benchmark.harness import xing_reference as reference
 
@@ -329,3 +341,199 @@ class TestTheShareOfAnEightChipDeployment:
     assert sum(float(stats['pairs_held']) for _, stats in parts) == LENGTH * 4
     # And one share alone is not the layer.
     assert _relative(parts[0][0], want.reshape(LENGTH, -1)) > 1e-3
+
+
+# The checkpoint the model had before: it kept the flash kernels' arrays and
+# ran the rest of the block, the stream kernels' forwards among it, again.
+FLASH_ONLY = nn.remat(
+    transformer_lib.MoEBlock,
+    policy=jax.checkpoint_policies.save_only_these_names(
+        *transformer_lib.flash_lib.BACKWARD_READS))
+STREAM_KERNELS = ('hc_pre_fwd', 'hc_post_fwd', 'hc_post_bwd', 'hc_pre_bwd')
+FLASH_KERNELS = ('flash_attention_fwd', 'flash_attention_bwd_dq')
+
+
+@pytest.fixture
+def kernels_selected(monkeypatch):
+  """The flash and stream kernels wherever the TPU would take them, on the
+  Pallas interpreter here."""
+  monkeypatch.setattr(transformer_lib, 'resolve_attention_mode',
+                      lambda mode, length: 'flash')
+  monkeypatch.setattr(hc_lib, '_use_kernels',
+                      lambda mode, rows, c: mode != 'xla')
+
+
+def _stream_stack(block_cls, blocks):
+  """(loss over parameters and input, parameters, input): ``blocks`` blocks
+  of latent attention over four streams of 128, 128 tokens; the first
+  block's feed-forward dense, the second's routed experts beside a shared
+  one."""
+  x = jax.random.normal(jax.random.PRNGKey(10), (1, 128, 4 * 128))
+
+  class Stack(nn.Module):
+
+    @nn.compact
+    def __call__(self, x):
+      for i in range(blocks):
+        x, _ = block_cls(
+            num_heads=2, num_kv_heads=2, head_dim=32, num_experts=8,
+            experts_held=(2, 4), expert_dim=32, top_k=4, rope_theta=1e4,
+            mixer='latent_attention', q_lora_rank=32, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+            feed_forward='dense' if i == 0 else 'experts', dense_dim=64,
+            router_reads='normed', router='sigmoid_bias', routed_scaling=2.0,
+            shared_expert_dim=32, hc_streams=4, gate_activation='silu',
+            moe_block_rows=8, name='block{}'.format(i))(x)
+      return jnp.sum(jnp.sin(x))
+
+  stack = Stack()
+  return stack.apply, stack.init(jax.random.PRNGKey(11), x), x
+
+
+class TestTheBlockCheckpointKeepsWhatTheStreamKernelsBackwardReads:
+  """Pallas interpreter, tiny widths: the model's checkpoint against the
+  one that kept the flash arrays alone."""
+
+  @pytest.mark.parametrize('blocks', [1, 2], ids=['dense', 'dense_experts'])
+  def test_each_stream_forward_once_a_sublayer_and_the_same_gradients(
+      self, blocks, jaxpr_calls, kernels_selected):
+    results = {}
+    for name, block_cls in [('kept', smallthinker_model.CheckpointedBlock),
+                            ('flash only', FLASH_ONLY)]:
+      loss, params, x = _stream_stack(block_cls, blocks)
+      grad = jax.value_and_grad(loss, argnums=(0, 1))
+      calls, _ = jaxpr_calls(grad, params, x)
+      results[name] = dict(
+          calls=calls, grads=jax.tree.leaves(grad(params, x)))
+    kept, before = results['kept']['calls'], results['flash only']['calls']
+    # Each sublayer runs its pre and post kernels once; the flash only
+    # checkpoint ran both pre kernels and the first post kernel again.
+    assert [kept[k] for k in STREAM_KERNELS] == [2 * blocks] * 4
+    assert [before[k] for k in STREAM_KERNELS] == [
+        4 * blocks, 3 * blocks, 2 * blocks, 2 * blocks]
+    assert [kept[k] for k in FLASH_KERNELS] == [blocks] * 2 == [
+        before[k] for k in FLASH_KERNELS]
+    # f is kept: attention's `out` and the feed-forward's last product (the
+    # dense w2; the shared expert's w2) are not run again, nor the routed
+    # experts' sum of rows. Their down product is: the sum's backward reads
+    # its rows for the routing weights' gradient.
+    assert kept['dot_general'] == before['dot_general'] - 2 * blocks
+    assert kept['moe_sum_rows'] == before['moe_sum_rows'] - (blocks - 1)
+    assert kept['moe_grouped_matmul'] == before['moe_grouped_matmul']
+    got, want = results['kept']['grads'], results['flash only']['grads']
+    assert len(got) == len(want)
+    # The kept arrays are the ones the second forward produced: on the CPU
+    # loss and every gradient leaf come out the same to the last bit.
+    for g, w in zip(got, want):
+      np.testing.assert_array_equal(g, w)
+
+  def test_the_step_names_the_flash_and_the_stream_reads_and_no_more(
+      self, small, kernels_selected):
+    model, state, tokens, _, biased, _ = small
+    program = lambda params: model.loss_fn(
+        params, biased, {'tokens': tokens}, None, ModeKeys.TRAIN, None)[0]
+    get_registry().gauge('hc/kept_bytes_per_token').set(-1.0)
+    jaxpr = jax.make_jaxpr(jax.grad(program))(state.params).jaxpr
+    tagged = _tagged(jaxpr)
+    # The policy is made of the two BACKWARD_READS; a tag renamed in a
+    # forward rule alone fails here rather than bring the second forward
+    # back.
+    assert set(tagged) == set(transformer_lib.flash_lib.BACKWARD_READS) | \
+        set(hc_lib.BACKWARD_READS)
+    # Each name once a sublayer, twice a block, in the forward alone.
+    layers = SMALL['num_hidden_layers']
+    assert {name: len(tagged[name]) for name in hc_lib.BACKWARD_READS} == \
+        dict.fromkeys(hc_lib.BACKWARD_READS, 2 * layers)
+    # The gauge is the tagged bytes a token a block, less the second
+    # sublayer's state: the block's output, kept as the next one's input.
+    rows = tokens.size
+    width = 4 * SMALL['hidden_size'] * 4
+    per_block = (sum(sum(tagged[name]) for name in hc_lib.BACKWARD_READS) /
+                 layers - rows * width) / rows
+    assert get_registry().gauge('hc/kept_bytes_per_token').value == \
+        per_block == hc_lib.kept_bytes_per_token(rows, 4, 128, 4)
+    # At the cell's widths (four streams of 3,584, f in bf16).
+    assert hc_lib.kept_bytes_per_token(4096, 4, 3584, mode='pallas') == \
+        100608
+
+  def test_off_the_kernels_nothing_is_named_and_nothing_counted(self, small):
+    model, state, tokens, _, biased, _ = small
+    program = lambda params: model.loss_fn(
+        params, biased, {'tokens': tokens}, None, ModeKeys.TRAIN, None)[0]
+    jaxpr = jax.make_jaxpr(jax.grad(program))(state.params).jaxpr
+    assert not set(_tagged(jaxpr)) & set(hc_lib.BACKWARD_READS)
+    assert get_registry().gauge('hc/kept_bytes_per_token').value == 0
+
+
+def _tagged(jaxpr, out=None):
+  """{checkpoint name: [bytes of each tagged array]} over a jaxpr, nested
+  ones included."""
+  out = {} if out is None else out
+  for eqn in jaxpr.eqns:
+    if eqn.primitive.name == 'name':
+      aval = eqn.outvars[0].aval
+      out.setdefault(eqn.params['name'], []).append(
+          aval.size * aval.dtype.itemsize)
+    elif eqn.primitive.name != 'pallas_call':
+      for inner in jax.core.jaxprs_in_params(eqn.params):
+        _tagged(inner, out)
+  return out
+
+
+# The other token models at their tests' tiny sizes.
+OTHER_MODELS = {
+    'smallthinker': (smallthinker_model, lambda: SmallThinkerModel(
+        experts_held=(2, 4), hidden_size=64, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, moe_ffn_hidden_size=32,
+        moe_num_primary_experts=8, moe_num_active_primary_experts=3,
+        num_hidden_layers=4, sliding_window_size=8, vocab_rows=64,
+        sequence_length=32, moe_block_rows=8, loss_block_tokens=16,
+        device_type='cpu'), 32),
+    'sdar': (sdar_model, lambda: SDARModel(
+        experts_held=(2, 4), hidden_size=32, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, moe_intermediate_size=16,
+        num_experts=8, num_experts_per_tok=3, num_hidden_layers=2,
+        vocab_rows=64, sequence_length=48, block_length=4, moe_block_rows=8,
+        loss_block_tokens=16, device_type='cpu'), 48),
+    'lfm2': (lfm2_model, lambda: LFM2Model(
+        experts_held=(2, 4), hidden_size=128, num_attention_heads=4,
+        num_key_value_heads=2, intermediate_size=192,
+        moe_intermediate_size=64, num_experts=8, num_experts_per_tok=3,
+        num_hidden_layers=5, num_dense_layers=1, first_layer=1,
+        vocab_rows=64, sequence_length=32, moe_block_rows=8,
+        loss_block_tokens=16, device_type='cpu'), 32),
+}
+
+
+class TestTheOtherTokenModelsNameNoStreamArray:
+  """Their blocks have one stream: the checkpoint that keeps the stream
+  kernels' arrays gives them, to the text, the step the flash only
+  checkpoint gave, with every kernel the TPU selects (nothing runs)."""
+
+  @pytest.mark.parametrize('name', sorted(OTHER_MODELS))
+  def test_the_differentiated_step_is_the_flash_only_one(
+      self, name, monkeypatch, jaxpr_calls):
+    module, make, length = OTHER_MODELS[name]
+    model = make()
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, length), 1, 64)
+    state = model.create_train_state(jax.random.PRNGKey(1),
+                                     {'tokens': tokens}, None)
+    monkeypatch.setattr(runtime, 'on_tpu', lambda: True)
+    monkeypatch.setattr(transformer_lib, 'resolve_attention_mode',
+                        lambda mode, length: 'flash')
+    step = lambda params: model.loss_fn(
+        params, state.model_state, {'tokens': tokens}, None, ModeKeys.TRAIN,
+        jax.random.PRNGKey(2))[0]
+    get_registry().gauge('hc/kept_bytes_per_token').set(-1.0)
+    texts, counts = [], []
+    for block_cls in (module.CheckpointedBlock, FLASH_ONLY):
+      monkeypatch.setattr(module, 'CheckpointedBlock', block_cls)
+      texts.append(re.sub(r' at 0x[0-9a-f]+', '', str(
+          jax.make_jaxpr(jax.grad(step))(state.params))))
+      counts.append(jaxpr_calls(jax.grad(step), state.params))
+    (calls, tags), (calls_before, tags_before) = counts
+    assert texts[0] == texts[1]
+    assert calls == calls_before and tags == tags_before
+    assert set(tags) == set(transformer_lib.flash_lib.BACKWARD_READS)
+    assert calls['flash_attention_fwd'] >= 1
+    assert get_registry().gauge('hc/kept_bytes_per_token').value == 0
