@@ -69,17 +69,19 @@ CheckpointedBlock = nn.remat(
         *transformer_lib.flash_lib.BACKWARD_READS, *hc_lib.BACKWARD_READS))
 
 
-def next_token_loss(hidden, head, tokens, block_tokens: int, dtype):
-  """Mean cross-entropy of position i's logits against token i + 1, in f32,
-  over positions 0..L-2 of every sequence.
+def next_token_loss(hidden, head, tokens, block_tokens: int, dtype,
+                    shift: int = 1):
+  """Mean cross-entropy of position i's logits against token i + ``shift``,
+  in f32, over positions 0..L-1-shift of every sequence (``shift`` 2: a
+  multi-token-prediction module's loss, ``layers/mtp.py``).
 
   hidden [B, L, d], head [d, V], tokens [B, L]; the logits are formed
   ``block_tokens`` tokens at a time (``blocked_cross_entropy``)."""
   b, l, _ = hidden.shape
-  counted = jnp.broadcast_to(jnp.arange(l) < l - 1, (b, l))
+  counted = jnp.broadcast_to(jnp.arange(l) < l - shift, (b, l))
   return transformer_lib.blocked_cross_entropy(
-      hidden, head, jnp.roll(tokens, -1, axis=1), counted, block_tokens,
-      dtype) / (b * (l - 1))
+      hidden, head, jnp.roll(tokens, -shift, axis=1), counted, block_tokens,
+      dtype) / (b * (l - shift))
 
 
 class SmallThinkerNet(nn.Module):

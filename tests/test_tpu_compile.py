@@ -115,6 +115,23 @@ def test_the_latent_attention_widths_compile_for_the_v5e(one_chip):
   assert ' pad(' not in text
 
 
+def test_keys_and_values_of_256_compile_for_the_v5e(one_chip):
+  """q, k and v all 256 wide at the GLM-4.7-Flash cell's shape (20 heads,
+  8,192 tokens), forward and the fused backward at the blocks every width
+  gets: 256 runs whole and fits the scoped VMEM the kernels ask for."""
+  shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16,
+                                             sharding=one_chip)
+  attend = lambda q, k, v: flash_lib.flash_attention(
+      q, k, v, causal=True, scale=0.0625, interpret=False)
+  operand = shape(1, 8192, 20, 256)
+  program = _compiled(lambda q, k, v, g: jax.vjp(attend, q, k, v)[1](g),
+                      operand, operand, operand, operand)
+  text = program.as_text()
+  assert 'flash_attention_fwd' in text and 'flash_attention_bwd_dq' in text
+  assert 'flash_attention_bwd_dkv' not in text
+  assert ' pad(' not in text
+
+
 @pytest.mark.parametrize('kernel', ['hc_pre_fwd', 'hc_post_fwd',
                                     'hc_post_bwd', 'hc_pre_bwd'])
 def test_the_stream_kernels_compile_for_the_v5e(one_chip, kernel):

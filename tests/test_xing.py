@@ -9,6 +9,7 @@ shared expert, attention and streams counted once; the published form only;
 the block's checkpoint keeping what the stream kernels' backward reads, and
 the other token models' steps untouched by it."""
 
+import hashlib
 import re
 
 import flax.linen as nn
@@ -505,10 +506,19 @@ OTHER_MODELS = {
 }
 
 
+# The sha256 of each model's differentiated step below, as the code gave it
+# before the multi-token-prediction module (`layers/mtp.py`) and the shift of
+# `next_token_loss` were added: a change to shared code that moves these
+# steps shows here.
+PARENT_STEP_DIGESTS = {'lfm2': '22ffc9ec8fc05493', 'sdar': 'cf438394ed45c17c',
+                       'smallthinker': '777d5e652004da64'}
+
+
 class TestTheOtherTokenModelsNameNoStreamArray:
   """Their blocks have one stream: the checkpoint that keeps the stream
   kernels' arrays gives them, to the text, the step the flash only
-  checkpoint gave, with every kernel the TPU selects (nothing runs)."""
+  checkpoint gave, with every kernel the TPU selects (nothing runs); and
+  that step is the one they had before multi-token prediction was added."""
 
   @pytest.mark.parametrize('name', sorted(OTHER_MODELS))
   def test_the_differentiated_step_is_the_flash_only_one(
@@ -533,6 +543,8 @@ class TestTheOtherTokenModelsNameNoStreamArray:
       counts.append(jaxpr_calls(jax.grad(step), state.params))
     (calls, tags), (calls_before, tags_before) = counts
     assert texts[0] == texts[1]
+    assert hashlib.sha256(texts[0].encode()).hexdigest()[:16] == \
+        PARENT_STEP_DIGESTS[name]
     assert calls == calls_before and tags == tags_before
     assert set(tags) == set(transformer_lib.flash_lib.BACKWARD_READS)
     assert calls['flash_attention_fwd'] >= 1
