@@ -29,9 +29,22 @@ A tile outside the mask is not a grid step at all: it is not launched,
 its blocks are not fetched and no output block is written back for it. (A
 rectangular grid with a branch on the tile's predicate inside pays all
 three for every tile it skips; what that cost the two token cells is in
-PERF.md, Findings PR 33.) An unmasked call lists the whole rectangle. A
-tile on the mask's edge is computed whole, with the mask applied to its
-elements.
+PERF.md's findings.) An unmasked call lists the whole rectangle.
+
+A step knows from its flags (``tile_table``) what the mask keeps of its
+tile. Where a q block holds two strips of STRIP_ROWS rows or more, a tile
+the mask keeps WHOLE forms no mask and selects no score, and a tile on the
+mask's EDGE (kept in part) runs strip by strip: each strip's products run
+over its SPAN, the STRIP_ROWS-wide column sub-blocks from the first that
+holds one of its kept pairs to the last (``strip_spans``: a prefix of the
+tile's columns on the causal diagonal, a suffix on a window's lower edge,
+the strip's own columns on block diffusion's own-block diagonal), with the
+mask applied inside the span; the online-softmax state of a strip is its
+rows of the q block's scratch. The host lists each strip's (first, count)
+sub-blocks in one more scalar-prefetch table and the kernels branch over
+the span lengths that occur (a slice's size is static). Blocks under two
+strips, the two-kernel backward below and the ring's carry kernel compute
+every tile of a masked call whole, masked.
 
 Memory: per-device O(L*D) activations only — no score tensor ever reaches
 HBM, forward OR backward: the backward is the same kernel family instead of
@@ -138,18 +151,6 @@ def _in_band(q_pos, k_pos, window: Optional[int]):
   return mask
 
 
-def _block_in_band(i_q, i_k, block_q: int, block_k: int,
-                   window: Optional[int]):
-  """Whether block (i_q, i_k) holds any position of the causal band: not
-  wholly above the diagonal and, with ``window``, not wholly left of the
-  band. Blocks outside, on either side, are not needed."""
-  needed = i_q * block_q + block_q - 1 >= i_k * block_k
-  if window is not None:
-    needed = needed & (
-        i_q * block_q - (i_k * block_k + block_k - 1) < window)
-  return needed
-
-
 def _block_index(position, block: int):
   """``position // block`` for non-negative positions (a shift where
   ``block`` is a power of two: the kernels run it on every tile's row and
@@ -179,36 +180,11 @@ def _in_block_diffusion(q_pos, k_pos, length: int, block: int):
   return (k_code < below) | (k_code == own)
 
 
-def _block_in_block_diffusion(i_q, i_k, block_q: int, block_k: int,
-                              length: int, block: int):
-  """Whether tile (i_q, i_k) holds any pair of the block-diffusion mask:
-  its noised rows against its noised columns (block ranges that meet), its
-  noised rows against its clean columns (a clean block strictly below the
-  last noised row's), its clean rows against its clean columns (a clean
-  block at or below the last clean row's). A tile may straddle the border
-  between the halves. Evaluated on the host, numpy indices of the whole
-  rectangle at once."""
-  first_row, last_row = i_q * block_q, i_q * block_q + block_q - 1
-  first_col, last_col = i_k * block_k, i_k * block_k + block_k - 1
-  index = lambda position: _block_index(position, block)
-  noised_rows, clean_rows = first_row < length, last_row >= length
-  noised_cols, clean_cols = first_col < length, last_col >= length
-  last_noised_row = index(np.minimum(last_row, length - 1))
-  first_clean_col = index(np.maximum(first_col, length) - length)
-  own = (noised_rows & noised_cols &
-         (index(first_row) <= index(np.minimum(last_col, length - 1))) &
-         (index(first_col) <= last_noised_row))
-  earlier = noised_rows & clean_cols & (first_clean_col < last_noised_row)
-  causal = clean_rows & clean_cols & (
-      first_clean_col <= index(np.maximum(last_row, length) - length))
-  return own | earlier | causal
-
-
 def _tile_mask(q_base, k_base, block_q: int, block_k: int,
                window: Optional[int], diffusion):
-  """[block_q, block_k] bool of one tile whose first row and column sit at
-  ``q_base`` and ``k_base``: the causal band, or with ``diffusion`` =
-  (length, block) the block-diffusion mask."""
+  """[block_q, block_k] bool of a tile, or of a strip's span in it, whose
+  first row and column sit at ``q_base`` and ``k_base``: the causal band,
+  or with ``diffusion`` = (length, block) the block-diffusion mask."""
   if diffusion is not None:
     q_pos = q_base + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
     k_pos = k_base + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
@@ -218,14 +194,6 @@ def _tile_mask(q_base, k_base, block_q: int, block_k: int,
   k_pos = k_base + jax.lax.broadcasted_iota(
       jnp.int32, (block_q, block_k), 1)
   return _in_band(q_pos, k_pos, window)
-
-
-def _tile_needed(i_q, i_k, block_q: int, block_k: int,
-                 window: Optional[int], diffusion):
-  """The tile-level predicate of the mask ``_tile_mask`` applies (host)."""
-  if diffusion is not None:
-    return _block_in_block_diffusion(i_q, i_k, block_q, block_k, *diffusion)
-  return _block_in_band(i_q, i_k, block_q, block_k, window)
 
 
 def block_diffusion_mask(length: int, block: int):
@@ -252,30 +220,75 @@ def mask_pairs(l_q: int, l_k: int, causal: bool, window: Optional[int],
   return int(np.sum(np.clip(np.minimum(rows, l_k - 1) - first + 1, 0, None)))
 
 
-def _tiles_needed(n_q: int, n_k: int, block_q: int, block_k: int,
-                  causal: bool, window: Optional[int], diffusion):
-  """[n_q, n_k] bool, on the host: the tiles that hold a pair of the mask
-  (every tile of an unmasked call)."""
+def _row_intervals(l_q: int, l_k: int, causal: bool, window: Optional[int],
+                   diffusion):
+  """The columns each row keeps, as disjoint inclusive intervals: a list of
+  (first, last) int64 [l_q] pairs, empty where first > last. The band is one
+  interval a row (rows and columns counted from their first position, as
+  ``_tile_mask`` counts them); block diffusion two, the row's own noised
+  block and the clean columns below its threshold."""
+  rows = np.arange(l_q, dtype=np.int64)
+  if diffusion is not None:
+    length, block = diffusion
+    noised = rows < length
+    start = np.where(noised, rows, rows - length) // block * block
+    own = (np.where(noised, start, 0), np.where(noised, start + block - 1, -1))
+    clean = (np.full(l_q, length),
+             length - 1 + np.where(noised, start, start + block))
+    return [own, clean]
+  if not causal:
+    return [(np.zeros(l_q, np.int64), np.full(l_q, l_k - 1))]
+  first = np.zeros(l_q, np.int64) if window is None else np.maximum(
+      rows - window + 1, 0)
+  return [(first, np.minimum(rows, l_k - 1))]
+
+
+@functools.lru_cache(maxsize=64)
+def _kept_pairs(l_q: int, l_k: int, rows: int, cols: int, causal: bool,
+                window: Optional[int], diffusion):
+  """int64 [l_q // rows, l_k // cols], on the host: the pairs the mask keeps
+  in each ``rows`` x ``cols`` rectangle of the [l_q, l_k] scores. Read-only:
+  every call with these arguments shares it (tables are built each time a
+  call is traced)."""
+  start = np.arange(0, l_k, cols)
+  per_row = sum(
+      np.clip(np.minimum(last[:, None], start + cols - 1) -
+              np.maximum(first[:, None], start) + 1, 0, None)
+      for first, last in _row_intervals(l_q, l_k, causal, window, diffusion))
+  kept = per_row.reshape(l_q // rows, rows, -1).sum(axis=1)
+  kept.flags.writeable = False
+  return kept
+
+
+def _tile_kinds(n_q: int, n_k: int, block_q: int, block_k: int,
+                causal: bool, window: Optional[int], diffusion):
+  """[n_q, n_k] bool twice, on the host: the tiles that hold a pair of the
+  mask, and the tiles it keeps whole (every tile, both, of an unmasked
+  call)."""
   if not causal and diffusion is None:
-    return np.ones((n_q, n_k), bool)
-  return np.broadcast_to(_tile_needed(
-      np.arange(n_q, dtype=np.int32)[:, None],
-      np.arange(n_k, dtype=np.int32)[None, :], block_q, block_k, window,
-      diffusion), (n_q, n_k))
+    return np.ones((n_q, n_k), bool), np.ones((n_q, n_k), bool)
+  kept = _kept_pairs(n_q * block_q, n_k * block_k, block_q, block_k, causal,
+                     window, diffusion)
+  return kept > 0, kept == block_q * block_k
 
 
 def tiles_computed(n_q: int, n_k: int, block_q: int, block_k: int,
                    causal: bool, window: Optional[int], diffusion) -> int:
   """Tiles of the [n_q, n_k] rectangle that the mask keeps: the tiles the
-  kernels compute, and but for an empty block (``tile_table``) the grid
-  steps they launch."""
-  return int(np.sum(_tiles_needed(n_q, n_k, block_q, block_k, causal, window,
-                                  diffusion)))
+  kernels compute (an edge tile in part, ``strip_spans``), and but for an
+  empty block (``tile_table``) the grid steps they launch."""
+  return int(np.sum(_tile_kinds(n_q, n_k, block_q, block_k, causal, window,
+                                diffusion)[0]))
 
 
-# Flags of a grid step (``tile_table``): its accumulator starts here, and
-# is written out here.
-FIRST, LAST = 1, 2
+# Flags of a grid step (``tile_table``): its accumulator starts here, is
+# written out here; its tile is one the mask keeps only in part.
+FIRST, LAST, EDGE = 1, 2, 4
+
+# The rows of one strip of an edge tile, and the width of the column
+# sub-blocks its span is made of (``strip_spans``); chosen on the chip
+# against 128 (PERF.md, section 6).
+STRIP_ROWS = 256
 
 
 def tile_table(n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
@@ -292,21 +305,27 @@ def tile_table(n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
   rectangular sweep had, so every sum is formed in that order). Steps of
   one accumulator are consecutive: the resident blocks and the output are
   named by every one of them, so they are fetched and written back once.
-  ``flags`` has FIRST on an accumulator's first step and LAST on its last.
+  ``flags`` has FIRST on an accumulator's first step, LAST on its last and
+  EDGE on a step whose tile the mask keeps only in part (an unmasked call
+  has no EDGE step): where the blocks hold strips the kernels mask an edge
+  tile and no other (``_each_part``).
 
   An accumulator that the mask leaves NO tile (a k/v block past the last
   query under ``causal`` with ``l_k > l_q``; never a self-attention row,
-  which sees itself) still gets one step, FIRST | LAST, on its first
-  candidate tile: the in-tile mask keeps nothing of it, so the step
+  which sees itself) still gets one step, FIRST | LAST | EDGE, on its
+  first candidate tile: the in-tile mask keeps nothing of it, so the step
   computes the defined output (zeros for dk/dv and ``out``) at the price
-  of one tile, with no branch in the kernel."""
-  needed = _tiles_needed(n_q, n_k, block_q, block_k, causal, window,
-                         diffusion)
-  needed = np.tile(needed.T if k_resident else needed, (1, group))
+  of one tile, with no branch of its own in the kernel."""
+  needed, whole = _tile_kinds(n_q, n_k, block_q, block_k, causal, window,
+                              diffusion)
+  if k_resident:
+    needed, whole = needed.T, whole.T
+  needed, whole = np.tile(needed, (1, group)), np.tile(whole, (1, group))
   needed[~needed.any(axis=1), 0] = True
   resident, streamed = np.nonzero(needed)   # row-major: both ascending
   first = np.append(True, resident[1:] != resident[:-1])
-  flags = FIRST * first + LAST * np.append(first[1:], True)
+  flags = (FIRST * first + LAST * np.append(first[1:], True) +
+           EDGE * ~whole[resident, streamed])
   if k_resident:
     rows = (streamed // n_q, streamed % n_q, resident, flags)
   else:
@@ -314,39 +333,142 @@ def tile_table(n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
   return np.stack(rows).astype(np.int32)
 
 
-def _set_gauges(suffix: str, bh: int, l_q: int, l_k: int, block_q: int,
-                block_k: int, causal: bool, window, diffusion, steps: int,
+def strip_spans(table, l_q: int, l_k: int, block_q: int, block_k: int,
+                causal: bool, window: Optional[int], diffusion):
+  """int32 [steps, strips, 2], on the host: how each step of a q-resident
+  ``table`` runs an edge tile. Where a q block holds two strips of
+  STRIP_ROWS rows or more (and STRIP_ROWS divides both blocks), an EDGE
+  step runs strip by strip, each over its SPAN: (first, count) sub-blocks
+  of STRIP_ROWS columns of the tile, from the first that holds a pair of
+  the strip's rows to the last; (0, 0) for a strip that holds none, and
+  for every strip of an interior step. Elsewhere, and where no step is an
+  edge step, there are no strips (shape [steps, 0, 2]) and an edge tile is
+  computed whole."""
+  size = STRIP_ROWS
+  if (block_q < 2 * size or block_q % size or block_k % size or
+      not np.any(table[3] & EDGE)):
+    return np.zeros((table.shape[1], 0, 2), np.int32)
+  strips, subs = block_q // size, block_k // size
+  held = (_kept_pairs(l_q, l_k, size, size, causal, window, diffusion) > 0
+         ).reshape(l_q // block_q, strips, l_k // block_k, subs)
+  held = held.transpose(0, 2, 1, 3)[table[1], table[2]]  # [steps, strips, subs]
+  held &= (table[3] & EDGE != 0)[:, None, None]
+  first = np.argmax(held, axis=-1)
+  count = np.where(held.any(axis=-1),
+                   subs - np.argmax(held[..., ::-1], axis=-1) - first, 0)
+  return np.stack([first * (count > 0), count], axis=-1).astype(np.int32)
+
+
+def _stripped(table, spans):
+  """bool [steps]: the steps of ``table`` that run as strips."""
+  return (table[3] & EDGE != 0) & (spans.shape[1] > 0)
+
+
+def pairs_computed(table, spans, block_q: int, block_k: int) -> int:
+  """Pairs the kernel of a q-resident ``table`` computes a query head: a
+  whole tile's at every step but those run as strips (``strip_spans``),
+  which compute their spans' sub-blocks."""
+  stripped = _stripped(table, spans)
+  size = block_q // max(spans.shape[1], 1)
+  return int(np.count_nonzero(~stripped) * block_q * block_k +
+             size * size * spans[stripped, :, 1].sum())
+
+
+def _strip_plan(table, l_q: int, l_k: int, block_q: int, block_k: int,
+                causal: bool, window: Optional[int], diffusion, *,
+                suffix: str, bh: int, whole: bool = False, **gauges):
+  """``strip_spans`` of a q-resident kernel's table (none with ``whole``),
+  flat for its scalar prefetch (one 0 where there are no strips), and the
+  span lengths its strips take; sets the kernel's gauges (``_set_gauges``)
+  when the call is masked."""
+  spans = np.zeros((table.shape[1], 0, 2), np.int32) if whole else \
+      strip_spans(table, l_q, l_k, block_q, block_k, causal, window,
+                  diffusion)
+  if causal or diffusion is not None:
+    _set_gauges(suffix, bh, mask_pairs(l_q, l_k, causal, window, diffusion),
+                pairs_computed(table, spans, block_q, block_k),
+                edge_steps=int(np.count_nonzero(_stripped(table, spans))),
+                **gauges)
+  flat = spans.reshape(-1) if spans.size else np.zeros(1, np.int32)
+  return flat, _span_lengths(spans)
+
+
+def _span_lengths(spans):
+  """The span lengths each strip takes somewhere in ``spans`` (static: the
+  kernels hold one branch for each)."""
+  return tuple(tuple(sorted(set(spans[:, strip, 1].tolist()) - {0}))
+               for strip in range(spans.shape[1]))
+
+
+def _set_gauges(suffix: str, bh: int, needed: int, computed: int, *,
+                steps: int, edge_steps: int,
                 backward_kernels: Optional[int] = None):
-  """Host side, when a masked call is traced: the pairs the mask keeps, the
-  pairs of the tiles the kernels compute and the grid steps they launch
-  (``steps`` a query head), a call (all heads); from the backward also the
-  kernels it launches (1 fused, 2 not)."""
+  """Host side, when a masked call is traced, all heads of the call: the
+  pairs the mask keeps (``needed``) and the pairs a kernel computes
+  (``computed``: interior tiles whole, an edge tile's strips over their
+  spans, or the whole edge tile where it holds no strips), the grid steps
+  it launches and of them those run as strips (``steps``, ``edge_steps``,
+  a query head); from the backward also the kernels it launches (1 fused,
+  2 not)."""
   from tensor2robot_tpu.observability import get_registry
 
   registry = get_registry()
   if backward_kernels is not None:
     registry.gauge('attention/backward_kernels').set(float(backward_kernels))
-  registry.gauge('attention/mask_pairs_needed').set(
-      float(bh * mask_pairs(l_q, l_k, causal, window, diffusion)))
-  registry.gauge('attention/mask_pairs_computed' + suffix).set(float(
-      bh * block_q * block_k * tiles_computed(
-          l_q // block_q, l_k // block_k, block_q, block_k, causal, window,
-          diffusion)))
+  registry.gauge('attention/mask_pairs_needed').set(float(bh * needed))
+  registry.gauge('attention/mask_pairs_computed' + suffix).set(
+      float(bh * computed))
   registry.gauge('attention/grid_steps' + suffix).set(float(bh * steps))
+  registry.gauge('attention/edge_steps' + suffix).set(float(bh * edge_steps))
 
 
-def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
-                  scale: float, causal: bool, block_q: int, block_k: int,
-                  q_offset, k_offset, i_q, i_k,
-                  window: Optional[int] = None, diffusion=None):
-  """The shared online-softmax block update both kernels run.
+def _rows_at(start, size: int):
+  """``size`` rows (or lanes) from ``start``: a static slice, or a dynamic
+  one at a traced start (a strip's span, ``_each_part``)."""
+  if isinstance(start, int):
+    return slice(start, start + size)
+  return pl.ds(start, size)
 
-  Reads one q/k/v block from refs, scores it, and folds it into the
-  (acc, m, l) scratch accumulators. ``q_offset``/``k_offset`` are the
-  GLOBAL positions of the blocks' first rows (plain ints or traced
-  scalars) for causal masking. ``i_q``/``i_k`` are the grid indices,
-  passed in because pl.program_id cannot be called inside a pl.when
-  branch under the CPU interpreter.
+
+def _each_part(t, flags_ref, spans_ref, part, *, masked: bool, block_q: int,
+               block_k: int, strips):
+  """Runs ``part(row, rows, col, cols, masked)`` over what step ``t``'s tile
+  needs: the rows [row, row + rows) of the q block against the columns
+  [col, col + cols) of the k block. Where the blocks hold strips
+  (``strips``: the span lengths each strip takes, one branch each, since a
+  slice's size must be static), an interior tile (no EDGE flag) runs whole
+  with no mask and an edge tile strip by strip, each of block_q /
+  len(strips) rows (STRIP_ROWS, as ``strip_spans`` cut them) over its span
+  of sub-blocks as wide (read from ``spans_ref``), masked. Blocks
+  under two strips run every tile whole, masked where the call is, with no
+  branch: skipping the mask there cost more than it saved (PERF.md,
+  section 6)."""
+  if not (masked and strips):
+    part(0, block_q, 0, block_k, masked)
+    return
+  edge = flags_ref[t] & EDGE != 0
+  pl.when(jnp.logical_not(edge))(
+      functools.partial(part, 0, block_q, 0, block_k, False))
+
+  size = block_q // len(strips)
+
+  @pl.when(edge)
+  def _strips():
+    for strip, lengths in enumerate(strips):
+      at = (t * len(strips) + strip) * 2
+      first, count = spans_ref[at], spans_ref[at + 1]
+      col = pl.multiple_of(first * size, size)
+      for length in lengths:
+        pl.when(count == length)(functools.partial(
+            part, strip * size, size, col, length * size, True))
+
+
+def _block_update(q, k, v, acc_ref, m_ref, l_ref, rows, *, scale: float,
+                  mask=None):
+  """The shared online-softmax update both kernels run: scores the q rows
+  against the k/v rows given, and folds them into the (acc, m, l) scratch
+  accumulators' ``rows``. ``mask`` (bool, the scores' shape) is given on a
+  tile the mask keeps in part; None forms no mask and selects no score.
 
   m/l scratch is [bq, 128] with the per-row scalar broadcast UNIFORMLY
   across all 128 lanes: jax 0.9's Mosaic rejects sub-slicing width-1
@@ -354,40 +476,43 @@ def _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
   tiling (128)"), so the scalars are read back with a lane-reduce and
   stored with a broadcast instead of living in [bq, 1] refs.
   """
-  q = q_ref[0].astype(jnp.float32)                       # [bq, D]
-  k = k_ref[0].astype(jnp.float32)                       # [bk, D]
-  v = v_ref[0].astype(jnp.float32)
+  q = q.astype(jnp.float32)                              # [rows, D]
+  k = k.astype(jnp.float32)                              # [cols, D]
+  v = v.astype(jnp.float32)
   s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                           preferred_element_type=jnp.float32) * scale
-  if causal or diffusion is not None:
-    s = jnp.where(_tile_mask(q_offset + i_q * block_q,
-                             k_offset + i_k * block_k, block_q, block_k,
-                             window, diffusion), s, NEG_INF)
+  if mask is not None:
+    s = jnp.where(mask, s, NEG_INF)
 
-  m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)   # [bq, 1]
-  l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
-  m_block = jnp.max(s, axis=-1, keepdims=True)           # [bq, 1]
+  m_prev = jnp.max(m_ref[rows], axis=-1, keepdims=True)  # [rows, 1]
+  l_prev = jnp.max(l_ref[rows], axis=-1, keepdims=True)
+  m_block = jnp.max(s, axis=-1, keepdims=True)
   m_new = jnp.maximum(m_prev, m_block)
   safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
   p = jnp.exp(s - safe_m)
+  # A no-op where nothing is masked, kept on every tile: without it XLA's
+  # CPU backend (the interpreter's) forms the exponential in two fusions
+  # whose last bits differ, and interior tiles would not be the parent's.
   p = jnp.where(s <= NEG_INF / 2, 0.0, p)
   correction = jnp.exp(m_prev - safe_m)
   correction = jnp.where(m_prev <= NEG_INF / 2, 0.0, correction)
   l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-  l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-  m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-  acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
+  lanes = (l_new.shape[0], l_ref.shape[1])
+  l_ref[rows] = jnp.broadcast_to(l_new, lanes)
+  m_ref[rows] = jnp.broadcast_to(m_new, lanes)
+  acc_ref[rows] = acc_ref[rows] * correction + jax.lax.dot_general(
       p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _flash_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref, v_ref,
-                  o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                  causal: bool, block_q: int, block_k: int,
-                  window: Optional[int] = None, diffusion=None):
+def _flash_kernel(q_blocks_ref, k_blocks_ref, flags_ref, spans_ref, q_ref,
+                  k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
+                  scale: float, causal: bool, block_q: int, block_k: int,
+                  window: Optional[int] = None, diffusion=None, strips=()):
   """One step of the grid (bh, steps of ``tile_table``): one tile the mask
-  keeps, folded into its q block's accumulators. The q block, its output
-  and its log-sum-exp are named by every step of the q block, so they move
-  once; k and v blocks stream past in ascending order."""
+  keeps, folded into its q block's accumulators (``_each_part``: whole,
+  or an edge tile's strips). The q block, its output and its log-sum-exp
+  are named by every step of the q block, so they move once; k and v
+  blocks stream past in ascending order."""
   t = pl.program_id(1)
   flags = flags_ref[t]
 
@@ -397,12 +522,18 @@ def _flash_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref, v_ref,
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-  # One shared numerics implementation (_block_update) for both this
-  # kernel and the ring-carry kernel.
-  _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale=scale,
-                causal=causal, block_q=block_q, block_k=block_k, q_offset=0,
-                k_offset=0, i_q=q_blocks_ref[t], i_k=k_blocks_ref[t],
-                window=window, diffusion=diffusion)
+  q_base, k_base = q_blocks_ref[t] * block_q, k_blocks_ref[t] * block_k
+
+  def part(row, rows, col, cols, masked):
+    q_rows, k_rows = _rows_at(row, rows), _rows_at(col, cols)
+    mask = _tile_mask(q_base + row, k_base + col, rows, cols, window,
+                      diffusion) if masked else None
+    _block_update(q_ref[0, q_rows], k_ref[0, k_rows], v_ref[0, k_rows],
+                  acc_ref, m_ref, l_ref, q_rows, scale=scale, mask=mask)
+
+  _each_part(t, flags_ref, spans_ref, part,
+             masked=causal or diffusion is not None, block_q=block_q,
+             block_k=block_k, strips=strips)
 
   @pl.when(flags & LAST != 0)
   def _finalize():
@@ -411,7 +542,7 @@ def _flash_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref, v_ref,
     l_final = jnp.maximum(l_col, 1e-20)
     o_ref[0] = (acc_ref[...] / l_final).astype(o_ref.dtype)
     # Log-sum-exp per row, saved for the backward pass (FlashAttention).
-    # Broadcast over the 8 padding sublanes (see _flash_bhld's lse shape).
+    # Broadcast over the 8 padding sublanes (see _flash_fwd_call's lse).
     row = (m_col + jnp.log(l_final))[:, 0]
     lse_ref[0] = jnp.broadcast_to(row[None, :], (8, block_q))
 
@@ -426,7 +557,34 @@ def _kv_head(group: int):
 def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
                 block_k: int, interpret: bool, window: Optional[int] = None,
                 diffusion=None):
-  """[BH, L, D] flash attention via pallas_call.
+  """[BH, L, D] flash attention: the grid's tables on the host
+  (``tile_table``; ``_strip_plan``, which sets the gauges of a masked
+  call), the kernel by ``_flash_fwd_call``."""
+  bh, l_q, _ = q.shape
+  l_k = k.shape[1]
+  table = tile_table(l_q // block_q, l_k // block_k, block_q, block_k,
+                     causal, window, diffusion)
+  spans, strips = _strip_plan(table, l_q, l_k, block_q, block_k, causal,
+                              window, diffusion, suffix='', bh=bh,
+                              steps=table.shape[1])
+  return _flash_fwd_call(*table[1:], spans, q, k, v, scale=scale,
+                         causal=causal, block_q=block_q, block_k=block_k,
+                         interpret=interpret, window=window,
+                         diffusion=diffusion, strips=strips)
+
+
+# What fixes a kernel's body beside the shapes: a launcher is jitted on it,
+# so the calls of a program that agree (a model's layers) share one trace
+# and one lowering of the kernel.
+_KERNEL_STATICS = ('scale', 'causal', 'block_q', 'block_k', 'interpret',
+                   'window', 'diffusion', 'strips')
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _flash_fwd_call(q_blocks, k_blocks, flags, spans, q, k, v, *, scale,
+                    causal, block_q, block_k, interpret, window, diffusion,
+                    strips):
+  """The forward kernel over its tables (``_flash_bhld``).
 
   k/v may hold fewer heads than q ([BH/group, L, D], grouped-query
   attention): query head ``b`` then reads k/v head ``b // group`` through
@@ -439,23 +597,18 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
   (bh, L): ~3.5 MB at bh=8, L=16k — noise next to the k/v tensors.
   """
   bh, l_q, d = q.shape
-  l_k, d_v = k.shape[1], v.shape[2]
+  d_v = v.shape[2]
   kv = _kv_head(bh // k.shape[0])
-  table = tile_table(l_q // block_q, l_k // block_k, block_q, block_k,
-                     causal, window, diffusion)
-  if causal or diffusion is not None:
-    _set_gauges('', bh, l_q, l_k, block_q, block_k, causal, window,
-                diffusion, table.shape[1])
   kernel = functools.partial(
       _flash_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k, window=window, diffusion=diffusion)
+      block_k=block_k, window=window, diffusion=diffusion, strips=strips)
   # Index maps receive the table's rows (but the head's, which is 0 for a
-  # resident q block) as trailing arguments.
-  q_block = lambda b, t, qs, ks, flags: (b, qs[t], 0)
-  kv_block = lambda b, t, qs, ks, flags: (kv(b), ks[t], 0)
+  # resident q block) and the spans as trailing arguments.
+  q_block = lambda b, t, qs, *_: (b, qs[t], 0)
+  kv_block = lambda b, t, qs, ks, *_: (kv(b), ks[t], 0)
   grid_spec = pltpu.PrefetchScalarGridSpec(
-      num_scalar_prefetch=3,
-      grid=(bh, table.shape[1]),
+      num_scalar_prefetch=4,
+      grid=(bh, flags.shape[0]),
       in_specs=[
           pl.BlockSpec((1, block_q, d), q_block),
           pl.BlockSpec((1, block_k, d), kv_block),
@@ -463,8 +616,7 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
       ],
       out_specs=[
           pl.BlockSpec((1, block_q, d_v), q_block),
-          pl.BlockSpec((1, 8, block_q),
-                       lambda b, t, qs, ks, flags: (b, 0, qs[t])),
+          pl.BlockSpec((1, 8, block_q), lambda b, t, qs, *_: (b, 0, qs[t])),
       ],
       scratch_shapes=[
           pltpu.VMEM((block_q, d_v), jnp.float32),
@@ -482,7 +634,7 @@ def _flash_bhld(q, k, v, *, scale: float, causal: bool, block_q: int,
       ],
       interpret=interpret,
       name='flash_attention_fwd',
-  )(*table[1:], q, k, v)
+  )(q_blocks, k_blocks, flags, spans, q, k, v)
   return out, lse8[:, 0, :]
 
 
@@ -506,7 +658,7 @@ def _flash_carry_kernel(offsets_ref, q_ref, k_ref, v_ref, o_in_ref,
   def _init():
     acc_ref[:] = o_in_ref[0].astype(jnp.float32)
     # m/l ride in [1, 8, block_q] blocks (8 broadcast sublanes — Mosaic's
-    # output-block divisibility rule; see _flash_bhld's lse note). Reduce
+    # output-block divisibility rule; see _flash_fwd_call's lse note). Reduce
     # over the uniform sublanes rather than slicing one (width-1 memref
     # slices are rejected by jax 0.9 Mosaic), then broadcast across the
     # 128 scalar lanes of the scratch.
@@ -516,10 +668,12 @@ def _flash_carry_kernel(offsets_ref, q_ref, k_ref, v_ref, o_in_ref,
     l_ref[...] = jnp.broadcast_to(l_col, l_ref.shape)
 
   def _do_update():
-    _block_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale=scale,
-                  causal=causal, block_q=block_q, block_k=block_k,
-                  q_offset=offsets_ref[0], k_offset=offsets_ref[1],
-                  i_q=i_q, i_k=i_k)
+    # No table here: a causal tile is masked whole, interior or not.
+    mask = _tile_mask(offsets_ref[0] + i_q * block_q,
+                      offsets_ref[1] + i_k * block_k, block_q, block_k, None,
+                      None) if causal else None
+    _block_update(q_ref[0], k_ref[0], v_ref[0], acc_ref, m_ref, l_ref,
+                  slice(None), scale=scale, mask=mask)
 
   if causal:
     # Global-position block skip (offsets are traced scalars): the block
@@ -570,7 +724,7 @@ def flash_attention_carry(q, k, v, o, m, l, q_offset, k_offset,
   offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                        jnp.asarray(k_offset, jnp.int32)])
   # m/l carries are padded to 8 broadcast sublanes for Mosaic's block
-  # divisibility rule (same scheme as _flash_bhld's lse output).
+  # divisibility rule (same scheme as _flash_fwd_call's lse output).
   m8 = jnp.broadcast_to(m[:, None, :], (bh, 8, l_q))
   l8 = jnp.broadcast_to(l[:, None, :], (bh, 8, l_q))
   grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -625,29 +779,33 @@ def _bwd_default_blocks(l_q: int, l_k: int):
   return (256, 256) if max(l_q, l_k) <= 4096 else (512, 1024)
 
 
-def _bwd_tile(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref, v_ref, do_ref,
-              lse_ref, delta_ref, *, scale, causal, block_q, block_k,
-              window=None, diffusion=None):
-  """What every backward kernel forms of step ``t``'s tile, from the saved
-  log-sum-exp: (q, k, do, p, ds), float32 2D blocks."""
-  q = q_ref[0].astype(jnp.float32)
-  k = k_ref[0].astype(jnp.float32)
-  do = do_ref[0].astype(jnp.float32)
+def _bwd_part(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref, v_ref, do_ref,
+              lse_ref, delta_ref, row, rows, col, cols, masked, *, scale,
+              causal, block_q, block_k, window=None, diffusion=None):
+  """What every backward kernel forms of a part of step ``t``'s tile (the
+  rows [row, row + rows) of the q block against the columns [col, col +
+  cols) of the k block: ``_each_part``), from the saved log-sum-exp: (q,
+  k, do, p, ds), float32 2D blocks."""
+  q_rows, k_rows = _rows_at(row, rows), _rows_at(col, cols)
+  q = q_ref[0, q_rows].astype(jnp.float32)
+  k = k_ref[0, k_rows].astype(jnp.float32)
+  do = do_ref[0, q_rows].astype(jnp.float32)
   # Reduce over the uniform broadcast sublanes instead of slicing one
   # (width-1 memref slices are rejected by jax 0.9 Mosaic).
-  lse = jnp.max(lse_ref[0].astype(jnp.float32), axis=0)[:, None]
-  delta = jnp.max(delta_ref[0].astype(jnp.float32), axis=0)[:, None]
+  lse = jnp.max(lse_ref[0, :, q_rows].astype(jnp.float32), axis=0)[:, None]
+  delta = jnp.max(delta_ref[0, :, q_rows].astype(jnp.float32),
+                  axis=0)[:, None]
   s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                           preferred_element_type=jnp.float32) * scale
-  masked = causal or diffusion is not None
   if masked:
-    s = jnp.where(_tile_mask(q_blocks_ref[t] * block_q,
-                             k_blocks_ref[t] * block_k, block_q, block_k,
+    s = jnp.where(_tile_mask(q_blocks_ref[t] * block_q + row,
+                             k_blocks_ref[t] * block_k + col, rows, cols,
                              window, diffusion), s, NEG_INF)
   p = jnp.exp(s - lse)
-  if masked:
+  if causal or diffusion is not None:
+    # On every tile of a masked call, interior too: see _block_update.
     p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-  dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
+  dp = jax.lax.dot_general(do, v_ref[0, k_rows].astype(jnp.float32),
                            (((1,), (1,)), ((), ())),
                            preferred_element_type=jnp.float32)
   ds = p * (dp - delta) * scale
@@ -661,23 +819,24 @@ def _rows_t(a, b):
                              preferred_element_type=jnp.float32)
 
 
-def _flash_bwd_fused_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref,
-                            k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                            dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                            group: int, block_k: int, **tile):
+def _flash_bwd_fused_kernel(q_blocks_ref, k_blocks_ref, flags_ref, spans_ref,
+                            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                            dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                            group: int, strips, **tile):
   """dq, dk and dv in one: grid (bh, steps of ``tile_table``), the dq
   kernel's, q block resident and k/v streaming; s and dp are formed once a
-  tile and all three gradients leave the step. dk and dv accumulate in
-  float32 over the WHOLE k/v head ([l_k, d] scratch each, a step adds its
-  tile at the k block's rows): zeroed at the first step of the group's
-  first query head, written at the last step of its last. The group's
-  query heads follow one another on the first grid axis and the dk/dv
-  output block is the whole k/v head, so it stays put over all of them and
-  is written back once; a k/v block that the mask leaves no tile keeps its
-  zeros."""
+  tile (or once a strip's span of an edge tile: ``_each_part``) and all
+  three gradients leave the step. dk and dv accumulate in float32 over the
+  WHOLE k/v head ([l_k, d] scratch each, a part adds at its columns' rows):
+  zeroed at the first step of the group's first query head, written at the
+  last step of its last. The group's query heads follow one another on the
+  first grid axis and the dk/dv output block is the whole k/v head, so it
+  stays put over all of them and is written back once; a k/v block that
+  the mask leaves no tile keeps its zeros."""
   b, t = pl.program_id(0), pl.program_id(1)
   flags = flags_ref[t]
   last_step = t == pl.num_programs(1) - 1
+  block_k = tile['block_k']
 
   @pl.when((t == 0) & (b % group == 0))
   def _init_kv():
@@ -688,14 +847,22 @@ def _flash_bwd_fused_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref,
   def _init_q():
     dq_acc[...] = jnp.zeros_like(dq_acc)
 
-  q, k, do, p, ds = _bwd_tile(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref,
-                              v_ref, do_ref, lse_ref, delta_ref,
-                              block_k=block_k, **tile)
-  rows = pl.ds(pl.multiple_of(k_blocks_ref[t] * block_k, block_k), block_k)
-  dv_acc[rows, :] += _rows_t(p, do)
-  dk_acc[rows, :] += _rows_t(ds, q)
-  dq_acc[...] += jax.lax.dot_general(
-      ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+  def part(row, rows, col, cols, masked):
+    q, k, do, p, ds = _bwd_part(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref,
+                                v_ref, do_ref, lse_ref, delta_ref, row, rows,
+                                col, cols, masked, **tile)
+    # A strip's span starts on a sub-block as wide as the strip is tall.
+    kv_rows = pl.ds(pl.multiple_of(k_blocks_ref[t] * block_k + col,
+                                   block_k if isinstance(col, int) else rows),
+                    cols)
+    dv_acc[kv_rows, :] += _rows_t(p, do)
+    dk_acc[kv_rows, :] += _rows_t(ds, q)
+    dq_acc[_rows_at(row, rows)] += jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+  _each_part(t, flags_ref, spans_ref, part,
+             masked=tile['causal'] or tile['diffusion'] is not None,
+             block_q=tile['block_q'], block_k=block_k, strips=strips)
 
   @pl.when(flags & LAST != 0)
   def _finalize_q():
@@ -725,8 +892,10 @@ def _flash_bwd_kv_kernel(head_ref, q_blocks_ref, k_blocks_ref, flags_ref,
     dk_acc[...] = jnp.zeros_like(dk_acc)
     dv_acc[...] = jnp.zeros_like(dv_acc)
 
-  q, _, do, p, ds = _bwd_tile(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref,
-                              v_ref, do_ref, lse_ref, delta_ref, **tile)
+  q, _, do, p, ds = _bwd_part(
+      t, q_blocks_ref, k_blocks_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+      delta_ref, 0, tile['block_q'], 0, tile['block_k'],
+      tile['causal'] or tile['diffusion'] is not None, **tile)
   dv_acc[...] += _rows_t(p, do)
   dk_acc[...] += _rows_t(ds, q)
 
@@ -736,11 +905,12 @@ def _flash_bwd_kv_kernel(head_ref, q_blocks_ref, k_blocks_ref, flags_ref,
     dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_q_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref,
-                        v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-                        **tile):
+def _flash_bwd_q_kernel(q_blocks_ref, k_blocks_ref, flags_ref, spans_ref,
+                        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dq_ref, dq_acc, **tile):
   """dq of the two-kernel backward: grid (bh, steps of ``tile_table``) — q
   block resident, k/v stream through; s and dp formed a second time."""
+  del spans_ref  # no strips on this path
   t = pl.program_id(1)
   flags = flags_ref[t]
 
@@ -748,8 +918,10 @@ def _flash_bwd_q_kernel(q_blocks_ref, k_blocks_ref, flags_ref, q_ref, k_ref,
   def _init():
     dq_acc[...] = jnp.zeros_like(dq_acc)
 
-  _, k, _, _, ds = _bwd_tile(t, q_blocks_ref, k_blocks_ref, q_ref, k_ref,
-                             v_ref, do_ref, lse_ref, delta_ref, **tile)
+  _, k, _, _, ds = _bwd_part(
+      t, q_blocks_ref, k_blocks_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+      delta_ref, 0, tile['block_q'], 0, tile['block_k'],
+      tile['causal'] or tile['diffusion'] is not None, **tile)
   dq_acc[...] += jax.lax.dot_general(
       ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -801,37 +973,56 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
   bh, l_q, d = q.shape
   l_k, d_v = k.shape[1], v.shape[2]
   group = bh // k.shape[0]
-  kv = _kv_head(group)
-  resident = _fused_bwd_resident_bytes(l_k, d, k.dtype, d_v)
-  fused = resident <= FUSED_BWD_RESIDENT_BYTES
+  fused = _fused_bwd_resident_bytes(l_k, d, k.dtype, d_v) <= \
+      FUSED_BWD_RESIDENT_BYTES
   tiles = (l_q // block_q, l_k // block_k, block_q, block_k, causal, window,
            diffusion)
   q_table = tile_table(*tiles)
   kv_table = None if fused else tile_table(*tiles, k_resident=True,
                                            group=group)
-  if causal or diffusion is not None:
-    # ONE backward kernel's steps a query head. The two kernels' differ
-    # only where the mask leaves a q block or a k/v block empty: the larger.
-    steps = q_table.shape[1] if fused else max(
-        q_table.shape[1], kv_table.shape[1] // group)
-    _set_gauges('_bwd', bh, l_q, l_k, block_q, block_k, causal, window,
-                diffusion, steps, backward_kernels=1 if fused else 2)
+  # ONE backward kernel's steps a query head. The two kernels' differ only
+  # where the mask leaves a q block or a k/v block empty: the larger. The
+  # two-kernel backward runs an edge tile whole.
+  steps = q_table.shape[1] if fused else max(
+      q_table.shape[1], kv_table.shape[1] // group)
+  spans, strips = _strip_plan(
+      q_table, l_q, l_k, block_q, block_k, causal, window, diffusion,
+      whole=not fused, suffix='_bwd', bh=bh, steps=steps,
+      backward_kernels=1 if fused else 2)
   do = d_out.astype(jnp.float32)
   delta = jnp.sum(do * out.astype(jnp.float32), axis=-1)      # [BH, Lq]
   # lse/delta ride as [BH, 8, L] broadcast-sublane blocks (Mosaic's
   # second-minor divisibility rule — same scheme as the forward's lse).
   lse8 = jnp.broadcast_to(lse[:, None, :], (bh, 8, l_q))
   delta8 = jnp.broadcast_to(delta[:, None, :], (bh, 8, l_q))
+  return _flash_bwd_call(*q_table[1:], spans, kv_table, q, k, v, d_out, lse8,
+                         delta8, scale=scale, causal=causal, block_q=block_q,
+                         block_k=block_k, interpret=interpret, window=window,
+                         diffusion=diffusion, strips=strips)
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _flash_bwd_call(q_blocks, k_blocks, flags, spans, kv_table, q, k, v,
+                    d_out, lse8, delta8, *, scale, causal, block_q, block_k,
+                    interpret, window, diffusion, strips):
+  """The backward kernels over their tables (``_flash_bwd_pallas``): the
+  fused one, or the two-kernel pair where ``kv_table`` (the dk/dv kernel's)
+  is given."""
+  bh, _, d = q.shape
+  l_k, d_v = k.shape[1], v.shape[2]
+  group = bh // k.shape[0]
+  kv = _kv_head(group)
   tile = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
               window=window, diffusion=diffusion)
 
-  # Index maps receive the table's rows as trailing arguments.
-  q_of_q = lambda b, t, qs, ks, flags: (b, qs[t], 0)
-  row_of_q = lambda b, t, qs, ks, flags: (b, 0, qs[t])
-  kv_of_q = lambda b, t, qs, ks, flags: (kv(b), ks[t], 0)
+  # Index maps receive the table's rows (and the spans) as trailing
+  # arguments.
+  q_of_q = lambda b, t, qs, *_: (b, qs[t], 0)
+  row_of_q = lambda b, t, qs, *_: (b, 0, qs[t])
+  kv_of_q = lambda b, t, qs, ks, *_: (kv(b), ks[t], 0)
   q_resident = dict(
-      num_scalar_prefetch=3,
-      grid=(bh, q_table.shape[1]),
+      num_scalar_prefetch=4,
+      grid=(bh, flags.shape[0]),
       in_specs=[
           pl.BlockSpec((1, block_q, d), q_of_q),
           pl.BlockSpec((1, block_k, d), kv_of_q),
@@ -844,11 +1035,13 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
   dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
   dkv_shapes = [jax.ShapeDtypeStruct(k.shape, k.dtype),
                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
-  if fused:
+  if kv_table is None:
+    resident = _fused_bwd_resident_bytes(l_k, d, k.dtype, d_v)
     kv_head = lambda width: pl.BlockSpec(
-        (1, l_k, width), lambda b, t, qs, ks, flags: (kv(b), 0, 0))
+        (1, l_k, width), lambda b, t, *_: (kv(b), 0, 0))
     return pl.pallas_call(
-        functools.partial(_flash_bwd_fused_kernel, group=group, **tile),
+        functools.partial(_flash_bwd_fused_kernel, group=group,
+                          strips=strips, **tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             out_specs=[dq_spec, kv_head(d), kv_head(d_v)],
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
@@ -862,7 +1055,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
             vmem_limit_bytes=resident + _FUSED_BWD_STREAMED_BYTES),
         interpret=interpret,
         name='flash_attention_bwd_dq',
-    )(*q_table[1:], q, k, v, d_out, lse8, delta8)
+    )(q_blocks, k_blocks, flags, spans, q, k, v, d_out, lse8, delta8)
 
   # dk/dv: b is a k/v head, its step's query head b * group + head[t].
   q_of_kv = lambda b, t, head, qs, ks, flags: (b * group + head[t], qs[t], 0)
@@ -903,7 +1096,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, d_out, *, scale, causal,
       out_shape=[dq_shape],
       interpret=interpret,
       name='flash_attention_bwd_dq',
-  )(*q_table[1:], q, k, v, d_out, lse8, delta8)[0]
+  )(q_blocks, k_blocks, flags, spans, q, k, v, d_out, lse8, delta8)[0]
   return dq, dk, dv
 
 
@@ -1006,12 +1199,14 @@ def flash_attention(q, k, v,
 
   A masked call sets, on the host while it is traced, the gauges
   ``attention/mask_pairs_needed``, ``attention/mask_pairs_computed`` (the
-  forward kernel's tiles; ``..._computed_bwd`` each backward kernel's) and
+  pairs the forward kernel computes: an interior tile whole, an edge
+  tile's strips over their spans, or the whole edge tile where its blocks
+  hold no strips; ``..._computed_bwd`` each backward kernel's),
   ``attention/grid_steps`` (the steps the forward kernel launches;
-  ``..._steps_bwd`` each backward kernel's), all heads of the call: steps
-  x tile = pairs computed, every step computes; and
-  ``attention/backward_kernels``, the kernels the backward launches (1
-  fused, 2 not).
+  ``..._steps_bwd`` each backward kernel's) and ``attention/edge_steps``
+  (of those, the steps that run as strips; ``attention/edge_steps_bwd``
+  the backward's), all heads of the call; and ``attention/backward_kernels``,
+  the kernels the backward launches (1 fused, 2 not).
 
   Default block sizes come from v5e sweeps (B=1, H=8, D=128, causal,
   chained on-device timing): (1024, 1024) — grid-step count (fixed

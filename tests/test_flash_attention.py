@@ -323,9 +323,15 @@ class TestBlockDiffusionMask:
     # The grids themselves: (heads, steps). The fused backward is ONE
     # kernel under the dq kernel's name, on its grid; of the two, dk/dv's
     # runs over the 2 k/v heads, each k/v block followed by both query
-    # heads of its group.
-    grids = [(eqn.params['name'], eqn.params['grid_mapping'].grid)
-             for eqn in jaxpr.eqns if eqn.primitive.name == 'pallas_call']
+    # heads of its group. (Each kernel sits in the jitted launcher of its
+    # call.)
+    def kernels(jaxpr):
+      for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'pallas_call':
+          yield eqn.params['name'], eqn.params['grid_mapping'].grid
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+          yield from kernels(inner)
+    grids = list(kernels(jaxpr.jaxpr))
     two = [('flash_attention_bwd_dkv', (2, 2 * tiles_bwd))] * (
         backward == 'two kernels')
     assert sorted(grids) == sorted(
@@ -358,6 +364,7 @@ MASKS = {
     'window': (256, 256, True, 40, None),
     'block diffusion': (256, 256, False, None, (128, 4)),
     'causal, more keys than queries': (64, 256, True, None, None),
+    'unmasked': (256, 192, False, None, None),
 }
 # name: (k_resident, group)
 GRIDS = {'forward and dq': (False, 1), 'dk/dv': (True, 1),
@@ -379,9 +386,11 @@ class TestTileTable:
     head, qs, ks, flags = flash_lib.tile_table(
         n_q, n_k, block_q, block_k, causal, window, diffusion,
         k_resident=k_resident, group=group)
-    # The oracle: a tile is needed when the dense mask keeps a pair of it.
-    needed = _dense_mask(l_q, l_k, causal, window, diffusion).reshape(
-        n_q, block_q, n_k, block_k).any(axis=(1, 3))
+    # The oracle: a tile is needed when the dense mask keeps a pair of it,
+    # on the mask's edge when it does not keep them all.
+    tiles = _dense_mask(l_q, l_k, causal, window, diffusion).reshape(
+        n_q, block_q, n_k, block_k)
+    needed, whole = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
     want = {(h, i, j) for h in range(group) for i, j in np.argwhere(needed).tolist()}
     # An accumulator the mask leaves empty gets one step of its own.
     empty = np.flatnonzero(~needed.any(axis=0 if k_resident else 1))
@@ -400,7 +409,9 @@ class TestTileTable:
     for t, flag in enumerate(flags.tolist()):
       first = t == 0 or resident[t - 1] != resident[t]
       last = t == len(steps) - 1 or resident[t + 1] != resident[t]
-      assert flag == flash_lib.FIRST * first + flash_lib.LAST * last
+      edge = not whole[qs[t], ks[t]]
+      assert flag == (flash_lib.FIRST * first + flash_lib.LAST * last +
+                      flash_lib.EDGE * edge)
 
   @pytest.mark.parametrize(
       'n_q, n_k, block_q, block_k, causal, window, diffusion, steps', [
@@ -552,3 +563,150 @@ class TestAValueWidthUnlikeTheKeyWidth:
     q, k, v = _qkv(l=64, d=32)
     with pytest.raises(ValueError, match='one head width'):
       flash_attention(q, k[..., :16], v)
+
+
+def _brute_spans(dense, table, block_q, block_k, strip):
+  """``strip_spans`` from the dense mask: for each EDGE step and each strip
+  of ``strip`` rows, (first, count) of the ``strip``-column sub-blocks from
+  the first that holds a kept pair to the last; (0, 0) elsewhere."""
+  _, qs, ks, flags = table
+  spans = np.zeros((len(qs), block_q // strip, 2), int)
+  for t in np.flatnonzero(flags & flash_lib.EDGE):
+    for r in range(block_q // strip):
+      row = qs[t] * block_q + r * strip
+      held = [j for j in range(block_k // strip) if dense[
+          row:row + strip,
+          ks[t] * block_k + j * strip:ks[t] * block_k + (j + 1) * strip].any()]
+      if held:
+        spans[t, r] = held[0], held[-1] - held[0] + 1
+  return spans
+
+
+# name: (l, causal, window, diffusion)
+EDGE_MASKS = {
+    'causal': (256, True, None, None),
+    'window': (256, True, 40, None),
+    'block diffusion': (256, False, None, (128, 4)),
+    'block diffusion, tiles across the halves': (384, False, None, (192, 4)),
+    'block diffusion, blocks of 24': (384, False, None, (192, 24)),
+    'unmasked': (256, False, None, None),
+}
+
+
+class TestEdgeTilesRunAsStrips:
+  """A tile the mask keeps only in part (EDGE) runs as strips of STRIP_ROWS
+  query rows, each over the sub-blocks that hold its pairs; a tile the mask
+  keeps whole runs with no mask. The host side against the dense mask, then
+  the kernels against the dense oracle at blocks that engage strips."""
+
+  @pytest.mark.parametrize('strip, block_q, block_k', [
+      (16, 64, 64), (32, 64, 128), (16, 32, 64)])
+  @pytest.mark.parametrize('mask', list(EDGE_MASKS))
+  def test_each_strips_span_holds_its_pairs(self, mask, strip, block_q,
+                                            block_k, monkeypatch):
+    monkeypatch.setattr(flash_lib, 'STRIP_ROWS', strip)
+    l, causal, window, diffusion = EDGE_MASKS[mask]
+    dense = _dense_mask(l, l, causal, window, diffusion)
+    table = flash_lib.tile_table(l // block_q, l // block_k, block_q,
+                                 block_k, causal, window, diffusion)
+    spans = flash_lib.strip_spans(table, l, l, block_q, block_k, causal,
+                                  window, diffusion)
+    edge = table[3] & flash_lib.EDGE != 0
+    assert edge.any() == (mask != 'unmasked')
+    # A call with no edge step runs no strip.
+    want = _brute_spans(dense, table, block_q, block_k, strip)
+    np.testing.assert_array_equal(spans, want if edge.any() else want[:, :0])
+    # Pairs computed: a whole tile at every interior step, the spans' sub-
+    # blocks at an edge step; never fewer than the mask keeps.
+    computed = flash_lib.pairs_computed(table, spans, block_q, block_k)
+    assert computed == (np.count_nonzero(~edge) * block_q * block_k +
+                        strip * strip * spans[..., 1].sum())
+    assert dense.sum() <= computed <= len(edge) * block_q * block_k
+    assert computed < len(edge) * block_q * block_k or mask == 'unmasked'
+
+  def test_blocks_under_two_strips_run_edge_tiles_whole(self, monkeypatch):
+    monkeypatch.setattr(flash_lib, 'STRIP_ROWS', 32)
+    for block_q, block_k in ((32, 64), (64, 48), (48, 64)):
+      table = flash_lib.tile_table(192 // block_q, 192 // block_k, block_q,
+                                   block_k, True, None, None)
+      spans = flash_lib.strip_spans(table, 192, 192, block_q, block_k, True,
+                                    None, None)
+      assert spans.shape == (table.shape[1], 0, 2)
+      assert flash_lib.pairs_computed(table, spans, block_q, block_k) == \
+          table.shape[1] * block_q * block_k
+
+  @pytest.mark.parametrize(
+      'l, block_q, block_k, causal, window, diffusion, strip, edge, ratio', [
+          # The third cell: forward (1024, 1024) and backward (512, 1024).
+          (16384, 1024, 1024, False, None, (8192, 4), 256, 24, 1.0620),
+          (16384, 512, 1024, False, None, (8192, 4), 256, 48, 1.0620),
+          (16384, 1024, 1024, False, None, (8192, 4), 128, 24, 1.0307),
+          # A full layer of the second, fourth and sixth cells; the second
+          # cell's window layers; the fifth cell's forward at 4,096.
+          (8192, 1024, 1024, True, None, None, 256, 8, 1.0311),
+          (8192, 1024, 1024, True, 4096, None, 256, 12, 1.0624),
+          (4096, 1024, 1024, True, None, None, 256, 4, 1.0622),
+      ])
+  def test_at_the_cells_shapes(self, l, block_q, block_k, causal, window,
+                               diffusion, strip, edge, ratio, monkeypatch):
+    """The edge steps a head and the pairs computed over the pairs the mask
+    keeps, as the gauges read them; where the parent computed edge tiles
+    whole: 1.2494, 1.1249, 1.2499 and 1.2497."""
+    monkeypatch.setattr(flash_lib, 'STRIP_ROWS', strip)
+    table = flash_lib.tile_table(l // block_q, l // block_k, block_q,
+                                 block_k, causal, window, diffusion)
+    spans = flash_lib.strip_spans(table, l, l, block_q, block_k, causal,
+                                  window, diffusion)
+    assert np.count_nonzero(table[3] & flash_lib.EDGE) == edge
+    computed = flash_lib.pairs_computed(table, spans, block_q, block_k)
+    needed = flash_lib.mask_pairs(l, l, causal, window, diffusion)
+    assert computed / needed == pytest.approx(ratio, abs=5e-5)
+
+  @pytest.mark.parametrize('mask, d_v, blocks', [
+      ('causal', 16, (128, 128, 64, 128)),
+      ('window', 16, (128, 128, 128, 64)),
+      ('causal', 8, (128, 64, 128, 128)),
+      ('block diffusion', 16, (128, 128, 64, 128)),
+  ], ids=['causal', 'window', 'value width unlike the key width',
+          'block diffusion'])
+  def test_outputs_gradients_and_gauges_against_the_dense_mask(
+      self, mask, d_v, blocks, monkeypatch):
+    """Four strips of 32 rows a 128-row block (two of a 64-row one), query
+    heads in groups of 2; the gauges count each kernel's strips' spans."""
+    from tensor2robot_tpu.observability import get_registry
+
+    monkeypatch.setattr(flash_lib, 'STRIP_ROWS', 32)
+    l, causal, window, diffusion = EDGE_MASKS[mask]
+    block_q, block_k, block_q_bwd, block_k_bwd = blocks
+    key = jax.random.PRNGKey(l + d_v)
+    q = jax.random.normal(key, (1, l, 4, 16))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, l, 2, 16))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, l, 2, d_v))
+    dense_mask = _dense_mask(l, l, causal, window, diffusion)
+
+    def dense(q, k, v):
+      k, v = (jnp.repeat(x, 2, axis=2) for x in (k, v))
+      scores = jnp.einsum('bqhd,bkhd->bhqk', q, k) / 4.0
+      probs = jax.nn.softmax(jnp.where(dense_mask, scores, -jnp.inf), -1)
+      return jnp.einsum('bhqk,bkhd->bqhd', probs, v)
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, window=window, block_diffusion=diffusion,
+        block_q=block_q, block_k=block_k, block_q_bwd=block_q_bwd,
+        block_k_bwd=block_k_bwd)
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-6)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, 'qkv'):
+      np.testing.assert_allclose(g, w, atol=1e-5, err_msg='d' + name)
+    value = lambda name: get_registry().gauge('attention/' + name).value
+    for suffix, bq, bk in (('', block_q, block_k),
+                           ('_bwd', block_q_bwd, block_k_bwd)):
+      table = flash_lib.tile_table(l // bq, l // bk, bq, bk, causal, window,
+                                   diffusion)
+      spans = _brute_spans(dense_mask, table, bq, bk, 32)
+      edge = np.count_nonzero(table[3] & flash_lib.EDGE)
+      assert edge and value('edge_steps' + suffix) == 4 * edge
+      assert value('mask_pairs_computed' + suffix) == 4 * (
+          (table.shape[1] - edge) * bq * bk + 32 * 32 * spans[..., 1].sum())
